@@ -554,7 +554,7 @@ def _diag_main(argv) -> int:
         state = (
             f"{cache['entries']} entries, {cache['size_bytes'] / 1e6:.1f} MB"
             if cache["enabled"]
-            else "disabled (TrainingPipeline(compile_cache=True) or $DMLCLOUD_COMPILE_CACHE_DIR)"
+            else "not configured in this process (TrainingPipeline and ServeEngine turn it on)"
         )
         print(f"* COMPILE CACHE:\n    - dir: {cache['dir']}\n    - state: {state}")
         built = lambda b: "yes" if b else "NO"  # noqa: E731 - two-word formatter

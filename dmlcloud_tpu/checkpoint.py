@@ -208,11 +208,6 @@ class CheckpointDir:
         self.path = as_run_path(path)
         self._state_managers: dict[str | None, Any] = {}
         self._manager_opts: dict[str | None, tuple] = {}
-        #: scope -> shim preservation policy evaluated host-side (old orbax
-        #: without the preservation-policy API; utils/orbax_compat.py)
-        self._retention_policies: dict[str | None, Any] = {}
-        #: scope -> {step: metrics dict} backing the shim BestN ranking
-        self._policy_metrics: dict[str | None, dict[int, dict]] = {}
         #: transient-filesystem-error policy for Orbax save dispatch: total
         #: attempts and the first backoff (doubles per retry, capped at 8s).
         #: Instance attributes so tests (and callers on flaky object stores)
@@ -315,29 +310,14 @@ class CheckpointDir:
             return self._state_managers[scope]
         import orbax.checkpoint as ocp
 
-        from .utils import orbax_compat
-
-        # old orbax has no preservation_policy option: strip it, remember it,
-        # and apply the retention ourselves after each save (identical keep
-        # semantics, host-side). ``requested`` above already includes the
-        # policy, so the changed-options guard behaves the same either way.
-        orbax_options = dict(options)
-        shim_policy = orbax_options.get("preservation_policy")
-        if orbax_compat.is_shim_policy(shim_policy):
-            orbax_options.pop("preservation_policy")
-        else:
-            shim_policy = None
-
         opts = ocp.CheckpointManagerOptions(
             max_to_keep=requested[0],
             enable_async_checkpointing=requested[1],
-            **orbax_options,
+            **options,
         )
         root = self.state_dir / scope if scope else self.state_dir
         self._state_managers[scope] = ocp.CheckpointManager(root, options=opts)
         self._manager_opts[scope] = requested
-        if shim_policy is not None:
-            self._retention_policies[scope] = shim_policy
         return self._state_managers[scope]
 
     def save_state(self, step: int, state: Any, scope: str | None = None, **kwargs) -> None:
@@ -365,8 +345,6 @@ class CheckpointDir:
                 what=f"save of step {step} (scope {scope!r})",
             )
         self._write_sharding_sidecar(scope, int(step), state)
-        if scope in self._retention_policies:
-            self._apply_retention(scope, step, kwargs.get("metrics"))
 
     def _retry_transient(self, fn, what: str):
         """Run ``fn``, retrying transient filesystem errors (``OSError``)
@@ -472,6 +450,7 @@ class CheckpointDir:
         meta = self.state_manager(scope).item_metadata(step)
         if meta is None:
             raise ValueError(f"no checkpoint metadata for step {step} (scope {scope!r})")
+        meta = meta.tree  # the saved pytree's own structure, out of orbax's TreeMetadata wrapper
         sidecar = self.read_sharding_sidecar(scope, step)
         specs = (sidecar or {}).get("specs", {})
         if sidecar is None:
@@ -496,44 +475,6 @@ class CheckpointDir:
             return jax.ShapeDtypeStruct(shape, m.dtype, sharding=NamedSharding(mesh, spec))
 
         return jax.tree_util.tree_map_with_path(leaf, meta)
-
-    # -- host-side retention (old orbax; utils/orbax_compat.py) -------------
-    def _policy_metrics_file(self, scope: str | None) -> epath.Path:
-        # under meta/ (not state/) so orbax's step scan never sees it; the
-        # non-digit stem survives the stage's sidecar retention cleanup
-        return self.path / "meta" / (scope or "_root") / "_policy_metrics.json"
-
-    def _apply_retention(self, scope: str | None, step: int, metrics: Any) -> None:
-        """Evaluate the shim preservation policy after a save and delete the
-        steps it does not keep. Every process computes the same keep set (the
-        metrics kwarg is identical across ranks); orbax's ``delete`` does the
-        actual (primary-host) filesystem work. Rankings persist across
-        restarts via a root-written JSON sidecar."""
-        import json
-
-        import jax
-
-        from .utils import orbax_compat
-
-        known = self._policy_metrics.setdefault(scope, {})
-        if not known:
-            try:
-                raw = json.loads(self._policy_metrics_file(scope).read_text())
-                known.update({int(k): v for k, v in raw.items()})
-            except Exception:
-                pass  # fresh run dir, or pre-shim checkpoints: rank what we have
-        if metrics is not None:
-            known[int(step)] = metrics
-        mgr = self._state_managers[scope]
-        steps = set(int(s) for s in mgr.all_steps()) | {int(step)}
-        keep = orbax_compat.steps_to_keep(self._retention_policies[scope], steps, known)
-        for old in sorted(steps - keep):
-            mgr.delete(old)
-            known.pop(old, None)
-        if jax.process_index() == 0:
-            meta_file = self._policy_metrics_file(scope)
-            meta_file.parent.mkdir(parents=True, exist_ok=True)
-            atomic_write_text(meta_file, json.dumps({str(k): v for k, v in known.items()}))
 
     def restore_state(
         self,
@@ -608,8 +549,6 @@ class CheckpointDir:
             mgr.close()
         self._state_managers = {}
         self._manager_opts = {}
-        self._retention_policies = {}
-        self._policy_metrics = {}
 
     def __str__(self) -> str:
         return str(self.path)

@@ -3,8 +3,9 @@
 Three parts, composable but independent (doc/performance.md §4):
 
 - :mod:`.cache` — persistent XLA compilation cache wiring + stats: compile
-  once per *cluster*, not once per process (``TrainingPipeline(
-  compile_cache=...)``, ``$DMLCLOUD_COMPILE_CACHE_DIR``).
+  once per *cluster*, not once per process (on by default in
+  ``TrainingPipeline`` and ``ServeEngine``; ``$JAX_COMPILATION_CACHE_DIR``
+  or ``<checkout>/.jax_cache``).
 - :mod:`.aot` — ahead-of-time compilation of the jitted train/val steps
   against abstract batch specs: compile cost lands in a timed ``precompile``
   phase before the data loop (``misc/compile_ms``), and sharding/shape

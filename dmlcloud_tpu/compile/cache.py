@@ -8,14 +8,22 @@ once under a hash key), which makes it exactly right for a shared
 filesystem on a multi-host pod: every host points at the same directory and
 the first job to compile pays for everyone.
 
-``configure_cache`` is the one entry point (called by
-``TrainingPipeline(compile_cache=...)`` before any compilation, or directly
-at program start). Resolution order for the directory:
+``configure_cache`` is the one entry point. ``TrainingPipeline`` and
+``ServeEngine`` call it before their first compile (the cache is ON by
+default for both); scripts that compile before building either call it at
+program start. There is ONE rule for the directory:
 
-1. an explicit path argument,
-2. ``$DMLCLOUD_COMPILE_CACHE_DIR``,
-3. whatever ``jax_compilation_cache_dir`` is already configured to,
-4. ``~/.cache/dmlcloud_tpu/xla``.
+1. if ``$JAX_COMPILATION_CACHE_DIR`` is set, that directory is the cache
+   and this module sets no other (an explicit path argument is ignored with
+   one log line) — a launcher that sets the variable decides where compiled
+   programs survive;
+2. otherwise an explicit path argument, if one was given;
+3. otherwise ``<checkout>/.jax_cache`` — a FIXED path next to the package
+   (the path is part of jax's cache key, so a directory that moves never
+   hits), never a home, temporary, pid- or time-derived one.
+
+``jax_enable_compilation_cache=False`` (jax's own switch; the test session
+sets it, tests/conftest.py) turns the whole thing off.
 
 Stats are two-layered: ``cache_stats()`` reports the on-disk population
 (entries/bytes — shared across every process using the dir) plus this
@@ -26,16 +34,18 @@ process 0 should log them (``TrainingPipeline`` does).
 
 from __future__ import annotations
 
+import logging
 import os
 import threading
 from typing import Any
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache as _jax_cache
 
 __all__ = [
     "ENV_VAR",
-    "DEFAULT_CACHE_DIR",
     "configure_cache",
+    "default_cache_dir",
     "resolve_cache_dir",
     "configured_cache_dir",
     "entry_count",
@@ -44,70 +54,69 @@ __all__ = [
     "reset_process_stats",
 ]
 
-ENV_VAR = "DMLCLOUD_COMPILE_CACHE_DIR"
-DEFAULT_CACHE_DIR = "~/.cache/dmlcloud_tpu/xla"
+#: jax's own variable: jax reads it into ``jax_compilation_cache_dir`` at import
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 
+_logger = logging.getLogger("dmlcloud_tpu")
 _lock = threading.Lock()
 _aot_hits = 0
 _aot_misses = 0
 _aot_compile_ms = 0.0
 
 
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: the directory that holds the package."""
+    package_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_dir), ".jax_cache")
+
+
 def configured_cache_dir() -> str | None:
-    """The directory jax's persistent cache currently writes to, or None."""
-    value = getattr(jax.config, "jax_compilation_cache_dir", None)
-    return value or None
+    """The directory jax's persistent cache currently writes to, or None
+    (no directory set, or jax's cache switched off)."""
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    return jax.config.jax_compilation_cache_dir or None
 
 
 def resolve_cache_dir(cache_dir: Any = True) -> str | None:
-    """Resolve the cache directory per the module docstring's order without
+    """Resolve the cache directory per the module docstring's rule without
     touching jax config. ``None``/``False`` disables (returns None)."""
     if cache_dir in (None, False):
         return None
-    if isinstance(cache_dir, (str, os.PathLike)):
-        chosen = os.fspath(cache_dir)
-    else:  # True / anything truthy: env var, existing config, default
-        chosen = os.environ.get(ENV_VAR) or configured_cache_dir() or DEFAULT_CACHE_DIR
-    return os.path.abspath(os.path.expanduser(chosen))
+    explicit = os.fspath(cache_dir) if isinstance(cache_dir, (str, os.PathLike)) else None
+    from_env = os.environ.get(ENV_VAR)
+    if from_env:
+        if explicit is not None and os.path.abspath(explicit) != os.path.abspath(from_env):
+            _logger.info(
+                "compile cache: ignoring explicit directory %s, $%s=%s decides", explicit, ENV_VAR, from_env
+            )
+        return os.path.abspath(from_env)
+    return os.path.abspath(explicit) if explicit is not None else default_cache_dir()
 
 
-def configure_cache(cache_dir: Any = True, aggressive: bool = True) -> str | None:
-    """Point jax's persistent compilation cache at ``cache_dir`` (resolved as
-    above), creating the directory. Must run before the first compilation of
-    the programs it should cover. Returns the resolved directory (None when
-    disabled).
+def configure_cache(cache_dir: Any = True) -> str | None:
+    """Point jax's persistent compilation cache at the resolved directory
+    (see above), creating it. Must run before the first compilation of the
+    programs it should cover. Returns the directory, or None when disabled
+    (``cache_dir`` None/False, or ``jax_enable_compilation_cache`` off).
 
-    ``aggressive`` (default) also drops jax's minimum-compile-time /
-    minimum-entry-size thresholds so every program is persisted — the right
-    trade for training jobs, where a cache entry costs kilobytes and a cold
-    recompile costs seconds to minutes. Flags missing on older jax are
-    skipped silently (the cache still works, with jax's own thresholds)."""
+    Also drops jax's minimum-compile-time / minimum-entry-size thresholds so
+    every program is persisted — the right trade for training and serving
+    jobs, where a cache entry costs kilobytes and a cold recompile costs
+    seconds to minutes."""
     resolved = resolve_cache_dir(cache_dir)
-    if resolved is None:
+    if resolved is None or not jax.config.jax_enable_compilation_cache:
         return None
     os.makedirs(resolved, exist_ok=True)
-    previous = configured_cache_dir()
-    jax.config.update("jax_compilation_cache_dir", resolved)
+    previous = jax.config.jax_compilation_cache_dir
     if previous != resolved:
-        # jax latches the cache backend on the FIRST compilation of the
-        # process; if anything compiled before this call (it usually has —
-        # even an import-time jnp op), the new dir is ignored until the
-        # latched state is dropped. Private API, so best-effort by version.
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:
-            pass
-    if aggressive:
-        for flag, value in (
-            ("jax_persistent_cache_min_compile_time_secs", 0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-        ):
-            try:
-                jax.config.update(flag, value)
-            except (AttributeError, ValueError):
-                pass
+        _jax_cache.set_cache_dir(resolved)
+        if previous:
+            # jax opens the directory it finds at the first compilation and
+            # keeps it; moving to another one means dropping that handle
+            _jax_cache.reset_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return resolved
 
 
@@ -155,8 +164,8 @@ def cache_stats() -> dict:
     """On-disk population + this process's AOT counters, JSON-encodable.
 
     When the cache is not enabled yet, ``dir`` still reports what
-    ``configure_cache(True)`` *would* use (env var or default) so ``diag``
-    shows an actionable path either way."""
+    ``configure_cache()`` *would* use so ``diag`` shows an actionable path
+    either way."""
     enabled_dir = configured_cache_dir()
     directory = enabled_dir or resolve_cache_dir(True)
     entries = size = 0

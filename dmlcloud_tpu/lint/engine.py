@@ -301,7 +301,7 @@ class ModuleCtx:
         #: names (incl. dotted ``self.f`` chains) bound to jitted callables
         #: with donated args -> set of donated positional indexes (DML204)
         self.donating_names: dict[str, set[int]] = {}
-        #: ``shard_map``/``shard_map_compat`` call sites (DML202) and the
+        #: ``shard_map`` call sites (DML202) and the
         #: function defs provably wrapped by one (DML201/DML203 context)
         self.shard_map_calls: list[ast.Call] = []
         self.shard_mapped_defs: set[ast.AST] = set()
@@ -426,7 +426,7 @@ class ModuleCtx:
             if donated and getattr(node, "name", None):
                 self.donating_names.setdefault(node.name, donated)
 
-        # shard_map / shard_map_compat sites and the defs they wrap
+        # shard_map sites and the defs they wrap
         for node in ast.walk(self.tree):
             if not isinstance(node, ast.Call):
                 continue
@@ -434,7 +434,7 @@ class ModuleCtx:
             last = resolved.split(".")[-1] if resolved else ""
             if not last and isinstance(node.func, ast.Attribute):
                 last = node.func.attr
-            if last not in ("shard_map", "shard_map_compat"):
+            if last != "shard_map":
                 continue
             self.shard_map_calls.append(node)
             if node.args:

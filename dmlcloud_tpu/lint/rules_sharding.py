@@ -77,7 +77,7 @@ def _fn_context_name(ctx: ModuleCtx, node: ast.AST) -> str:
 
 def _in_shard_map_body(ctx: ModuleCtx, node: ast.AST) -> bool:
     """Whether ``node`` sits inside a function (or lambda) this module
-    provably hands to ``shard_map``/``shard_map_compat``."""
+    provably hands to ``shard_map``."""
     enclosing = set(ctx.enclosing_functions(node))
     if enclosing & ctx.shard_mapped_defs:
         return True

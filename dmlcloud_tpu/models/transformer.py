@@ -60,8 +60,11 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     seq_axis: str = "seq"  # mesh axis used when attn_impl == 'ring'
-    # Mesh for attn_impl='ring' under plain jit (ring_attention_sharded wraps
-    # itself in shard_map); leave None when the step is already shard_mapped.
+    # Mesh for attn_impl='ring' and 'flash' under plain jit: both wrap
+    # themselves in shard_map over it (ring to split the sequence; flash
+    # because XLA cannot partition the Pallas kernel, so on a multi-device
+    # TPU mesh it does not compile without). Leave None on one device, or
+    # when the step is already shard_mapped.
     mesh: Any = None
     # Residual-stream sharding constraint ([B, T, D] activations), applied
     # after the embedding and every block. Pin this (e.g. a NamedSharding of
@@ -207,6 +210,17 @@ def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None
     return out.reshape(b, t, h, d)
 
 
+def _flash_attention(cfg: TransformerConfig, q, k, v, segment_ids=None):
+    """The flash path of every training branch: the kernel as it is on one
+    device, shard_mapped over ``cfg.mesh`` on several."""
+    from ..ops.flash_attention import flash_attention, flash_attention_sharded
+
+    kwargs = dict(causal=True, window=cfg.sliding_window, segment_ids=segment_ids)
+    if cfg.mesh is not None and cfg.mesh.size > 1:
+        return flash_attention_sharded(q, k, v, cfg.mesh, **kwargs)
+    return flash_attention(q, k, v, **kwargs)
+
+
 def _adapter_add(y, inp, name, adapters):
     """Add the per-row LoRA delta for dense ``name`` when ``adapters``
     carries a stacked pair for it (multi-tenant serving; see
@@ -276,11 +290,7 @@ class Attention(nn.Module):
             q = apply_rope(q, cos, sin, positions=positions)
             k = apply_rope(k, cos, sin, positions=positions)
             if cfg.attn_impl == "flash":
-                from ..ops.flash_attention import flash_attention
-
-                out = flash_attention(
-                    q, k, v, causal=True, window=cfg.sliding_window, segment_ids=seg_ids
-                )
+                out = _flash_attention(cfg, q, k, v, segment_ids=seg_ids)
             else:
                 out = _dot_attention(q, k, v, mask=mask)
         elif paged is not None:
@@ -333,9 +343,7 @@ class Attention(nn.Module):
                 mask = mask[None] & (kv_pos[None] >= pad_len[:, None, None])
             out = _dot_attention(q, k, v, mask=mask)
         elif cfg.attn_impl == "flash":
-            from ..ops.flash_attention import flash_attention
-
-            out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
+            out = _flash_attention(cfg, q, k, v)
         elif cfg.attn_impl == "ring":
             if cfg.mesh is not None:
                 from ..ops.ring_attention import ring_attention_sharded

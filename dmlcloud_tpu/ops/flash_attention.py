@@ -389,11 +389,12 @@ def flash_attention(
     backward recomputes probabilities flash-style in two kernels (dQ;
     dK/dV) — activations never materialise in HBM.
 
-    ``impl`` picks the lowering: ``"pallas"`` (the TPU kernels; honoured in
-    interpret mode off-TPU) or ``"xla"`` (the same blockwise algorithm as
-    plain XLA ops — the off-TPU default, since interpret mode loses to the
-    unfused path; see the module docstring). ``None`` auto-selects, except
-    an explicit ``interpret`` pins ``"pallas"``.
+    ``impl`` picks the lowering: ``"pallas"`` (the TPU kernels; off-TPU it
+    raises unless ``interpret`` says whether to emulate or to lower them) or
+    ``"xla"`` (the same blockwise algorithm as plain XLA ops — the off-TPU
+    default, since interpret mode loses to the unfused path; see the module
+    docstring). ``None`` auto-selects, except an explicit ``interpret`` pins
+    ``"pallas"``.
 
     Default Pallas blocks are large (512x1024) because the grid-step
     overhead, not VMEM, is the binding constraint on TPU: measured on v5e,
@@ -416,7 +417,13 @@ def flash_attention(
     elif impl == "xla":
         mode = "xla"
     elif impl == "pallas":
-        mode = bool(interpret) if interpret is not None else jax.default_backend() != "tpu"
+        if interpret is None and jax.default_backend() != "tpu":
+            raise ValueError(
+                f'impl="pallas" on the {jax.default_backend()!r} backend needs an explicit interpret=: '
+                "True emulates the kernels (slow; kernel-logic tests), False lowers them for a TPU "
+                "(ahead-of-time compiles for a described chip)"
+            )
+        mode = bool(interpret)
     else:
         raise ValueError(f"impl must be 'pallas', 'xla' or None, got {impl!r}")
     if block_q is None:
@@ -447,6 +454,53 @@ def flash_attention(
         out, lse = _flash_lse(q, k, v, segment_ids, causal, float(sm_scale), bq, bk, mode, window)
         return out, lse.reshape(b, h, t).transpose(0, 2, 1)  # [B, T, H]
     return _flash(q, k, v, segment_ids, causal, float(sm_scale), bq, bk, mode, window)
+
+
+def dividing_batch_axes(mesh, batch_size: int) -> tuple | None:
+    """The data axes of ``mesh`` (``data``, then ``fsdp``) that divide a
+    batch dimension, as a PartitionSpec entry — None when none does, so a
+    batch too small for them (module.init's example input) stays replicated.
+    Shared by the two attention ops that shard_map themselves."""
+    axes, rem = [], batch_size
+    for a in ("data", "fsdp"):
+        if a in mesh.axis_names and rem % mesh.shape[a] == 0:
+            axes.append(a)
+            rem //= mesh.shape[a]
+    return tuple(axes) or None
+
+
+def flash_attention_sharded(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    mesh,
+    *,
+    head_axis: str = "model",
+    segment_ids: jnp.ndarray | None = None,
+    **kwargs,
+) -> jnp.ndarray:
+    """:func:`flash_attention` callable under plain jit on a multi-device
+    mesh. XLA cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so the call shard_maps itself over
+    ``mesh``: batch on the data axes and heads on ``head_axis``, each where
+    it divides (module.init's size-1 example batch stays replicated).
+    Attention is independent per batch row and per KV-head group, so every
+    shard runs the unchanged kernel on its slice — with heads laid out as the
+    q/k/v projection rules leave them, no collective is added. The sequence
+    stays whole on each device; splitting it is ``ring_attention``'s job.
+    Keyword arguments are :func:`flash_attention`'s (without ``return_lse``)."""
+    from jax.sharding import PartitionSpec as P
+
+    batch = dividing_batch_axes(mesh, q.shape[0])
+    heads = head_axis if head_axis in mesh.axis_names and k.shape[2] % mesh.shape[head_axis] == 0 else None
+    spec = P(batch, None, heads, None)
+    if segment_ids is None:
+        fn = lambda q, k, v: flash_attention(q, k, v, **kwargs)
+        args, in_specs = (q, k, v), (spec, spec, spec)
+    else:
+        fn = lambda q, k, v, seg: flash_attention(q, k, v, segment_ids=seg, **kwargs)
+        args, in_specs = (q, k, v, jnp.asarray(segment_ids, jnp.int32)), (spec, spec, spec, P(batch, None))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec, check_vma=False)(*args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))

@@ -36,18 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .flash_attention import flash_attention
+from .flash_attention import dividing_batch_axes, flash_attention
 
 _NEG_INF = -1e30
-
-
-def _axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` across jax versions: 0.4.x has no such
-    function — ``psum(1, axis)`` is the classic idiom there (folded to a
-    compile-time constant for a concrete mesh axis)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def _merge_partials(m, w, acc, out_b, lse_b):
@@ -86,7 +77,7 @@ def ring_attention(
     long-context windowed training communicates O(W), not O(T).
     """
     b, tl, h, d = q.shape
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
 
     if window is not None:
@@ -169,7 +160,7 @@ def _ring_attention_windowed(q, k, v, axis_name, window, sm_scale, block_q, bloc
     from .flash_attention import _auto_block, _flash_lse
 
     b, tl, h, d = q.shape
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     if sm_scale is None:
         sm_scale = 1.0 / _math.sqrt(d)
@@ -246,12 +237,7 @@ def ring_attention_sharded(
             f"sequence length {q.shape[1]} is not divisible by mesh axis "
             f"{axis_name!r} of size {mesh.shape[axis_name]}"
         )
-    batch_axes, rem = [], q.shape[0]
-    for a in ("data", "fsdp"):
-        if a in mesh.axis_names and rem % mesh.shape[a] == 0:
-            batch_axes.append(a)
-            rem //= mesh.shape[a]
-    spec_q = P(tuple(batch_axes) or None, axis_name, None, None)
+    spec_q = P(dividing_batch_axes(mesh, q.shape[0]), axis_name, None, None)
 
     fn = partial(
         ring_attention,
@@ -263,8 +249,7 @@ def ring_attention_sharded(
         interpret=interpret,
         window=window,
     )
-    from ..parallel.mesh import shard_map_compat
-
-    return shard_map_compat(
-        fn, mesh=mesh, in_specs=(spec_q, spec_q, spec_q), out_specs=spec_q
+    # the ring's collectives produce per-shard values on purpose
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(spec_q, spec_q, spec_q), out_specs=spec_q, check_vma=False
     )(q, k, v)

@@ -155,23 +155,6 @@ def data_parallel_size(mesh: Mesh) -> int:
     return int(math.prod(mesh.shape[a] for a in data_axes(mesh)) or 1)
 
 
-def shard_map_compat(fn, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: newer releases expose it at the
-    top level (replication check flag ``check_vma``); 0.4.x ships it as
-    ``jax.experimental.shard_map.shard_map`` with the flag named
-    ``check_rep``. Both callers here disable the check (their collectives
-    intentionally produce per-shard values)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-    )
-
-
 def respec_for_mesh(spec: P | Sequence, shape: Sequence[int], mesh: Mesh) -> P:
     """Re-target a PartitionSpec recorded on ONE mesh onto ``mesh`` — the
     elastic-resume primitive: a checkpoint saved on an N-device mesh carries

@@ -103,11 +103,13 @@ def pipeline_apply(
     act_spec = P(None, batch_axes)  # [n_micro, micro_b, ...]
 
     fn = partial(_pipeline_local, stage_fn, n_stages=n_stages, n_micro=n_micro, axis=axis)
-    return mesh_lib.shard_map_compat(
+    # the stage loop's ppermutes produce per-shard values on purpose
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(P(axis), act_spec),
         out_specs=act_spec,
+        check_vma=False,
     )(stacked_params, x)
 
 

@@ -201,10 +201,7 @@ def _cpu_safety_flags() -> None:
     the CPU client is instantiated — which is why every ``init_*`` path calls
     it first.
     """
-    try:
-        jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except AttributeError:  # pragma: no cover - flag renamed/removed upstream
-        pass
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
 
 
 def init_single() -> None:

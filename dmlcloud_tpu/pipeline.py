@@ -59,7 +59,7 @@ class TrainingPipeline:
         verify: Optional[str] = None,
         hbm_budget: Optional[int] = None,
         sanitize: Optional[str] = None,
-        compile_cache: Any = None,
+        compile_cache: Any = True,
         precompile: bool = False,
         buckets: Any = None,
         telemetry: Any = None,
@@ -99,12 +99,13 @@ class TrainingPipeline:
 
         The cold-start killers (dmlcloud_tpu.compile; doc/performance.md §4):
 
-        - ``compile_cache``: persistent XLA compilation cache. ``True`` uses
-          ``$DMLCLOUD_COMPILE_CACHE_DIR`` (default
-          ``~/.cache/dmlcloud_tpu/xla``); a path selects the directory —
-          point every host of a pod at the same shared-FS dir (entries are
-          content-addressed; concurrent writers are safe; only process 0
-          logs stats). None (default) leaves jax's config untouched.
+        - ``compile_cache``: persistent XLA compilation cache, on by
+          default. The directory is ``$JAX_COMPILATION_CACHE_DIR`` when
+          that is set — point every host of a pod at the same shared-FS
+          dir (entries are content-addressed; concurrent writers are safe;
+          only process 0 logs stats) — else a path passed here, else
+          ``<checkout>/.jax_cache`` (compile/cache.py). None/False leaves
+          jax's config untouched.
         - ``precompile``: default for ``Stage.precompile()`` — AOT-compile
           the train/val steps at stage start against the first batch's
           abstract spec, before the data loop.

@@ -433,8 +433,12 @@ class ServeEngine:
         verify: str | None = None,
         hbm_budget: int | None = None,
     ):
+        from ..compile.cache import configure_cache
         from ..models.quant import prepare_decode_params
 
+        # before the engine's first compile: a restarted server loads its
+        # (batch x table)-bucket programs instead of rebuilding them
+        configure_cache()
         if spec_k < 0:
             raise ValueError(f"spec_k must be >= 0, got {spec_k}")
         if medusa_k < 0:

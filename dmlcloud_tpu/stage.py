@@ -830,9 +830,7 @@ class TrainValStage(Stage):
             mode = self.checkpoint_best_mode()
             if mode not in ("min", "max"):
                 raise ValueError(f"checkpoint_best_mode() must be 'min' or 'max', got {mode!r}")
-            # via the compat layer: new orbax passes the policy through, old
-            # orbax (no checkpoint_managers module) gets host-side retention
-            from .utils import orbax_compat as ocm
+            from orbax.checkpoint import checkpoint_managers as ocm
 
             # best-N by the metric PLUS always the newest (deterministic
             # requeue-resume freshness; best_fn+max_to_keep alone leaves the

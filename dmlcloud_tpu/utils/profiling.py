@@ -144,8 +144,9 @@ def profile_steps(fn, n: int, logdir: str, *args, **kwargs):
     return result
 
 
-#: bf16 peak FLOP/s by TPU device_kind substring (fallback: v5e's 197e12).
-#: The same table bench.py uses for its MFU lines.
+#: bf16 peak FLOP/s by TPU device_kind substring (Google Cloud's published
+#: per-chip peaks). The same table bench.py uses for its MFU lines; a kind
+#: that is not here has no peak — ``chip_peak_flops`` raises.
 PEAK_BF16_FLOPS = {
     "v4": 275e12,
     "v5 lite": 197e12,
@@ -158,8 +159,8 @@ PEAK_BF16_FLOPS = {
 
 def peak_flops_for_kind(kind: str) -> float | None:
     """Peak bf16 FLOP/s for a ``device_kind`` string, or None if unknown
-    (callers decide whether to fall back — an unknowing fallback turns MFU
-    numbers on non-TPU backends into nonsense)."""
+    (callers skip the metric or raise — a made-up peak turns MFU numbers on
+    other devices into nonsense)."""
     kind = kind.lower()
     for key, peak in PEAK_BF16_FLOPS.items():
         if key in kind:
@@ -168,12 +169,19 @@ def peak_flops_for_kind(kind: str) -> float | None:
 
 
 def chip_peak_flops(device=None) -> float:
-    """Peak bf16 FLOP/s of ``device`` (default: the first local device);
-    unknown device kinds fall back to the v5e peak."""
+    """Peak bf16 FLOP/s of ``device`` (default: the first local device).
+    A device kind that ``PEAK_BF16_FLOPS`` does not list is an error, not a
+    default: utilisation against another chip's peak is not a number."""
     import jax
 
     kind = (device or jax.local_devices()[0]).device_kind
-    return peak_flops_for_kind(kind) or 197e12
+    peak = peak_flops_for_kind(kind)
+    if peak is None:
+        raise ValueError(
+            f"no bf16 peak known for device kind {kind!r}; add it to "
+            "utils.profiling.PEAK_BF16_FLOPS with its source"
+        )
+    return peak
 
 
 def _xplane_pb2():
@@ -183,8 +191,10 @@ def _xplane_pb2():
         from tensorflow.tsl.profiler.protobuf import xplane_pb2
     except ImportError as e:  # tensorflow ships the xplane schema
         raise ImportError(
-            "roofline analysis parses the trace's xplane.pb, which needs the "
-            "tensorflow package for the proto schema only"
+            "roofline() reads the trace's xplane.pb through tensorflow's proto schema, and "
+            "the tensorflow package is absent on this installation (pyproject.toml does not "
+            "ask for it); ROADMAP S2 moves the reader to jax.profiler.ProfileData, which "
+            "needs only jax"
         ) from e
     return xplane_pb2
 

@@ -85,6 +85,9 @@ class LlamaStage(dml.TrainValStage):
             max_seq_len=cfg.seq_len,
             attn_impl=cfg.attn,
             remat=bool(cfg.remat),
+            # the flash kernel shard_maps itself over the mesh (XLA cannot
+            # partition it)
+            mesh=self.mesh if cfg.attn == "flash" else None,
             **PRESETS[cfg.preset],
         )
         self.model = DecoderLM(model_cfg)

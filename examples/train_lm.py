@@ -42,9 +42,10 @@ class LMStage(dml.TrainValStage):
             tie_embeddings=bool(cfg.get("tie_embeddings", False)),
             remat=bool(cfg.get("remat", False)),
             sliding_window=cfg.get("window"),
-            # ring attention under plain jit needs the mesh to shard_map
-            # itself over the seq axis; dot/flash are mesh-agnostic
-            mesh=self.mesh if cfg.attn == "ring" else None,
+            # ring and flash attention under plain jit shard_map themselves
+            # over the mesh (ring splits the sequence; the flash kernel cannot
+            # be partitioned by XLA); dot is mesh-agnostic
+            mesh=self.mesh if cfg.attn in ("ring", "flash") else None,
             **PRESETS[cfg.preset],
         )
         model = DecoderLM(model_cfg)

@@ -7,7 +7,7 @@ Static lint corpus — never imported or executed.
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dmlcloud_tpu.parallel.mesh import create_mesh, shard_map_compat
+from dmlcloud_tpu.parallel.mesh import create_mesh
 
 
 def body3(a, b, c):
@@ -27,4 +27,4 @@ f = jax.shard_map(body3, mesh=mesh, in_specs=(P("data"), P("data")), out_specs=P
 g = jax.shard_map(body1, mesh=mesh, in_specs=(P("model"),), out_specs=P("data"))
 
 # BAD: out_specs names an axis nothing declares anywhere
-h = shard_map_compat(body1, mesh=unknown_mesh, in_specs=(P("data"),), out_specs=P("qrst"))
+h = jax.shard_map(body1, mesh=unknown_mesh, in_specs=(P("data"),), out_specs=P("qrst"))

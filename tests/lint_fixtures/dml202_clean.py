@@ -7,7 +7,7 @@ Static lint corpus — never imported or executed.
 import jax
 from jax.sharding import PartitionSpec as P
 
-from dmlcloud_tpu.parallel.mesh import create_mesh, shard_map_compat
+from dmlcloud_tpu.parallel.mesh import create_mesh
 
 
 def body2(a, b):
@@ -30,7 +30,7 @@ g = jax.shard_map(body2, mesh=mesh, in_specs=specs, out_specs=P())
 # fine: mesh unresolvable (function parameter) — axes checked against the
 # registry, and 'data' is declared
 def wrap(some_mesh):
-    return shard_map_compat(body1, mesh=some_mesh, in_specs=(P("data"),), out_specs=P("data"))
+    return jax.shard_map(body1, mesh=some_mesh, in_specs=(P("data"),), out_specs=P("data"))
 
 
 # fine: lambda wrapped, arity matches

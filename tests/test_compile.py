@@ -265,59 +265,79 @@ class TestStageIntegration:
 
 
 class TestCacheStats:
-    @staticmethod
-    def _restore_cache_config(prev):
-        """Un-latch the persistent cache so later tests compile with the
-        process's original (disabled) configuration. NOTE: never call
-        ``jax.clear_caches()`` here — on this jax/XLA:CPU it destabilizes
-        live collective executables and later tests segfault."""
-        jax.config.update("jax_compilation_cache_dir", prev)
-        from jax._src import compilation_cache as cc
+    @pytest.fixture
+    def cache_on(self, monkeypatch):
+        """Turn jax's cache switch on for one test (the session keeps it off,
+        conftest.py) with no directory inherited from the environment, and
+        un-latch afterwards so later tests compile with the session's
+        (disabled) configuration. NOTE: never call ``jax.clear_caches()``
+        here — on this jax/XLA:CPU it destabilizes live collective
+        executables and later tests segfault."""
+        from jax.experimental.compilation_cache import compilation_cache as jax_cache
 
-        cc.reset_cache()
+        monkeypatch.delenv(cache_lib.ENV_VAR, raising=False)
+        prev_dir = jax.config.jax_compilation_cache_dir
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax_cache.reset_cache()  # jax latched "cache unused" at the session's first compile
+        yield
+        jax.config.update("jax_enable_compilation_cache", False)
+        jax.config.update("jax_compilation_cache_dir", prev_dir)
+        jax_cache.reset_cache()
 
-    def test_configure_and_stats(self, tmp_path):
-        prev = cache_lib.configured_cache_dir()
-        try:
-            resolved = cache_lib.configure_cache(str(tmp_path / "xla"))
-            assert resolved == str(tmp_path / "xla")
-            # a fresh lambda is a fresh jit object: compiles (and persists)
-            jax.jit(lambda x: jnp.sin(x) @ jnp.cos(x).T)(jnp.ones((64, 64))).block_until_ready()
-            stats = cache_lib.cache_stats()
-            assert stats["enabled"] and stats["dir"] == resolved
-            assert stats["entries"] >= 1
-            assert stats["size_bytes"] > 0
-        finally:
-            self._restore_cache_config(prev)
+    def test_configure_and_stats(self, tmp_path, cache_on):
+        resolved = cache_lib.configure_cache(str(tmp_path / "xla"))
+        assert resolved == str(tmp_path / "xla")
+        # a fresh lambda is a fresh jit object: compiles (and persists)
+        jax.jit(lambda x: jnp.sin(x) @ jnp.cos(x).T)(jnp.ones((64, 64))).block_until_ready()
+        stats = cache_lib.cache_stats()
+        assert stats["enabled"] and stats["dir"] == resolved
+        assert stats["entries"] >= 1
+        assert stats["size_bytes"] > 0
 
-    def test_resolve_order(self, tmp_path, monkeypatch):
+    def test_disabled_values_resolve_to_none(self):
         assert cache_lib.resolve_cache_dir(None) is None
         assert cache_lib.resolve_cache_dir(False) is None
-        explicit = cache_lib.resolve_cache_dir(str(tmp_path / "explicit"))
-        assert explicit.endswith("explicit")
-        monkeypatch.setenv(cache_lib.ENV_VAR, str(tmp_path / "from-env"))
-        assert cache_lib.resolve_cache_dir(True).endswith("from-env")
 
-    def test_aot_hit_recorded_on_second_precompile(self, tmp_path, single_runtime):
+    def test_env_var_wins_over_explicit_path(self, tmp_path, monkeypatch):
+        monkeypatch.setenv(cache_lib.ENV_VAR, str(tmp_path / "from-env"))
+        assert cache_lib.resolve_cache_dir(True) == str(tmp_path / "from-env")
+        assert cache_lib.resolve_cache_dir(str(tmp_path / "explicit")) == str(tmp_path / "from-env")
+
+    def test_default_is_inside_the_checkout(self, monkeypatch):
+        import os
+
+        monkeypatch.delenv(cache_lib.ENV_VAR, raising=False)
+        monkeypatch.setenv("DMLCLOUD_COMPILE_CACHE_DIR", "/nonexistent/retired-knob")
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(dml.__file__)))
+        default = cache_lib.resolve_cache_dir(True)
+        assert default == os.path.join(checkout, ".jax_cache") == cache_lib.default_cache_dir()
+        assert not default.startswith(os.path.expanduser("~/.cache"))
+        # without the variable an explicit path is still honoured
+        assert cache_lib.resolve_cache_dir("/tmp/explicit-xla") == "/tmp/explicit-xla"
+
+    def test_jax_switch_off_disables_configure(self, tmp_path):
+        # the session's own state (conftest.py): nothing is configured or created
+        assert not jax.config.jax_enable_compilation_cache
+        assert cache_lib.configure_cache(str(tmp_path / "xla")) is None
+        assert not (tmp_path / "xla").exists()
+        assert cache_lib.cache_stats()["enabled"] is False
+
+    def test_aot_hit_recorded_on_second_precompile(self, tmp_path, single_runtime, cache_on):
         """The persistent cache turns the second process's compile into a
         deserialization; in-process we can at least assert the hit/miss
         accounting: an identical program compiled through a FRESH jit fn
         adds no new cache entry -> counted as a hit."""
-        prev = cache_lib.configured_cache_dir()
         mesh = _one_device_mesh()
         spec = aot.global_batch_spec({"v": np.zeros((16,), np.float32)}, mesh)["v"]
-        try:
-            cache_lib.configure_cache(str(tmp_path / "xla"))
-            cache_lib.reset_process_stats()
-            # each PrecompiledStep wraps a FRESH jit object, so the second
-            # .lower().compile() re-traces — only the persistent cache can
-            # turn it into a deserialization (a hit, no new entry)
-            aot.PrecompiledStep(jax.jit(lambda x: jnp.tanh(x) * 3)).precompile(spec)
-            first = cache_lib.cache_stats()
-            aot.PrecompiledStep(jax.jit(lambda x: jnp.tanh(x) * 3)).precompile(spec)
-            second = cache_lib.cache_stats()
-        finally:
-            self._restore_cache_config(prev)
+        cache_lib.configure_cache(str(tmp_path / "xla"))
+        cache_lib.reset_process_stats()
+        # each PrecompiledStep wraps a FRESH jit object, so the second
+        # .lower().compile() re-traces — only the persistent cache can
+        # turn it into a deserialization (a hit, no new entry)
+        aot.PrecompiledStep(jax.jit(lambda x: jnp.tanh(x) * 3)).precompile(spec)
+        first = cache_lib.cache_stats()
+        aot.PrecompiledStep(jax.jit(lambda x: jnp.tanh(x) * 3)).precompile(spec)
+        second = cache_lib.cache_stats()
         assert first["aot_misses"] >= 1
         assert second["aot_hits"] >= first["aot_hits"] + 1
 

@@ -116,6 +116,53 @@ class TestFlashFwdBwdProperty:
             )
 
 
+    def test_pallas_off_tpu_needs_an_explicit_interpret(self):
+        """No silent interpreter: ``impl="pallas"`` on a CPU says whether to
+        emulate the kernels or to lower them, or it raises."""
+        q, k, v = _qkv()
+        with pytest.raises(ValueError, match="explicit interpret"):
+            flash_attention(q, k, v, causal=True, impl="pallas")
+
+
+class TestFlashSharded:
+    """``flash_attention_sharded`` (what ``attn_impl="flash"`` runs on a
+    multi-device mesh) against the unsharded op, on the suite's virtual CPU
+    devices: batch over fsdp, heads over model, GQA groups kept together."""
+
+    @pytest.mark.parametrize("packed", [False, True], ids=["plain", "segment_ids"])
+    def test_fwd_and_grads_match_unsharded(self, packed):
+        from dmlcloud_tpu.ops.flash_attention import flash_attention_sharded
+        from dmlcloud_tpu.parallel import mesh as mesh_lib
+
+        mesh = mesh_lib.create_mesh({"fsdp": 2, "model": 2}, devices=jax.devices()[:4])
+        q, k, v = _qkv(t=128, h=8, kh=2)
+        seg = jnp.asarray(np.repeat([[1, 2, 3, 0]], 2, 0).repeat(32, axis=1), jnp.int32) if packed else None
+        cot = jnp.asarray(np.random.RandomState(7).randn(*q.shape), jnp.float32)
+        kwargs = dict(causal=True, window=48, segment_ids=seg)
+        plain = lambda q, k, v: flash_attention(q, k, v, **kwargs)
+        sharded = jax.jit(lambda q, k, v: flash_attention_sharded(q, k, v, mesh, **kwargs))
+        np.testing.assert_allclose(
+            np.asarray(sharded(q, k, v)), np.asarray(plain(q, k, v)), atol=1e-5, rtol=1e-5
+        )
+        for g, w, name in zip(_grads(sharded, q, k, v, cot), _grads(plain, q, k, v, cot), "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(g), np.asarray(w), atol=1e-5, rtol=1e-5, err_msg=f"d{name}"
+            )
+
+    def test_indivisible_batch_and_heads_stay_replicated(self):
+        """module.init's size-1 example batch, and KV heads the model axis
+        does not divide, run whole on every device instead of failing."""
+        from dmlcloud_tpu.ops.flash_attention import flash_attention_sharded
+        from dmlcloud_tpu.parallel import mesh as mesh_lib
+
+        mesh = mesh_lib.create_mesh({"fsdp": 2, "model": 2}, devices=jax.devices()[:4])
+        q, k, v = _qkv(b=1, t=64, h=3, kh=3)
+        got = jax.jit(lambda q, k, v: flash_attention_sharded(q, k, v, mesh, causal=True))(q, k, v)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(flash_attention(q, k, v, causal=True)), atol=1e-5, rtol=1e-5
+        )
+
+
 class TestSpeculativeExactness:
     def test_shared_model_token_identical(self):
         """draft == target: every proposal must be accepted and the output

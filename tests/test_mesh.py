@@ -83,14 +83,12 @@ def test_make_global_batch_shards_batch_dim(mesh8):
 
 def test_sharded_psum_executes(mesh8):
     """A real 8-way psum through shard_map — the collective path DDP used to own."""
-    from dmlcloud_tpu.parallel.mesh import shard_map_compat
-
     x = jnp.arange(8.0)
 
     def global_sum(x):
         return jax.lax.psum(jnp.sum(x), "data")
 
-    global_sum = shard_map_compat(global_sum, mesh=mesh8, in_specs=P("data"), out_specs=P())
+    global_sum = jax.shard_map(global_sum, mesh=mesh8, in_specs=P("data"), out_specs=P())
     assert float(global_sum(x)) == 28.0
 
 

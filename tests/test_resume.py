@@ -350,13 +350,7 @@ def test_keep_best_retention(tmp_path, single_runtime):
     ckpt = CheckpointDir(run_dir)
     assert sorted(ckpt.state_manager("TrainValStage").all_steps()) == [2, 4, 5]
     # resume sidecars stayed in lockstep with the kept steps
-    # digit stems only: the scope dir may also hold the compat layer's
-    # _policy_metrics.json ranking sidecar (utils/orbax_compat.py)
-    metas = sorted(
-        int(f.stem)
-        for f in (ckpt.path / "meta" / "TrainValStage").glob("*.json")
-        if f.stem.isdigit()
-    )
+    metas = sorted(int(f.stem) for f in (ckpt.path / "meta" / "TrainValStage").glob("*.json"))
     assert metas == [2, 4, 5]
     ckpt.close()
 
@@ -394,7 +388,7 @@ def test_identical_policy_respecification_is_idempotent(tmp_path, single_runtime
     """Re-specifying a byte-identical keep-best policy (fresh lambdas) must
     not trip the changed-options guard."""
     from dmlcloud_tpu.checkpoint import CheckpointDir
-    from dmlcloud_tpu.utils import orbax_compat as ocm
+    from orbax.checkpoint import checkpoint_managers as ocm
 
     ckpt = CheckpointDir(str(tmp_path / "run"))
     ckpt.create()
