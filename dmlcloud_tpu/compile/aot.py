@@ -38,6 +38,7 @@ is exactly the full-precision one.
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Any
 
@@ -150,9 +151,20 @@ class PrecompiledStep:
 
         entries_before = cache_lib.entry_count()
         t0 = time.perf_counter()
-        with _journal.span("compile", label=self.name, signature=len(self._compiled) + 1):
-            compiled = self._fn.lower(*abstract_args).compile()
-        elapsed_ms = (time.perf_counter() - t0) * 1e3
+        compiled = self._fn.lower(*abstract_args).compile()
+        t1 = time.perf_counter()
+        elapsed_ms = (t1 - t0) * 1e3
+        j = _journal.active_journal()
+        if j is not None:
+            # with a journal armed, and only then, the step's phase map goes beside
+            # it: what joins a profile's operations to the program's phases
+            from ..utils.profiling import write_phase_map
+
+            n = len(self._compiled) + 1
+            safe = "".join(c if c.isalnum() or c in "._-" else "_" for c in self.name)
+            path = os.path.join(j.directory, f"phases-{safe}-{n}.json")
+            j.emit("compile", t0, t1, label=self.name, signature=n,
+                   phases=write_phase_map(compiled, path, self.name))
         entries_after = cache_lib.entry_count()
         hit = (
             entries_before is not None

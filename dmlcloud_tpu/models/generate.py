@@ -113,6 +113,7 @@ def decode_step(
     )
 
 
+@jax.named_scope("sampling")
 def sample_logits(logits, rng, temperature: float, top_k: int, top_p: float):
     """logits: [B, V] fp32 -> tokens [B] int32."""
     if temperature == 0.0:
@@ -137,6 +138,7 @@ def sample_logits(logits, rng, temperature: float, top_k: int, top_p: float):
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+@jax.named_scope("sampling")
 def _truncate_scaled(logits, temperature, top_k, top_p):
     """Per-row temperature/top-k/nucleus truncation with TRACED params.
 
@@ -175,6 +177,7 @@ def _truncate_scaled(logits, temperature, top_k, top_p):
     return jnp.where((top_p < 1.0) & (x < cutoff), -jnp.inf, x)
 
 
+@jax.named_scope("sampling")
 def sample_logits_batched(logits, rng, temperature, top_k, top_p):
     """Per-row traced twin of :func:`sample_logits`: ``logits`` is
     ``[B, V]`` fp32, the sampling params are ``[B]`` arrays so ONE

@@ -188,6 +188,7 @@ def _window_keep(q_pos, k_pos, window: int) -> jnp.ndarray:
     return (q_pos - k_pos) < window
 
 
+@jax.named_scope("attention")
 def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None):
     """Reference attention: fp32 softmax, bf16 matmuls. q:[B,T,H,D] k/v:[B,S,K,D].
     ``mask`` ([T, S] or [B, T, S] bool, True = attend) REPLACES the causal
@@ -210,6 +211,7 @@ def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None
     return out.reshape(b, t, h, d)
 
 
+@jax.named_scope("attn_kernel")
 def _flash_attention(cfg: TransformerConfig, q, k, v, segment_ids=None):
     """The flash path of every training branch: the kernel as it is on one
     device, shard_mapped over ``cfg.mesh`` on several."""
@@ -345,18 +347,19 @@ class Attention(nn.Module):
         elif cfg.attn_impl == "flash":
             out = _flash_attention(cfg, q, k, v)
         elif cfg.attn_impl == "ring":
-            if cfg.mesh is not None:
-                from ..ops.ring_attention import ring_attention_sharded
+            with jax.named_scope("attn_kernel"):
+                if cfg.mesh is not None:
+                    from ..ops.ring_attention import ring_attention_sharded
 
-                out = ring_attention_sharded(
-                    q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
-                )
-            else:
-                from ..ops.ring_attention import ring_attention
+                    out = ring_attention_sharded(
+                        q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
+                    )
+                else:
+                    from ..ops.ring_attention import ring_attention
 
-                out = ring_attention(
-                    q, k, v, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
-                )
+                    out = ring_attention(
+                        q, k, v, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
+                    )
         elif cfg.sliding_window is not None:
             pos = jnp.arange(t)
             q_pos, k_pos = pos[:, None], pos[None, :]
@@ -555,17 +558,20 @@ class DecoderLM(nn.Module):
             # the chunked-vocab loss path (chunked_lm_loss) consumes the
             # final hidden states directly and never materializes logits
             return x
-        if cfg.tie_embeddings:
-            embed = self.variables["params"]["embed"]["embedding"]
-            logits = jnp.einsum("btd,vd->btv", x.astype(jnp.float32), embed.astype(jnp.float32))
-        else:
-            from .quant import QuantDense
+        # the phase of the head's product (utils/profiling.PHASES): part of the loss
+        # in a training or evaluation forward, the decode step's own ``head``
+        with jax.named_scope("loss_head" if new_cache is None else "head"):
+            if cfg.tie_embeddings:
+                embed = self.variables["params"]["embed"]["embedding"]
+                logits = jnp.einsum("btd,vd->btv", x.astype(jnp.float32), embed.astype(jnp.float32))
+            else:
+                from .quant import QuantDense
 
-            logits = QuantDense(
-                cfg.vocab_size, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32, name="lm_head"
-            )(x)
-            if adapter_tree is not None:
-                logits = _adapter_add(logits, x, "lm_head", (adapter_tree, adapter_ids))
+                logits = QuantDense(
+                    cfg.vocab_size, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32, name="lm_head"
+                )(x)
+                if adapter_tree is not None:
+                    logits = _adapter_add(logits, x, "lm_head", (adapter_tree, adapter_ids))
         if new_cache is None:
             return logits
         if return_hidden:
@@ -576,6 +582,7 @@ class DecoderLM(nn.Module):
         return logits, new_cache
 
 
+@jax.named_scope("loss_head")
 def chunked_lm_loss(
     hidden: jnp.ndarray,
     kernel: jnp.ndarray,
@@ -647,6 +654,7 @@ def chunked_lm_loss(
     return _packed_mean(losses, segment_ids)
 
 
+@jax.named_scope("loss_head")
 def lm_loss(
     logits: jnp.ndarray, tokens: jnp.ndarray, segment_ids: jnp.ndarray | None = None
 ) -> jnp.ndarray:

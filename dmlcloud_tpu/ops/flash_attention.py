@@ -836,6 +836,7 @@ def _flash_fwd_impl(
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*operands)
 
     out = results[0].reshape(b, h, t, d).transpose(0, 2, 1, 3)
@@ -909,6 +910,7 @@ def _flash_bwd_impl(
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],  # dq accumulator
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_operands)
 
     # per-query-head dK/dV; group-summed below for GQA. 3D grid: the q-block
@@ -950,6 +952,7 @@ def _flash_bwd_impl(
             pl.BlockSpec((1, block_k, d), lambda bh, kb, qb: (bh, kb, 0), **vmem),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_operands)
 
     dq = dq.reshape(b, h, t, d).transpose(0, 2, 1, 3)

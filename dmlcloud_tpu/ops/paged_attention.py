@@ -52,11 +52,13 @@ therefore cost index arithmetic only; no branch, no dynamic shape.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["gather_pages", "scatter_tokens"]
 
 
+@jax.named_scope("kv_gather")
 def gather_pages(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     """Reassemble each row's pages into a contiguous KV view.
 
@@ -71,6 +73,7 @@ def gather_pages(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return g.reshape(tables.shape[0], tables.shape[1] * pool.shape[1], *pool.shape[2:])
 
 
+@jax.named_scope("kv_write")
 def scatter_tokens(
     pool: jnp.ndarray, tables: jnp.ndarray, positions: jnp.ndarray, values: jnp.ndarray
 ) -> jnp.ndarray:

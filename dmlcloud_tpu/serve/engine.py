@@ -181,7 +181,8 @@ def _paged_step(
     logits, pools = decode_step(
         model, params, tokens, pools, pages=(tables, fill), adapters=adapters
     )
-    last = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]  # [B, V]
+    with jax.named_scope("head"):
+        last = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]  # [B, V]
     tok = sample_logits_batched(last, rng, temperature, top_k, top_p)
     return tok, pools
 
@@ -617,13 +618,15 @@ class ServeEngine:
         # per-engine jit: jax keys its trace cache on the function OBJECT,
         # so a fresh partial per engine gives each engine its own cache —
         # the TraceGuard budget is then this engine's alone, not the
-        # process-wide total across every engine ever built
+        # process-wide total across every engine ever built. The partial
+        # takes the function's name, so the profile's module reads
+        # ``jit__paged_step`` (a bare partial is ``jit__unknown``)
         def _guarded(fn, budget, name, donate=(0,), statics=None):
             if statics is None:
                 statics = ("model",) + (("k",) if fn is not _paged_step else ())
             return TraceGuard(
                 jax.jit(
-                    functools.partial(fn),
+                    functools.update_wrapper(functools.partial(fn), fn),
                     static_argnames=statics,
                     donate_argnums=donate,
                 ),
@@ -745,14 +748,11 @@ class ServeEngine:
         with the findings; ``"error"`` raises :class:`LintError`."""
         import warnings
 
-        from ..compile import aot
         from ..lint import LintError
         from ..lint import ir as ir_mod
 
         bb = max(self.batch_buckets)
         tb = max(self.table_buckets)
-        sds = jax.ShapeDtypeStruct
-        f32, i32 = jnp.float32, jnp.int32
         specs = [
             ir_mod.ProgramSpec(
                 name="serve.signature_surface",
@@ -764,19 +764,7 @@ class ServeEngine:
             ir_mod.ProgramSpec(
                 name=f"serve.paged_step[b{bb}xt{tb}]",
                 fn=self._step_fn._fn,
-                args=(
-                    aot.abstract_spec(self.pool.pools),
-                    aot.abstract_spec(self.params),
-                    sds((bb, tb), i32),   # block tables
-                    sds((bb,), i32),      # fill
-                    sds((bb, 1), i32),    # tokens
-                    sds((bb,), i32),      # last_idx
-                    aot.abstract_spec(self._rng),
-                    None,                 # adapters
-                    sds((bb,), f32),      # temperature
-                    sds((bb,), i32),      # top_k
-                    sds((bb,), f32),      # top_p
-                ),
+                args=self._paged_step_specs(bb, tb, 1, adapters=False),
                 static_kwargs={"model": self.model},
                 donate_argnums=(0,),
                 hbm_budget_bytes=self.hbm_budget,
@@ -798,6 +786,47 @@ class ServeEngine:
             raise LintError(msg, findings=findings)
         warnings.warn(msg, stacklevel=3)
 
+    def _paged_step_specs(self, bb: int, tb: int, tokens: int, adapters: bool = True) -> tuple:
+        """The abstract arguments of one ``_paged_step`` signature: ``bb`` rows
+        of ``tokens`` tokens over ``tb`` table entries."""
+        from ..compile import aot
+
+        sds = jax.ShapeDtypeStruct
+        f32, i32 = jnp.float32, jnp.int32
+        lora = None
+        if adapters and self.adapters is not None:
+            lora = (aot.abstract_spec(self.adapters.stacked), sds((bb,), i32))
+        return (
+            aot.abstract_spec(self.pool.pools),
+            aot.abstract_spec(self.params),
+            sds((bb, tb), i32),       # block tables
+            sds((bb,), i32),          # fill
+            sds((bb, tokens), i32),   # tokens
+            sds((bb,), i32),          # last_idx
+            aot.abstract_spec(self._rng),
+            lora,
+            sds((bb,), f32),          # temperature
+            sds((bb,), i32),          # top_k
+            sds((bb,), f32),          # top_p
+        )
+
+    def phase_map(self, batch_bucket: int, table_bucket: int, *, prefill: bool = False) -> dict:
+        """``{instruction: (phase, direction)}`` (``utils.profiling.phase_map``)
+        of one compiled ``_paged_step`` signature: a decode batch of
+        ``batch_bucket`` rows over ``table_bucket`` table entries or, with
+        ``prefill``, ``batch_bucket`` rows of one prefill chunk. The engine
+        compiles lazily and keeps no executable, so the signature is lowered
+        and compiled here, on demand: a whole compile the first time (the
+        ahead-of-time path does not share the jitted call's cache entry), a
+        compile-cache hit after. For an operator reading a profile of this
+        engine, never inside ``step()``. It adds nothing to
+        ``compiled_signatures()``."""
+        from ..utils.profiling import phase_map
+
+        tokens = self.scheduler.prefill_chunk if prefill else 1
+        specs = self._paged_step_specs(int(batch_bucket), int(table_bucket), tokens)
+        return phase_map(self._step_fn._fn.lower(*specs, model=self.model).compile())
+
     # -- request lifecycle ---------------------------------------------------
     def submit(
         self,
@@ -814,6 +843,7 @@ class ServeEngine:
         tenant: str | None = None,
         token: str | None = None,
         trace: str | None = None,
+        arrival: float | None = None,
     ) -> int:
         """Queue one request; returns its id. ``prompt`` is a 1-D int32
         token sequence (no padding — paged rows sit at their own absolute
@@ -843,7 +873,13 @@ class ServeEngine:
         produces links under (doc/observability.md). A router mints one
         at ``Router.submit`` and threads it through failover, so the
         whole cross-replica history is ONE causal trace; a standalone
-        engine mints ``tr-<rid>`` when none is given."""
+        engine mints ``tr-<rid>`` when none is given.
+
+        ``arrival`` is when the request was due, on the engine's clock, for
+        a caller that held it in a queue of its own before this call (a
+        router, an open-loop load generator): the ledger's ``arrival``, the
+        ``queue_wait`` span and TTFT count from it. Default: now.
+        ``deadline_s`` stays a budget from this call."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt must contain at least one token")
@@ -869,6 +905,10 @@ class ServeEngine:
         if token is not None and token in self._tokens:
             raise DuplicateRequest(token, self._tokens[token])
         now = self.clock()
+        if arrival is None:
+            arrival = now
+        elif arrival > now:
+            raise ValueError(f"arrival ({arrival}) lies after the engine's clock ({now})")
         rid = self._next_id
         self._next_id += 1
         if rid in self._all:  # a reused rid would silently clobber bookkeeping
@@ -884,7 +924,7 @@ class ServeEngine:
         )
         resolved_tenant = tenant if tenant is not None else (adapter or "")
         seq = _Sequence(
-            req=req, arrival=now, adapter_id=aid,
+            req=req, arrival=float(arrival), adapter_id=aid,
             deadline=None if deadline_s is None else now + float(deadline_s),
             tenant=resolved_tenant, priority=int(priority), token=token, trace=trace,
             temperature=self._temperature if temperature is None else float(temperature),
@@ -894,14 +934,14 @@ class ServeEngine:
         )
         if self.draining:
             # drain contract: admission is closed — arrivals shed on sight
-            self.ledger.arrived(rid, now, tenant=resolved_tenant)
+            self.ledger.arrived(rid, seq.arrival, tenant=resolved_tenant)
             self._all[rid] = seq
             if token is not None:
                 self._tokens[token] = rid
             self._finalize(seq, now, "shed")
             return rid
         shed = self.scheduler.submit(seq)  # validates; raising records nothing
-        self.ledger.arrived(rid, now, tenant=resolved_tenant)
+        self.ledger.arrived(rid, seq.arrival, tenant=resolved_tenant)
         self._all[rid] = seq
         if token is not None:
             self._tokens[token] = rid
@@ -1017,7 +1057,19 @@ class ServeEngine:
         any device work ran. A failure in
         either device phase is isolated to the request(s) it was
         advancing — the step itself never raises for a per-request
-        fault."""
+        fault. With a journal armed the whole iteration is one
+        ``engine_step`` span: its bookkeeping is that span minus the
+        ``call_build`` and device-call spans inside it."""
+        j = journal.active_journal()
+        if j is None:
+            return self._step()
+        t0 = journal.now()
+        try:
+            return self._step()
+        finally:
+            j.emit("engine_step", t0)
+
+    def _step(self) -> bool:
         now = self.clock()
         if self.watchdog is not None:
             self.watchdog.notify()
@@ -1038,6 +1090,7 @@ class ServeEngine:
         if self.draining:
             self._drain_step(now)
         else:
+            j = journal.active_journal()
             for seq in self.scheduler.admit(now):
                 rid = seq.req.id
                 self.ledger.admitted(rid, now)
@@ -1049,21 +1102,23 @@ class ServeEngine:
                         rid, cached=seq.cached_tokens, saved=seq.fill,
                         prompt=seq.prompt_len,
                     )
-                    journal.emit("prefix_lookup", now, now, label=f"req{rid}",
-                                 request=rid, trace=seq.trace,
-                                 cached=seq.cached_tokens, saved=seq.fill,
-                                 shared=seq.shared)
+                    if j is not None:
+                        j.emit("prefix_lookup", now, now, label=f"req{rid}",
+                               request=rid, trace=seq.trace,
+                               cached=seq.cached_tokens, saved=seq.fill,
+                               shared=seq.shared)
                     if self.metrics is not None:
                         self._m_pref_lookups.inc()
                         if seq.cached_tokens > 0:
                             self._m_pref_hits.inc()
                         self._m_pref_saved.inc(seq.fill)
-                journal.emit("queue_wait", seq.arrival, now, label=f"req{rid}",
-                             request=rid, trace=seq.trace,
-                             depth=self.scheduler.depth())
-                journal.emit("admission", now, now, label=f"req{rid}",
-                             request=rid, trace=seq.trace, tenant=seq.tenant,
-                             blocks=len(seq.blocks), cached=seq.cached_tokens)
+                if j is not None:
+                    j.emit("queue_wait", seq.arrival, now, label=f"req{rid}",
+                           request=rid, trace=seq.trace,
+                           depth=self.scheduler.depth())
+                    j.emit("admission", now, now, label=f"req{rid}",
+                           request=rid, trace=seq.trace, tenant=seq.tenant,
+                           blocks=len(seq.blocks), cached=seq.cached_tokens)
         if self.metrics is not None:
             self._m_depth.observe(self.scheduler.depth())
             self._m_active.set(self.scheduler.active)
@@ -1283,19 +1338,44 @@ class ServeEngine:
 
     def _call(self, pool, model, params, tables, fill, tokens, last_idx, ids, row_params,
               use_adapters=True):
+        """One ``_paged_step`` call: ``(tokens, marks)``. ``marks`` are the
+        ``perf_counter`` readings after the uploads, after the launch and
+        after the fetch, for :meth:`_emit_call`; with no journal armed they
+        are all the call costs."""
         temps, topks, topps, _ = row_params
         adapters = None
         if self.adapters is not None and use_adapters:
             adapters = (self.adapters.stacked, jnp.asarray(ids, jnp.int32))
-        tok, new_pools = self._step_fn(
-            pool.pools, params,
+        args = (
             jnp.asarray(tables, jnp.int32), jnp.asarray(fill, jnp.int32),
             jnp.asarray(tokens, jnp.int32), jnp.asarray(last_idx, jnp.int32),
-            self._next_rng(), adapters, temps, topks, topps,
-            model=model,
+            self._next_rng(),
+        )
+        t_uploaded = time.perf_counter()
+        tok, new_pools = self._step_fn(
+            pool.pools, params, *args, adapters, temps, topks, topps, model=model,
         )
         pool.swap(new_pools)
-        return np.asarray(tok)  # the per-step host sync: tokens ARE the output
+        t_launched = time.perf_counter()  # dmllint: disable=DML106 -- call_launch IS the enqueue; the fetch below waits
+        tok = np.asarray(tok)  # the per-step host sync: tokens ARE the output
+        return tok, (t_uploaded, t_launched, time.perf_counter())
+
+    @staticmethod
+    def _emit_call(j, kind, t_build, t0, marks, bucket, blocks, label, **attrs) -> None:
+        """The spans of one device call, on an armed journal ``j``: the
+        call's own ``kind`` span from ``t0`` to the fetch's end, tiled by
+        ``call_upload`` (host arrays to the device, the key's fold-in),
+        ``call_launch`` (the jitted call returning, the pool swapped) and
+        ``call_fetch`` (the tokens back on the host); before it
+        ``call_build`` (copy-on-write guards, tables, fills, row
+        parameters and their uploads) from ``t_build``."""
+        t1, t2, t3 = marks
+        shared = {"parent": kind, "bucket": bucket, "blocks": blocks}
+        j.emit("call_build", t_build, t0, **shared)
+        j.emit(kind, t0, t3, label=label, bucket=bucket, blocks=blocks, **attrs)
+        j.emit("call_upload", t0, t1, **shared)
+        j.emit("call_launch", t1, t2, **shared)
+        j.emit("call_fetch", t2, t3, **shared)
 
     def _cow_guard(self, seq, lo: int, hi: int) -> None:
         """The copy-on-write fork rule: before ANY paged scatter that will
@@ -1328,8 +1408,10 @@ class ServeEngine:
             seq.blocks[bi] = new
             self.pool.release([old])
             seq.shared = min(seq.shared, bi)
-            journal.emit("cow_fork", journal.now(), label=f"req{seq.req.id}:cow",
-                         request=seq.req.id, trace=seq.trace, cow_block=bi)
+            j = journal.active_journal()
+            if j is not None:
+                j.emit("cow_fork", journal.now(), label=f"req{seq.req.id}:cow",
+                       request=seq.req.id, trace=seq.trace, cow_block=bi)
 
     def _table_rows(self, seqs, nb: int, draft: bool = False) -> np.ndarray:
         pool = self.draft_pool if draft else self.pool
@@ -1341,6 +1423,8 @@ class ServeEngine:
         return rows
 
     def _prefill_chunk(self, seq) -> None:
+        j = journal.active_journal()  # read once a call: off, no label or list is built
+        t_build = journal.now()
         self._chaos("prefill", [seq])
         c = self.scheduler.prefill_chunk
         n = min(c, seq.prompt_len - seq.fill)
@@ -1357,26 +1441,29 @@ class ServeEngine:
         fill = np.asarray([seq.fill], np.int32)
         last = np.asarray([n - 1], np.int32)
         t0 = journal.now()
-        tok = self._call(
+        tok, marks = self._call(
             self.pool, self.model, self.params,
             self._table_rows([seq], nb), fill, tokens, last,
             [seq.adapter_id], row_params,
         )
-        journal.emit("prefill", t0, label=f"req{seq.req.id}", request=seq.req.id,
-                     trace=seq.trace, chunk=n, fill=seq.fill + n, blocks=nb)
+        if j is not None:
+            self._emit_call(j, "prefill", t_build, t0, marks, 1, nb, f"req{seq.req.id}",
+                            request=seq.req.id, trace=seq.trace, chunk=n, fill=seq.fill + n)
         if self.spec_k:
             # the draft pool needs the same prompt K/V: one mirrored chunk
             # through the draft model (its sampled token is discarded)
             t1 = journal.now()
-            self._call(
+            _, marks = self._call(
                 self.draft_pool, self.draft_model, self.draft_params,
                 self._table_rows([seq], nb, draft=True), fill, tokens, last,
                 [seq.adapter_id], row_params,
                 use_adapters=False,  # the draft proposes base-model (spec x LoRA)
             )
-            journal.emit("draft", t1, label=f"req{seq.req.id}:prefill",
-                         request=seq.req.id, traces=[seq.trace], chunk=n, blocks=nb)
+            if j is not None:
+                self._emit_call(j, "draft", t1, t1, marks, 1, nb, f"req{seq.req.id}:prefill",
+                                request=seq.req.id, traces=[seq.trace], chunk=n)
         seq.fill += n
+        self.ledger.prefilled(n)
         if final:
             # the last real prompt position's logits ARE the first token —
             # time-to-first-token ends here, before any decode step
@@ -1395,6 +1482,8 @@ class ServeEngine:
             self._emit(seq, int(tok[0]), now)
 
     def _decode(self, batch) -> None:
+        j = journal.active_journal()  # read once a call: off, no label or list is built
+        t_build = journal.now()
         self._chaos("decode", batch)
         for s in batch:
             # refcount check before the scatter (DML211): decode writes at
@@ -1415,13 +1504,14 @@ class ServeEngine:
             ids[i] = s.adapter_id
         row_params = self._row_params(batch, bb)
         t0 = journal.now()
-        tok = self._call(
+        tok, marks = self._call(
             self.pool, self.model, self.params, tables, fill, tokens,
             np.zeros(bb, np.int32), ids, row_params,
         )
         now = self.clock()
-        journal.emit("decode_batch", t0, label=f"b{bb}", active=len(batch),
-                     bucket=bb, blocks=nb, traces=[s.trace for s in batch])
+        if j is not None:
+            self._emit_call(j, "decode_batch", t_build, t0, marks, bb, nb, f"b{bb}",
+                            active=len(batch), traces=[s.trace for s in batch])
         self.ledger.step_sample(self.scheduler.depth(), len(batch))
         if self.metrics is not None:
             self._m_batch.set(len(batch))

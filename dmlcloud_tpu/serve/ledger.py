@@ -77,6 +77,9 @@ class ServeLedger:
         self.max_records = max_records
         self.records: dict[int, dict] = {}
         self.decode_steps = 0
+        #: prompt tokens whose prefill chunk has completed, over every request
+        #: so far (tokens a prefix-cache hit skipped were never prefilled)
+        self.prefilled_tokens = 0
         # terminal rids in finish order — the FIFO eviction queue
         self._evictable: collections.deque[int] = collections.deque()
         # running aggregates: per-step samples (never per-step lists) and
@@ -127,6 +130,9 @@ class ServeLedger:
                     maxlen=self._window
                 )
             dq.append(now - rec["arrival"])
+
+    def prefilled(self, tokens: int) -> None:
+        self.prefilled_tokens += int(tokens)
 
     def token(self, rid: int) -> None:
         self.records[rid]["tokens"] += 1

@@ -701,13 +701,16 @@ class TrainValStage(Stage):
                 (loss, (metrics, new_extras)), grads = grad_fn(state.params, state.extras, rng, batch)
             else:
                 loss, metrics, new_extras, grads = self._accumulate(grad_fn, state, rng, batch, accum)
+            # the two scopes are phases of the step's profile (utils/profiling.PHASES)
             if clip > 0.0:
-                gnorm = jax.lax.rsqrt(
-                    jnp.maximum(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree_util.tree_leaves(grads)), 1e-12)
-                )
-                scale = jnp.minimum(1.0, clip * gnorm)
-                grads = jax.tree_util.tree_map(lambda g: g * scale.astype(g.dtype), grads)
-            new_state = state.apply_gradients(grads).replace(extras=new_extras)
+                with jax.named_scope("grad_clip"):
+                    gnorm = jax.lax.rsqrt(
+                        jnp.maximum(sum(jnp.sum(g.astype(jnp.float32) ** 2) for g in jax.tree_util.tree_leaves(grads)), 1e-12)
+                    )
+                    scale = jnp.minimum(1.0, clip * gnorm)
+                    grads = jax.tree_util.tree_map(lambda g: g * scale.astype(g.dtype), grads)
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads).replace(extras=new_extras)
             if int8:
                 # delayed scaling: the NEXT step quantizes with THIS
                 # step's post-update amax — one fused reduction here, no
@@ -716,7 +719,8 @@ class TrainValStage(Stage):
                     extras={**new_state.extras, QUANT_AMAX_KEY: amax_tree(new_state.params)}
                 )
             if ema_decay > 0.0:
-                new_state = new_state.update_ema(ema_decay)
+                with jax.named_scope("optimizer"):
+                    new_state = new_state.update_ema(ema_decay)
             metrics = dict(metrics)
             metrics[self.loss_metric_name()] = loss
             return new_state, metrics
@@ -1621,9 +1625,9 @@ class TrainValStage(Stage):
                 step_start = time.perf_counter_ns()
                 self.state, metrics = self._train_step_fn(self.state, batch)
                 step_end = time.perf_counter_ns()
-                _journal.emit(
-                    "step_dispatch", step_start / 1e9, step_end / 1e9, step=steps_done + 1
-                )
+                j = _journal.active_journal()
+                if j is not None:  # off: two clock readings and nothing built
+                    j.emit("step_dispatch", step_start / 1e9, step_end / 1e9, step=steps_done + 1)
 
                 if not deferred:
                     with self._stall.measure(label="metric_readback"):  # eager per-step readback

@@ -91,6 +91,12 @@ SPAN_KINDS = frozenset(
         "prefix_lookup",  # serving: radix-tree prefix match at admission
         "cow_fork",  # serving: one copy-on-write block fork
         "slo_alert",  # serving: a multi-window SLO burn-rate alert fired
+        "engine_step",  # serving: one ServeEngine.step(), bookkeeping and device calls
+        "call_build",  # serving: host work before a device call (COW guards, tables, row params)
+        "call_upload",  # serving: a call's host arrays to the device, the key's fold-in
+        "call_launch",  # serving: the jitted call returning, the pool swapped
+        "call_fetch",  # serving: the call's tokens back on the host (the one sync)
+        "profile",  # a jax.profiler trace the program took (utils.profiling.trace)
     }
 )
 

@@ -5,10 +5,10 @@ SURVEY.md §5.1): capture real XLA traces viewable in TensorBoard/Perfetto.
 - ``trace(logdir)``: context manager around ``jax.profiler`` — wrap any block
   (a few train steps) to record device timelines, HLO op breakdown, and memory.
 - ``profile_steps(fn, n, logdir)``: run a callable ``n`` times under a trace.
-- ``roofline(trace_dir)``: parse the trace's own per-op hardware counters
-  (hlo_category / flops / bytes_accessed) into a per-category roofline
-  table next to the chip's peaks — the analysis that settled whether the
-  ResNet bench was MXU- or HBM-bound (doc/performance.md §6).
+- ``phase_map(compiled)``: the phase (``PHASES``) and direction of every
+  instruction of a compiled step, from the ``op_name`` its own HLO text keeps.
+- ``phase_table(trace_dir, phases)``: device time by phase and by kernel from
+  a trace, read with ``jax.profiler.ProfileData`` alone.
 - ``StepTimer``: dispatch-to-dispatch wall timer with p50/p95 summaries, the
   host-side complement used by bench.py.
 - ``StallTimer``: accumulates the wall-clock the host spends *blocked* on
@@ -19,15 +19,21 @@ SURVEY.md §5.1): capture real XLA traces viewable in TensorBoard/Perfetto.
 
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
+import json
 import os
+import re
 import time
 from contextlib import contextmanager
 
 import numpy as np
 
-__all__ = ["trace", "profile_steps", "roofline", "format_roofline", "StepTimer", "StallTimer"]
+__all__ = [
+    "trace", "profile_steps", "PHASES", "phase_of", "phase_map", "write_phase_map", "phase_table",
+    "format_phase_table", "StepTimer", "StallTimer",
+]
 
 
 class StallTimer:
@@ -124,11 +130,20 @@ def trace(logdir: str, create_perfetto_link: bool = False):
     """
     import jax
 
+    from ..telemetry import journal as _journal
+
+    t0 = time.perf_counter()
     jax.profiler.start_trace(logdir, create_perfetto_link=create_perfetto_link)
+    t_started = time.perf_counter()
     try:
         yield
     finally:
+        t_stop = time.perf_counter()
         jax.profiler.stop_trace()
+        # the clock anchor: the profile's nanoseconds count from the profiler's
+        # start, which lies between ``ts`` and ``ts + started_after``
+        _journal.emit("profile", t0, t_stop, label=logdir, started_after=t_started - t0,
+                      stopped_after=time.perf_counter() - t_stop)
 
 
 def profile_steps(fn, n: int, logdir: str, *args, **kwargs):
@@ -184,111 +199,230 @@ def chip_peak_flops(device=None) -> float:
     return peak
 
 
-def _xplane_pb2():
-    # generated protos predate protobuf 5's C++ descriptor pool checks
-    os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except ImportError as e:  # tensorflow ships the xplane schema
-        raise ImportError(
-            "roofline() reads the trace's xplane.pb through tensorflow's proto schema, and "
-            "the tensorflow package is absent on this installation (pyproject.toml does not "
-            "ask for it); ROADMAP S2 moves the reader to jax.profiler.ProfileData, which "
-            "needs only jax"
-        ) from e
-    return xplane_pb2
+#: The phase vocabulary (doc/observability.md). A phase is a path component
+#: of an instruction's ``op_name``: a ``jax.named_scope`` the program opened
+#: (``attn_kernel``, ``loss_head``, ``grad_clip``, ``optimizer``, ``kv_write``,
+#: ``kv_gather``, ``attention``, ``head``, ``sampling``) or a Flax module's
+#: name (``embed``, ``mlp``; ``attn`` and ``*_norm`` through the aliases below).
+PHASES = (
+    "embed", "norm", "attn_proj", "attn_kernel", "kv_write", "kv_gather", "attention",
+    "mlp", "loss_head", "head", "sampling", "grad_clip", "optimizer",
+)
+#: Flax module names that are not themselves phase names.
+_PHASE_ALIASES = {"attn": "attn_proj", "moe": "mlp"}
+#: what jax wraps round a path component when a transformation passes over it
+_TRANSFORMS = frozenset(
+    {"jit", "pjit", "jvp", "transpose", "vmap", "pmap", "shard_map", "checkpoint", "remat",
+     "custom_jvp", "custom_vjp"}
+)
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_IDENT = re.compile(r"[\w.\-]+")
 
 
-def _stat_value(plane, st):
-    """Decode an XStat across its value oneof (incl. uint64 and interned refs)."""
-    kind = st.WhichOneof("value")
-    if kind is None:
-        return None
-    if kind == "ref_value":  # string interned in stat_metadata
-        return plane.stat_metadata[st.ref_value].name
-    return getattr(st, kind)
+def phase_of(op_name: str) -> tuple[str | None, str]:
+    """``(phase, direction)`` of one ``op_name``. The phase is the innermost
+    path component that names one (``PHASES``, or a Flax module name that
+    stands for one); ``None`` where no component does. The direction comes
+    from jax's own markers: ``recompute`` under ``rematted_computation``,
+    ``bwd`` under ``transpose(``, ``fwd`` under ``jvp(``, ``-`` where the
+    instruction was never differentiated (the optimizer, a serve step)."""
+    if "rematted_computation" in op_name:
+        direction = "recompute"
+    elif "transpose(" in op_name:
+        direction = "bwd"
+    elif "jvp(" in op_name:
+        direction = "fwd"
+    else:
+        direction = "-"
+    for component in reversed(op_name.split("/")):
+        for word in reversed(_IDENT.findall(component)):
+            if word in _TRANSFORMS:
+                continue
+            word = _PHASE_ALIASES.get(word, word)
+            if word in PHASES:
+                return word, direction
+            if word.endswith("_norm"):
+                return "norm", direction
+            break  # the component's own name is no phase: look further out
+    return None, direction
 
 
-def roofline(trace_dir: str, steps: int = 1) -> tuple[dict, list[dict]]:
-    """Aggregate a ``jax.profiler`` trace by HLO category from the chip's own
-    op counters. Returns ``(peaks, rows)``: ``peaks`` has the device type and
-    hardware peaks (TFLOP/s, HBM GB/s); each row has ``category``,
-    ``time_frac``, ``ms_per_step``, ``tflops`` (achieved), ``gbps``
-    (achieved), ``n_per_step``. ``steps`` = timed steps inside the trace.
+def _hlo_computations(text: str) -> dict:
+    """``{computation: [(instruction, op_name | None, is_root, called computation | None)]}``
+    of an HLO module's text."""
+    computations, current = {}, None
+    for line in text.splitlines():
+        if current is None:
+            m = _HLO_COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            op_name, calls = _HLO_OP_NAME.search(line), _HLO_CALLS.search(line)
+            current.append((m.group(2), op_name.group(1) if op_name else None, bool(m.group(1)),
+                            calls.group(1) if calls else None))
+    return computations
 
-    Counter conventions: ``flops`` counts multiply-add as TWO ops (the MFU
-    convention — compare against peak directly); ``bytes_accessed`` includes
-    VMEM-resident reads, so aggregates may exceed the HBM peak while per-op
-    numbers near it still identify bandwidth-bound ops."""
-    xplane_pb2 = _xplane_pb2()
+
+def phase_map(compiled) -> dict[str, tuple[str | None, str]]:
+    """``{instruction name: (phase, direction)}`` for every instruction of a
+    compiled step (``jitted.lower(...).compile()``, or its ``as_text()``).
+
+    The profile a chip writes names each device operation by its HLO
+    instruction (``%fusion.129 = ...``) and carries no ``op_name``; the
+    compiled executable's own text does. This joins the two by instruction
+    name, so it has to be the text of the executable that ran: XLA numbers
+    instructions per compile.
+
+    A fusion runs, and is timed, as one operation. XLA gives the fusion
+    instruction the metadata of its root, so a fusion's phase is its root's;
+    where the root carries none (a tuple of several outputs), the phase most
+    of the fused instructions carry. A fused body that spans two phases is
+    not split: its whole time goes to that one phase. Instructions whose
+    ``op_name`` holds no phase (parameters, copies XLA added, the key's
+    fold-in) map to ``(None, direction)`` and are the table's
+    ``unattributed`` row."""
+    text = compiled if isinstance(compiled, str) else compiled.as_text()
+    computations = _hlo_computations(text)
+    out = {}
+    for instructions in computations.values():
+        for name, op_name, _, calls in instructions:
+            phase, direction = phase_of(op_name) if op_name else (None, "-")
+            if phase is None and calls in computations:
+                body = [(phase_of(o), is_root) for _, o, is_root, _ in computations[calls] if o]
+                named = [pd for pd, _ in body if pd[0] is not None]
+                root = next((pd for pd, is_root in body if is_root), (None, "-"))
+                if root[0] is not None:
+                    phase, direction = root
+                elif named:
+                    phase, direction = collections.Counter(named).most_common(1)[0][0]
+            out[name] = (phase, direction)
+    return out
+
+
+def write_phase_map(compiled, path: str, program: str) -> str:
+    """``phase_map(compiled)`` as JSON at ``path``:
+    ``{"program": ..., "phases": {instruction: [phase, direction]}}``."""
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump({"program": program, "phases": phase_map(compiled)}, f)
+    return path
+
+
+def _newest_xplane(trace_dir: str) -> str:
+    if os.path.isfile(trace_dir):
+        return trace_dir
     paths = sorted(glob.glob(os.path.join(trace_dir, "plugins/profile/*/*.xplane.pb")))
     if not paths:
         raise FileNotFoundError(f"no xplane.pb under {trace_dir} (not a jax.profiler trace dir?)")
-    xs = xplane_pb2.XSpace()
-    with open(paths[-1], "rb") as f:
-        xs.ParseFromString(f.read())
+    return paths[-1]
+
+
+def _self_times(events: list) -> list:
+    """``(name, ns)`` per event of one device line, each instant given to the
+    innermost event open over it (a ``while`` spans its body's operations)."""
+    out, stack = [], []  # stack of (end, index into out)
+    for start, end, name in sorted(events, key=lambda e: (e[0], -e[1])):
+        while stack and stack[-1][0] <= start:
+            stack.pop()
+        if stack:
+            out[stack[-1][1]][1] -= min(end, stack[-1][0]) - start
+        out.append([name, end - start])
+        stack.append((end, len(out) - 1))
+    return [(n, max(ns, 0)) for n, ns in out]
+
+
+def phase_table(trace_dir: str, phases: dict | str | None = None, steps: int = 1, program: str | None = None) -> dict:
+    """Device time by phase and by kernel from a ``jax.profiler`` trace, read
+    with ``jax.profiler.ProfileData`` alone. ``phases`` is a ``phase_map`` (or
+    the path of the JSON ``write_phase_map`` wrote) of the program that ran;
+    without one every operation is ``unattributed`` and only the kernels are
+    told apart. ``program`` keeps the operations that ran inside programs
+    whose name holds it (``train_step``, ``paged_step``); ``steps`` is how
+    many steps the trace holds. Returns::
+
+        {"device": plane name, "profile_start_ns": ..., "steps": n, "busy_ms_per_step": ...,
+         "phases": [{"phase", "direction", "time_frac", "ms_per_step", "n_per_step"}],
+         "kernels": [{"kernel", "time_frac", "ms_per_step", "n_per_step"}]}
+
+    ``phases`` rows sum to the device's busy time inside those programs; a
+    kernel is a custom call, named as the program named it
+    (``pallas_call(name=...)``)."""
+    import jax
+
+    if isinstance(phases, str):
+        with open(phases, encoding="utf-8") as f:
+            phases = json.load(f)["phases"]
+    phases = phases or {}
+    data = jax.profiler.ProfileData.from_file(_newest_xplane(trace_dir))
     plane = next(
-        (
-            p
-            for p in xs.planes
-            if p.name.startswith("/device:TPU") and any(l.name == "XLA Ops" for l in p.lines)
-        ),
+        (p for p in data.planes if p.name.startswith("/device:") and any(l.name == "XLA Ops" for l in p.lines)),
         None,
     )
     if plane is None:
-        raise ValueError("no TPU device plane with an 'XLA Ops' line in this trace")
-
-    def stats_of(stats):
-        return {plane.stat_metadata[st.metadata_id].name: _stat_value(plane, st) for st in stats}
-
-    pstats = stats_of(plane.stats)
-    peaks = {
-        "device": pstats.get("device_type_string", "?"),
-        "peak_tflops": float(pstats.get("peak_teraflops_per_second", 0) or 0),
-        "peak_hbm_gbps": float(pstats.get("peak_hbm_bw_gigabytes_per_second", 0) or 0),
+        raise ValueError("no device plane with an 'XLA Ops' line in this trace")
+    lines = {l.name: l for l in plane.lines}
+    ops = [(e.start_ns, e.start_ns + e.duration_ns, e.name) for e in lines["XLA Ops"].events]
+    if program is not None and "XLA Modules" in lines:
+        runs = sorted((e.start_ns, e.start_ns + e.duration_ns) for e in lines["XLA Modules"].events if program in e.name)
+        starts = [a for a, _ in runs]
+        # an operation belongs to the run it started in (ends are rounded and may pass the run's by a nanosecond)
+        in_run = lambda t: (i := bisect.bisect_right(starts, t) - 1) >= 0 and t < runs[i][1]
+        ops = [o for o in ops if in_run(o[0])]
+    by_phase = collections.defaultdict(lambda: [0, 0])
+    by_kernel = collections.defaultdict(lambda: [0, 0])
+    for name, ns in _self_times(ops):
+        m = _IDENT.search(name)
+        instruction = m.group(0) if m else name
+        phase, direction = phases.get(instruction) or (None, "-")
+        row = by_phase[(phase or "unattributed", direction)]
+        row[0] += ns
+        row[1] += 1
+        if " custom-call(" in name:
+            row = by_kernel[re.sub(r"\.\d+$", "", instruction)]  # flash_fwd.3 -> flash_fwd
+            row[0] += ns
+            row[1] += 1
+    total = sum(ns for ns, _ in by_phase.values()) or 1
+    rows = lambda agg, key: [
+        {**key(k), "time_frac": ns / total, "ms_per_step": ns / 1e6 / steps, "n_per_step": n // steps}
+        for k, (ns, n) in sorted(agg.items(), key=lambda kv: -kv[1][0])
+    ]
+    started = [dict(p.stats).get("profile_start_time") for p in data.planes if p.name == "Task Environment"]
+    return {
+        "device": plane.name,
+        # Unix nanoseconds of the profile's time zero, where the runtime wrote it: an
+        # event at ``t`` ns lies at ``profile_start_ns + t``, the journal's ``ts`` x 1e9
+        "profile_start_ns": started[0] if started else None,
+        "steps": steps,
+        "busy_ms_per_step": total / 1e6 / steps if by_phase else 0.0,
+        "phases": rows(by_phase, lambda k: {"phase": k[0], "direction": k[1]}),
+        "kernels": rows(by_kernel, lambda k: {"kernel": k}),
     }
-    (ops_line,) = [l for l in plane.lines if l.name == "XLA Ops"]
-    agg = collections.defaultdict(lambda: [0.0, 0.0, 0.0, 0])  # ps, flops, bytes, n
-    for ev in ops_line.events:
-        s = stats_of(plane.event_metadata[ev.metadata_id].stats)
-        row = agg[s.get("hlo_category", "?")]
-        row[0] += ev.duration_ps
-        row[1] += float(s.get("flops", 0) or 0)
-        row[2] += float(s.get("bytes_accessed", 0) or 0)
-        row[3] += 1
-    total_ps = sum(v[0] for v in agg.values()) or 1.0
-    rows = [
-        {
-            "category": cat,
-            "time_frac": ps / total_ps,
-            "ms_per_step": ps / 1e9 / steps,
-            "tflops": fl / ps if ps else 0.0,  # flops/ps == TFLOP/s
-            "gbps": by / (ps / 1e12) / 1e9 if ps else 0.0,
-            "n_per_step": n // steps,
-        }
-        for cat, (ps, fl, by, n) in sorted(agg.items(), key=lambda kv: -kv[1][0])
-    ]
-    return peaks, rows
 
 
-def format_roofline(peaks: dict, rows: list[dict], min_frac: float = 0.001) -> str:
-    """Human-readable roofline table (what scripts/analyze_trace.py prints)."""
+def format_phase_table(table: dict, min_frac: float = 0.001) -> str:
+    """Human-readable phase table (what scripts/analyze_trace.py prints)."""
     out = [
-        f"device: {peaks['device']}  peak {peaks['peak_tflops']:.0f} TF/s, "
-        f"HBM {peaks['peak_hbm_gbps']:.0f} GB/s",
-        f"{'category':<28}{'time%':>7}{'ms/step':>9}{'TFLOP/s':>9}{'GB/s':>8}{'n/step':>8}",
+        f"device: {table['device']}  {table['busy_ms_per_step']:.2f} ms/step busy over {table['steps']} step(s)",
+        f"{'phase':<16}{'direction':<11}{'time%':>7}{'ms/step':>10}{'n/step':>8}",
     ]
-    for r in rows:
-        if r["time_frac"] < min_frac:
-            continue
-        out.append(
-            f"{r['category']:<28}{r['time_frac'] * 100:>6.1f}%{r['ms_per_step']:>8.2f}"
-            f"{r['tflops']:>9.1f}{r['gbps']:>8.0f}{r['n_per_step']:>8}"
-        )
-    total_ms = sum(r["ms_per_step"] for r in rows)
-    tf = sum(r["tflops"] * r["ms_per_step"] for r in rows) / total_ms if total_ms else 0.0
-    pct = f" ({tf / peaks['peak_tflops'] * 100:.0f}% of peak)" if peaks["peak_tflops"] else ""
-    out.append(f"total: {total_ms:.2f} ms/step on device; aggregate {tf:.1f} TFLOP/s{pct}")
+    for r in table["phases"]:
+        if r["time_frac"] >= min_frac:
+            out.append(
+                f"{r['phase']:<16}{r['direction']:<11}{r['time_frac'] * 100:>6.1f}%{r['ms_per_step']:>10.3f}{r['n_per_step']:>8}"
+            )
+    if table["kernels"]:
+        out.append(f"{'kernel':<27}{'time%':>7}{'ms/step':>10}{'n/step':>8}")
+        for r in table["kernels"]:
+            out.append(
+                f"{r['kernel']:<27}{r['time_frac'] * 100:>6.1f}%{r['ms_per_step']:>10.3f}{r['n_per_step']:>8}"
+            )
     return "\n".join(out)
 
 

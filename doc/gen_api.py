@@ -67,7 +67,7 @@ MODULES = [
     ("dmlcloud_tpu.utils.config", "Config container with interpolation."),
     ("dmlcloud_tpu.utils.logging", "Experiment logging, diagnostics, IO redirection."),
     ("dmlcloud_tpu.utils.seed", "Seeding and determinism flags."),
-    ("dmlcloud_tpu.utils.profiling", "jax.profiler traces, roofline analysis, step timers."),
+    ("dmlcloud_tpu.utils.profiling", "jax.profiler traces, phase maps and phase tables, step timers."),
     ("dmlcloud_tpu.utils.tensorboard", "TensorBoard metrics sink."),
     ("dmlcloud_tpu.utils.table", "Live progress table."),
     ("dmlcloud_tpu.utils.slurm", "Slurm environment parsing."),

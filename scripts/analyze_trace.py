@@ -1,21 +1,19 @@
-"""Roofline breakdown of a ``jax.profiler`` trace, by HLO category —
-or, pointed at a SERVE run's span journals, the per-request latency table.
+"""Device time by phase and by kernel of a ``jax.profiler`` trace — or, pointed
+at a SERVE run's span journals, the per-request latency table.
 
-Thin CLI over ``dmlcloud_tpu.utils.profiling.roofline`` (which parses the
-xplane.pb's own per-op counters — the same data XProf's op-profile tab
-renders). This is how doc/performance.md §5's ResNet ledger was produced:
+Thin CLI over ``dmlcloud_tpu.utils.profiling.phase_table``, which reads the
+trace's ``.xplane.pb`` with ``jax.profiler.ProfileData`` alone:
 
-    python scripts/tune_resnet.py --trace /tmp/tr
-    python scripts/analyze_trace.py /tmp/tr --steps 30
+    python scripts/analyze_trace.py /tmp/tr --steps 30 --program train_step \
+        --phases /tmp/run/telemetry/phases-MyStage.train_step-1.json
 
-Notes on the counters (they are the chip's own accounting, not estimates):
-- ``flops`` counts a multiply-add as TWO ops — the MFU convention. This is
-  how the 16%-MFU myth for the ResNet bench died: the widely quoted
-  "4.1 GFLOPs" for ResNet-50 is a MAC count, and the hardware executes
-  2x that, which the trace shows directly (23.9 GFLOPs/image trained).
-- ``bytes_accessed`` includes VMEM-resident operand reads, so the aggregate
-  can exceed the HBM peak; per-op numbers near the HBM peak still identify
-  bandwidth-bound ops (their operands stream from HBM).
+The profile names an operation by its HLO instruction and carries no scope; the
+phase of each instruction comes from the phase map the program writes beside
+its journal when telemetry is armed (``PrecompiledStep.precompile``), or that
+``utils.profiling.write_phase_map`` / ``ServeEngine.phase_map`` give for any
+compiled step. Without ``--phases`` everything is ``unattributed`` and only the
+kernels, which carry their ``pallas_call(name=...)``, are told apart
+(doc/observability.md, "Phases").
 
 When the directory holds telemetry span journals instead (a serve run:
 ``journal-rank*.jsonl`` under it or its ``telemetry/``), the analysis
@@ -23,9 +21,6 @@ switches to the request plane — per-request TTFT/ITL percentiles derived
 from the linked traces (doc/observability.md), with ``--tenant`` focusing
 one tenant's requests. ITL is estimated from the gaps between successive
 decode batches a request rode (the journal records batches, not tokens).
-
-Requires tensorflow (baked into this image) for the xplane proto only —
-the serve path is pure stdlib + numpy.
 """
 
 import argparse
@@ -34,12 +29,12 @@ import sys
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
-from dmlcloud_tpu.utils.profiling import format_roofline, roofline  # noqa: E402
+from dmlcloud_tpu.utils.profiling import format_phase_table, phase_table  # noqa: E402
 
 #: bump when the --json object's shape changes (consumers pin on this).
-#: v2 is ADDITIVE over v1: the roofline keys ("steps"/"peaks"/"rows")
-#: are unchanged; serve-journal inputs add a "serve" object instead.
-JSON_SCHEMA_VERSION = 2
+#: v3: a profiler trace gives the phase table ("table") where v2 gave the
+#: tensorflow-read roofline ("peaks"/"rows"); the "serve" object is v2's.
+JSON_SCHEMA_VERSION = 3
 
 _BATCH_KINDS = ("decode_batch", "draft", "verify", "medusa")
 
@@ -136,14 +131,16 @@ def main(argv=None):
         "with telemetry journals",
     )
     ap.add_argument("--steps", type=int, default=30, help="timed steps inside the trace")
+    ap.add_argument("--phases", default=None, help="phase map (JSON) of the program that ran")
+    ap.add_argument("--program", default=None, help="keep programs whose name holds this (train_step, paged_step)")
     ap.add_argument(
         "--tenant", default=None,
         help="serve journals: only this tenant's requests",
     )
     ap.add_argument(
         "--json", action="store_true",
-        help='machine-readable output: {"version", "steps", "peaks", "rows"} '
-        'for a profiler trace, {"version", "serve"} for serve journals',
+        help='machine-readable output: {"version", "table"} for a profiler trace, '
+        '{"version", "serve"} for serve journals',
     )
     args = ap.parse_args(argv)
 
@@ -167,8 +164,8 @@ def main(argv=None):
               file=sys.stderr)
         return 2
 
-    peaks, rows = roofline(args.trace_dir, steps=args.steps)
-    if not rows:
+    table = phase_table(args.trace_dir, phases=args.phases, steps=args.steps, program=args.program)
+    if not table["phases"]:
         # a device plane with zero op events: the traced region dispatched no
         # device work (trace() wrapped host-only code, or the steps never ran)
         print(
@@ -179,19 +176,9 @@ def main(argv=None):
         )
         return 1
     if args.json:
-        print(
-            json.dumps(
-                {
-                    "version": JSON_SCHEMA_VERSION,
-                    "steps": args.steps,
-                    "peaks": peaks,
-                    "rows": rows,
-                },
-                sort_keys=True,
-            )
-        )
+        print(json.dumps({"version": JSON_SCHEMA_VERSION, "table": table}, sort_keys=True))
     else:
-        print(format_roofline(peaks, rows))
+        print(format_phase_table(table))
     return 0
 
 

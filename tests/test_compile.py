@@ -350,3 +350,72 @@ class TestCacheStats:
         cache = info["compile_cache"]
         assert set(cache) >= {"enabled", "dir", "entries", "size_bytes", "aot_hits", "aot_misses"}
         assert cache["dir"]  # always actionable: configured dir or the default
+
+
+# ------------------------------------------- the phase map beside the journal
+
+
+class _TinyLMStage(dml.TrainValStage):
+    """A two-layer decoder LM on the flash path, clipped, AdamW: every phase
+    of a real train step, at toy widths."""
+
+    def pre_stage(self):
+        from dmlcloud_tpu.models.transformer import DecoderLM, TransformerConfig
+
+        cfg = TransformerConfig(vocab_size=64, hidden_dim=32, num_layers=2, num_heads=4, num_kv_heads=2, mlp_dim=64,
+                                max_seq_len=32, attn_impl="flash", dtype=jnp.float32)
+        tokens = np.random.RandomState(0).randint(0, 64, size=(2, 32)).astype(np.int32)
+        self.pipeline.register_model("lm", DecoderLM(cfg), init_args=(jnp.asarray(tokens),), verbose=False)
+        self.pipeline.register_optimizer("adamw", optax.adamw(1e-3))
+        self.pipeline.register_dataset("train", [tokens, tokens], verbose=False)
+
+    def gradient_clip(self):
+        return 1.0
+
+    def step(self, state, batch):
+        from dmlcloud_tpu.models.transformer import lm_loss
+
+        return lm_loss(state.apply_fn({"params": state.params}, batch), batch)
+
+    def val_epoch(self):
+        pass
+
+
+class TestPhaseMapBesideTheJournal:
+    def test_armed_journal_gets_the_compiled_steps_map(self, single_runtime, tmp_path):
+        """With a journal armed, precompile writes the step's phase map beside
+        it and names the file in its compile span; in the map every
+        instruction under a known scope is in that scope's phase, forward and
+        backward, and what has no phase is reported, not dropped."""
+        from dmlcloud_tpu.telemetry import journal as journal_mod
+        from dmlcloud_tpu.utils.profiling import PHASES
+
+        j = journal_mod.activate(journal_mod.SpanJournal(tmp_path))
+        try:
+            stage = _TinyLMStage()
+            _run_pipeline(stage, epochs=1, precompile=True)
+        finally:
+            journal_mod.deactivate()
+        spans = [r for r in j.tail(1024) if r["kind"] == "compile" and "train_step" in r["label"]]
+        assert len(spans) == 1 and spans[0]["signature"] == 1
+        path = spans[0]["phases"]
+        assert path == str(tmp_path / "phases-_TinyLMStage.train_step-1.json")
+        doc = json.load(open(path))
+        assert doc["program"] == "_TinyLMStage.train_step"
+        seen = {tuple(pd) for pd in doc["phases"].values() if pd[0]}
+        assert {p for p, _ in seen} <= set(PHASES)
+        for phase in ("embed", "attn_proj", "attn_kernel", "mlp", "norm", "loss_head"):
+            assert {(phase, "fwd"), (phase, "bwd")} <= seen, phase
+        assert {("grad_clip", "-"), ("optimizer", "-")} <= seen
+        rest = [n for n, (phase, _) in doc["phases"].items() if phase is None]
+        assert rest and len(rest) < len(doc["phases"])  # parameters, constants, XLA's copies
+        j.close()
+
+    def test_no_journal_no_map_and_no_hlo_text(self, single_runtime, tmp_path, monkeypatch):
+        from dmlcloud_tpu.utils import profiling
+
+        calls = []
+        monkeypatch.setattr(profiling, "write_phase_map", lambda *a, **k: calls.append(a))
+        stage = _MaskedStage(sizes=(8, 8))
+        _run_pipeline(stage, epochs=1, precompile=True)
+        assert stage._train_compiled.signatures == 1 and calls == []

@@ -5,7 +5,7 @@ clock, the stdlib /metrics endpoint, the engine integration
 (``metrics=True`` / ``slos=``), request-scoped trace linkage, the
 flush-on-exit hardening, the observability CLI (``trace`` / ``top`` /
 ``timeline --by-request`` / the diag alert census), and analyze_trace's
-serve mode with its v2 JSON schema."""
+serve mode of its JSON schema."""
 
 import json
 import os
@@ -565,7 +565,7 @@ class TestObservabilityCLI:
 
 
 # ---------------------------------------------------------------------------
-# analyze_trace: serve mode + v2 JSON schema
+# analyze_trace: serve mode of the JSON schema
 # ---------------------------------------------------------------------------
 
 
@@ -604,12 +604,12 @@ def _synthetic_serve_journal(tmp_path):
 
 
 class TestAnalyzeTraceServe:
-    def test_serve_mode_json_schema_v2(self, tmp_path, capsys):
+    def test_serve_mode_json_schema(self, tmp_path, capsys):
         mod = _load_analyze_trace()
         _synthetic_serve_journal(tmp_path)
         assert mod.main([str(tmp_path), "--json"]) == 0
         out = json.loads(capsys.readouterr().out)
-        assert out["version"] == 2
+        assert out["version"] == 3  # the profiler-trace object changed shape (PR 25); "serve" is v2's
         s = out["serve"]
         assert s["requests"] == 2 and s["orphan_spans"] == 0
         assert s["statuses"] == {"ok": 1, "error": 1}
@@ -631,7 +631,7 @@ class TestAnalyzeTraceServe:
         assert mod.main([str(tmp_path)]) == 0
         out = capsys.readouterr().out
         assert "ttft_ms" in out and "2 requests" in out
-        # --tenant is meaningless on a roofline (xplane) directory
+        # --tenant is meaningless on a profiler-trace (xplane) directory
         empty = tmp_path / "empty"
         empty.mkdir()
         assert mod.main([str(empty), "--tenant", "hot"]) == 2

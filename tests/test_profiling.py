@@ -56,26 +56,30 @@ def test_step_timer_reset_forgets_last_tick():
     assert t.count == 1
 
 
-def test_roofline_requires_trace_dir(tmp_path):
-    import pytest
-
-    from dmlcloud_tpu.utils.profiling import roofline
+def test_phase_table_requires_trace_dir(tmp_path):
+    from dmlcloud_tpu.utils.profiling import phase_table
 
     with pytest.raises(FileNotFoundError, match="xplane"):
-        roofline(str(tmp_path))
+        phase_table(str(tmp_path))
 
 
-def test_format_roofline_renders_without_peaks():
-    from dmlcloud_tpu.utils.profiling import format_roofline
+def _table(phases, kernels=()):
+    return {"device": "/device:TPU:0", "profile_start_ns": None, "steps": 2, "busy_ms_per_step": 1.5,
+            "phases": phases, "kernels": list(kernels)}
 
-    peaks = {"device": "X", "peak_tflops": 0.0, "peak_hbm_gbps": 0.0}
+
+def test_format_phase_table_hides_small_rows_and_lists_kernels():
+    from dmlcloud_tpu.utils.profiling import format_phase_table
+
     rows = [
-        {"category": "fusion", "time_frac": 0.9, "ms_per_step": 1.0, "tflops": 2.0, "gbps": 10.0, "n_per_step": 3},
-        {"category": "tiny", "time_frac": 0.0001, "ms_per_step": 0.0, "tflops": 0.0, "gbps": 0.0, "n_per_step": 1},
+        {"phase": "mlp", "direction": "bwd", "time_frac": 0.9, "ms_per_step": 1.0, "n_per_step": 3},
+        {"phase": "embed", "direction": "fwd", "time_frac": 0.0001, "ms_per_step": 0.0, "n_per_step": 1},
     ]
-    out = format_roofline(peaks, rows)
-    assert "fusion" in out and "tiny" not in out  # sub-0.1% rows hidden
-    assert "% of peak" not in out  # no bogus percentage from a zero peak
+    out = format_phase_table(_table(rows))
+    assert "mlp" in out and "bwd" in out and "embed" not in out  # sub-0.1% rows hidden
+    assert "kernel" not in out  # no kernel block without kernels
+    kernel = {"kernel": "flash_fwd", "time_frac": 0.1, "ms_per_step": 0.15, "n_per_step": 2}
+    assert "flash_fwd" in format_phase_table(_table(rows, [kernel]))
 
 
 def _load_analyze_trace():
@@ -95,26 +99,20 @@ def test_analyze_trace_json_schema(monkeypatch, capsys):
     import json
 
     mod = _load_analyze_trace()
-    peaks = {"device": "X", "peak_tflops": 1.0, "peak_hbm_gbps": 2.0}
-    rows = [
-        {"category": "fusion", "time_frac": 1.0, "ms_per_step": 1.0,
-         "tflops": 1.0, "gbps": 1.0, "n_per_step": 1},
-    ]
-    monkeypatch.setattr(mod, "roofline", lambda d, steps=30: (peaks, rows))
-    assert mod.main(["/tmp/whatever", "--json", "--steps", "7"]) == 0
+    table = _table([{"phase": "mlp", "direction": "-", "time_frac": 1.0, "ms_per_step": 1.0, "n_per_step": 1}])
+    seen = {}
+    monkeypatch.setattr(mod, "phase_table", lambda d, **kw: seen.update(kw) or table)
+    assert mod.main(["/tmp/whatever", "--json", "--steps", "7", "--program", "train_step"]) == 0
     out = json.loads(capsys.readouterr().out)
-    # v2 is ADDITIVE over v1: the roofline keys are locked unchanged
-    # (serve-journal inputs add a "serve" object instead — see
-    # tests/test_observability.py)
-    assert out["version"] == 2
-    assert out["steps"] == 7
-    assert out["peaks"] == peaks and out["rows"] == rows
+    # v3: the phase table replaced v2's tensorflow-read roofline keys (serve-journal
+    # inputs still give a "serve" object — see tests/test_observability.py)
+    assert out == {"version": 3, "table": table}
+    assert seen == {"phases": None, "steps": 7, "program": "train_step"}
 
 
 def test_analyze_trace_empty_rows_is_a_clear_message(monkeypatch, capsys):
     mod = _load_analyze_trace()
-    peaks = {"device": "X", "peak_tflops": 1.0, "peak_hbm_gbps": 2.0}
-    monkeypatch.setattr(mod, "roofline", lambda d, steps=30: (peaks, []))
+    monkeypatch.setattr(mod, "phase_table", lambda d, **kw: _table([]))
     assert mod.main(["/tmp/whatever"]) == 1
     err = capsys.readouterr().err
     assert "no XLA op rows" in err and "block_until_ready" in err
@@ -266,3 +264,105 @@ class TestStallTimerLabels:
         assert [r["kind"] for r in recs] == ["checkpoint", "host_stall"]
         assert recs[1]["label"] == "custom_wait"  # label preserved as attr
         j.close()
+
+
+# ---------------------------------------------------------------------------
+# phases: op_name -> (phase, direction), compiled step -> map, trace -> table
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(train_step)/jit(main)/jvp(DecoderLM)/layer_0/attn/q_proj/dot_general", ("attn_proj", "fwd")),
+    ("jit(train_step)/transpose(jvp(DecoderLM))/layer_1/attn/attn_kernel/flash_bwd_dq/pallas_call", ("attn_kernel", "bwd")),
+    ("jit(train_step)/jvp(DecoderLM)/layer_0/mlp_norm/mul", ("norm", "fwd")),
+    ("jit(train_step)/transpose(jvp(DecoderLM))/jvp(DecoderLM)/checkpoint/rematted_computation/layer_0/mlp/mul",
+     ("mlp", "recompute")),
+    ("jit(train_step)/transpose(jvp(DecoderLM))/jvp(DecoderLM)/checkpoint/layer_0/mlp/gate_proj/add_any", ("mlp", "bwd")),
+    ("jit(train_step)/transpose(jvp(mlp))/mul", ("mlp", "bwd")),  # a transform wraps the scope it crosses
+    ("jit(train_step)/optimizer/add", ("optimizer", "-")),
+    ("jit(_paged_step)/DecoderLM/layer_3/attn/kv_write/scatter", ("kv_write", "-")),
+    ("jit(_paged_step)/DecoderLM/head/lm_head/dot_general", ("head", "-")),
+    ("jit(train_step)/jvp(DecoderLM)/layer_0/add", (None, "fwd")),  # a residual: no scope names a phase
+    ("params['layer_0']['attn']['k_proj']['kernel']", (None, "-")),  # a parameter is no operation
+])
+def test_phase_of(op_name, expected):
+    from dmlcloud_tpu.utils.profiling import phase_of
+
+    assert phase_of(op_name) == expected
+
+
+HLO = """HloModule jit_step
+
+%fused_computation (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  %m = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/jvp(M)/attn_norm/mul"}
+  ROOT %a = f32[8]{0} add(%m, %p), metadata={op_name="jit(step)/jvp(M)/mlp/add"}
+}
+
+%fused_computation.1 (p.1: f32[8]) -> (f32[8], f32[8]) {
+  %p.1 = f32[8]{0} parameter(0)
+  %n.1 = f32[8]{0} negate(%p.1), metadata={op_name="jit(step)/optimizer/neg"}
+  %n.2 = f32[8]{0} negate(%n.1), metadata={op_name="jit(step)/optimizer/neg"}
+  %n.3 = f32[8]{0} negate(%n.2), metadata={op_name="jit(step)/grad_clip/neg"}
+  ROOT %t = (f32[8]{0}, f32[8]{0}) tuple(%n.2, %n.3)
+}
+
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0), metadata={op_name="x"}
+  %fusion = f32[8]{0} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/jvp(M)/mlp/add"}
+  %fusion.1 = (f32[8]{0}, f32[8]{0}) fusion(%fusion), kind=kLoop, calls=%fused_computation.1
+  %flash_fwd.3 = f32[8]{0} custom-call(%fusion), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(M)/attn/attn_kernel/flash_fwd/pallas_call"}
+  ROOT %copy = f32[8]{0} copy(%flash_fwd.3)
+}
+"""
+
+
+def test_phase_map_takes_a_fusions_phase_from_its_root():
+    from dmlcloud_tpu.utils.profiling import phase_map
+
+    m = phase_map(HLO)
+    assert m["fusion"] == ("mlp", "fwd")  # the root's phase, though the body also holds a norm
+    assert m["fusion.1"] == ("optimizer", "-")  # a tuple root carries none: what most of the body carries
+    assert m["flash_fwd.3"] == ("attn_kernel", "fwd")
+    assert m["copy"] == (None, "-") and m["x"] == (None, "-")  # reported, with no phase
+
+
+def test_phase_table_reads_a_recorded_tpu_trace_without_tensorflow():
+    """The small profile recorded on a v5e (benchmark/tests): device time by
+    phase through a hand-made map, the clock anchor, kernels none."""
+    import os
+    import sys
+
+    from dmlcloud_tpu.utils.profiling import format_phase_table, phase_table
+
+    fixture = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark", "tests", "fixture.xplane.pb")
+    if not os.path.isfile(fixture):
+        pytest.skip("benchmark/ not present next to the package")
+    table = phase_table(fixture, phases={"convolution_tanh_fusion": ("mlp", "-")}, steps=4, program="bench_fixture_step")
+    assert "tensorflow" not in sys.modules
+    rows = {(r["phase"], r["direction"]): r for r in table["phases"]}
+    assert rows[("mlp", "-")]["ms_per_step"] == pytest.approx(62.978e-3 / 4, rel=1e-6)  # the four fusions
+    assert rows[("mlp", "-")]["n_per_step"] == 1 and rows[("unattributed", "-")]["n_per_step"] == 2  # two copies a run
+    assert sum(r["time_frac"] for r in table["phases"]) == pytest.approx(1.0)
+    assert table["busy_ms_per_step"] == pytest.approx(72.465e-3 / 4, rel=1e-3)
+    assert table["profile_start_ns"] == 1790715310895364939 and table["kernels"] == []
+    assert "mlp" in format_phase_table(table)
+    # another program's name keeps nothing
+    assert phase_table(fixture, program="train_step")["phases"] == []
+
+
+def test_trace_emits_a_profile_span_on_an_armed_journal(tmp_path):
+    from dmlcloud_tpu.telemetry import journal as journal_mod
+    from dmlcloud_tpu.telemetry.journal import SpanJournal
+
+    with trace(str(tmp_path / "quiet")):  # no journal: nothing to emit to, nothing raised
+        pass
+    j = journal_mod.activate(SpanJournal(tmp_path / "journal"))
+    try:
+        with trace(str(tmp_path / "prof")):
+            jnp.ones(4).block_until_ready()
+    finally:
+        journal_mod.deactivate()
+    [rec] = [r for r in j.tail(8) if r["kind"] == "profile"]
+    assert rec["label"].endswith("prof") and 0 <= rec["started_after"] <= rec["dur"]
+    j.close()

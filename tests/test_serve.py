@@ -734,6 +734,145 @@ class TestServeTelemetry:
         assert sum(r["chunk"] for r in pre) == 12  # whole prompt, chunked
 
 
+    def test_call_spans_tile_their_call_and_the_step_encloses_them(self, tiny_model, tmp_path):
+        """Every device call's span is tiled by call_upload, call_launch and
+        call_fetch, preceded by its call_build, and all of them lie inside
+        the engine_step span of the step that made them."""
+        from dmlcloud_tpu.telemetry import journal as journal_mod
+
+        model, params = tiny_model
+        j = journal_mod.activate(journal_mod.SpanJournal(tmp_path, ring_size=4096))
+        try:
+            engine = _engine(model, params)
+            engine.submit(_prompt(12, seed=1), 4)
+            engine.submit(_prompt(5, seed=2), 3)
+            steps = 0
+            while not engine.idle:
+                engine.step()
+                steps += 1
+        finally:
+            journal_mod.deactivate()
+        recs = j.tail(4096)
+        end = lambda r: r["ts"] + r["dur"]
+        close = lambda a, b: abs(a - b) < 2e-6  # ts is rounded to the microsecond
+        by_kind = lambda k: sorted((r for r in recs if r["kind"] == k), key=lambda r: r["ts"])
+        calls = sorted((r for r in recs if r["kind"] in ("prefill", "decode_batch")), key=lambda r: r["ts"])
+        parts = {k: by_kind(k) for k in ("call_build", "call_upload", "call_launch", "call_fetch")}
+        assert len(calls) >= 4 and all(len(v) == len(calls) for v in parts.values())
+        for i, call in enumerate(calls):
+            build, up, launch, fetch = (parts[k][i] for k in ("call_build", "call_upload", "call_launch", "call_fetch"))
+            for part in (build, up, launch, fetch):
+                assert part["parent"] == call["kind"] and part["bucket"] == call["bucket"]
+                assert part["blocks"] == call["blocks"]
+            assert close(up["ts"], call["ts"]) and close(end(up), launch["ts"])
+            assert close(end(launch), fetch["ts"]) and close(end(fetch), end(call))
+            assert up["dur"] + launch["dur"] + fetch["dur"] == pytest.approx(call["dur"], abs=1e-8)
+            assert close(end(build), call["ts"]) and build["ts"] <= call["ts"]
+        engine_steps = by_kind("engine_step")
+        assert len(engine_steps) == steps
+        for r in calls + [x for v in parts.values() for x in v]:
+            assert any(s["ts"] - 2e-6 <= r["ts"] and end(r) <= end(s) + 2e-6 for s in engine_steps), r
+        assert {r["bucket"] for r in calls if r["kind"] == "prefill"} == {1}
+
+    def test_no_journal_no_span_is_built(self, tiny_model, monkeypatch):
+        """Off means off: with no journal armed a step reaches neither an
+        emit nor the code that builds a call's labels and lists."""
+        from dmlcloud_tpu.serve import engine as engine_mod
+        from dmlcloud_tpu.telemetry import journal as journal_mod
+
+        reached = []
+        monkeypatch.setattr(engine_mod.ServeEngine, "_emit_call",
+                            staticmethod(lambda *a, **k: reached.append("emit_call")))
+        monkeypatch.setattr(journal_mod.SpanJournal, "emit", lambda *a, **k: reached.append("emit"))
+        assert journal_mod.active_journal() is None
+        model, params = tiny_model
+        engine = _engine(model, params)
+        engine.submit(_prompt(12, seed=1), 4)
+        engine.run()
+        assert engine.ledger.summary()["completed"] == 1 and reached == []
+
+    def test_each_engine_owns_its_named_trace_cache(self, tiny_model):
+        """The jitted step carries its function's name (the profile's module
+        reads jit__paged_step) and is still a fresh object per engine: one
+        engine's compiles never count against another's TraceGuard budget."""
+        model, params = tiny_model
+        a, b = _engine(model, params, guard="raise"), _engine(model, params, guard="raise")
+        assert a._step_fn._fn is not b._step_fn._fn
+        assert a._step_fn._fn.__name__ == "_paged_step"
+        a.submit(_prompt(9, seed=3), 3)
+        a.run()
+        assert a.compiled_signatures() > 0 and b.compiled_signatures() == 0
+        b.submit(_prompt(9, seed=3), 3)
+        b.run()
+        assert b.compiled_signatures() == a.compiled_signatures() <= b.max_signatures
+
+    def test_phase_map_of_a_paged_step_signature(self, tiny_model):
+        """engine.phase_map compiles one signature on demand: every scope of
+        the serve step is a phase in it, none with a direction, the rest is
+        reported, and the engine's own signature count does not move."""
+        from dmlcloud_tpu.utils.profiling import _hlo_computations, phase_of
+
+        model, params = tiny_model
+        engine = _engine(model, params)
+        engine.submit(_prompt(6, seed=4), 2)
+        engine.run()
+        before = engine.compiled_signatures()
+        decode = engine.phase_map(2, 4)
+        prefill = engine.phase_map(1, 4, prefill=True)
+        assert engine.compiled_signatures() == before
+        want = {"embed", "norm", "attn_proj", "kv_write", "kv_gather", "attention", "mlp", "head", "sampling"}
+        for m in (decode, prefill):
+            assert {p for p, _ in m.values() if p} == want
+            assert {d for p, d in m.values() if p} == {"-"}
+            rest = [n for n, (p, _) in m.items() if p is None]
+            assert rest and len(rest) < len(m)
+        specs = engine._paged_step_specs(2, 4, 1)
+        text = engine._step_fn._fn.lower(*specs, model=engine.model).compile().as_text()
+        assert text.startswith("HloModule jit__paged_step")
+        for instructions in _hlo_computations(text).values():
+            for name, op_name, _, _ in instructions:
+                if op_name and phase_of(op_name)[0]:
+                    assert decode[name] == phase_of(op_name), (name, op_name)
+
+    def test_arrival_counts_from_when_the_request_was_due(self, tiny_model, tmp_path):
+        """A caller that queued the request itself passes the time it was
+        due: the ledger's arrival, the queue_wait span and TTFT count from
+        it; a time after the engine's clock is refused."""
+        import time
+
+        from dmlcloud_tpu.telemetry import journal as journal_mod
+
+        model, params = tiny_model
+        engine = _engine(model, params)
+        due = time.perf_counter() - 0.25
+        j = journal_mod.activate(journal_mod.SpanJournal(tmp_path))
+        try:
+            late = engine.submit(_prompt(6, seed=1), 2, arrival=due)
+            now = engine.submit(_prompt(6, seed=2), 2)
+            engine.run()
+        finally:
+            journal_mod.deactivate()
+        recs = engine.ledger.records
+        assert recs[late]["arrival"] == due and recs[now]["arrival"] > due + 0.25
+        assert recs[late]["admitted"] - recs[late]["arrival"] >= 0.25
+        assert recs[late]["first_token"] - recs[late]["arrival"] >= 0.25
+        waits = {r["request"]: r["dur"] for r in j.tail(256) if r["kind"] == "queue_wait"}
+        assert waits[late] >= 0.25 > waits[now]
+        with pytest.raises(ValueError, match="after the engine's clock"):
+            engine.submit(_prompt(6, seed=3), 2, arrival=time.perf_counter() + 60.0)
+
+    def test_ledger_counts_prompt_tokens_prefilled(self, tiny_model):
+        model, params = tiny_model
+        engine = _engine(model, params)  # prefill_chunk 8: a 12-token prompt takes two chunks
+        engine.submit(_prompt(12, seed=1), 2)
+        engine.submit(_prompt(5, seed=2), 2)
+        assert engine.ledger.prefilled_tokens == 0
+        engine.step()
+        assert engine.ledger.prefilled_tokens == 8
+        engine.run()
+        assert engine.ledger.prefilled_tokens == 17
+
+
 # ---------------------------------------------------------------------------
 # refcounted pool: the free + unique-live == capacity invariant under sharing
 # ---------------------------------------------------------------------------

@@ -61,6 +61,10 @@ V1_KINDS = {
     # (named "preflight" because "verify" was already the spec-decode
     # verification pass)
     "preflight",
+    # serve call split (PR 25): one engine step, the host work before a device
+    # call, the call's uploads, launch and fetch; a profile the program took
+    "engine_step", "call_build", "call_upload", "call_launch", "call_fetch",
+    "profile",
 }
 
 #: Core fields every v1 record carries, with their types.
