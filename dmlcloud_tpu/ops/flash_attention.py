@@ -6,24 +6,41 @@ because it is *the* hot op of the transformer configs in BASELINE.json.
 
 Kernel design (online-softmax, Dao-style but TPU-shaped):
 
-- Forward grid: ``(batch*heads, T/block_q, S/block_k)`` — K/V stream through
-  the innermost *grid* axis, so VMEM holds one [block_k, D] tile of each at a
-  time (Mosaic double-buffers the pipeline); sequence length never enters the
-  VMEM footprint. The online-softmax carry (running max/denominator/output
-  accumulator, fp32) lives in VMEM scratch, persisting across the K-block
-  axis. No [T, S] score matrix ever materialises. The differentiable path
-  also writes the per-row logsumexp (the FlashAttention-2 residual: O and
-  LSE, nothing else).
+- A block plan (``_BlockPlan``), made once at trace time from
+  ``(T, S, block_q, block_k, causal, window)``: for each query block the band
+  of key blocks that hold a pair the mask keeps (and the mirror, for each key
+  block its band of query blocks), and for each such pair whether the mask's
+  edge crosses it. Plain integer arithmetic; the three kernels, their index
+  maps and the tests all read it, so forward and backward cannot drift.
+- Forward grid: ``(batch*heads, T/block_q, widest band)`` — K/V stream through
+  the innermost *grid* axis, offset by the band's first block, so VMEM holds
+  one [block_k, D] tile of each at a time (Mosaic double-buffers the
+  pipeline); sequence length never enters the VMEM footprint, and key blocks
+  past the diagonal or older than the window are not grid steps at all (the
+  few padded steps of bands shorter than the widest skip their body and
+  re-request the band's last block, which elides their DMA). The
+  online-softmax carry (running max/denominator/output accumulator, fp32)
+  lives in VMEM scratch, persisting across the band. No [T, S] score matrix
+  ever materialises. The differentiable path also writes the per-row
+  logsumexp (the FlashAttention-2 residual: O and LSE, nothing else).
 - Backward: two kernels sharing the saved LSE and the precomputed
   ``delta = rowsum(dO * O)``. The dQ kernel mirrors the forward grid
-  (one query block, K/V on the innermost grid axis, dq in scratch); the
-  dK/dV kernel transposes it (one KV block, Q/dO on the innermost axis).
-  Probabilities are recomputed as ``exp(s - lse)`` — no second softmax pass,
-  no saved [T, S] matrix.
+  (one query block, its band of K/V on the innermost grid axis, dq in
+  scratch); the dK/dV kernel transposes it (one KV block, its band of Q/dO
+  on the innermost axis). Probabilities are recomputed as ``exp(s - lse)`` —
+  no second softmax pass, no saved [T, S] matrix.
 - MXU does the matmuls with fp32 accumulation (``preferred_element_type``);
-  VPU does the exp/renormalisation.
-- Causal masking skips *entire* blocks past the diagonal in both directions
-  (loop bounds depend on ``program_id``), and masks only the diagonal block.
+  VPU does the exp/renormalisation. Each kernel holds two bodies and a
+  scalar from the plan picks one per step: the causal-and-window mask (two
+  iotas, two compares, an and, a select per element) is computed only in the
+  pairs its edge crosses; a pair wholly inside the mask runs the same body
+  without it (and, in the forward of an unpacked batch, without the dead-row
+  select). The segment mask stays in every pair of a packed batch: its edges
+  are data.
+- Block shapes: 512 x 1024 unless a sweep on the v5e chose another for exactly
+  the call's (head_dim, T, S, causal, window) (``_SWEPT_BLOCKS``; constants —
+  nothing is tuned or timed at run time or import time). Explicit
+  ``block_q`` / ``block_k`` win.
 - GQA: the K/V block index map folds the query head onto its KV head, so
   grouped heads reread the same VMEM block instead of materialising repeats;
   the backward accumulates per-query-head dK/dV and group-sums outside the
@@ -45,6 +62,7 @@ kernel emulation for kernel-logic tests. Both Pallas modes need
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -83,17 +101,16 @@ def _default_mode(interpret: bool | None):
     return False if jax.default_backend() == "tpu" else "xla"
 
 
-def _window_mask(s, q0, k0, q_block, block_k, causal: bool, window: int | None):
-    """Apply causal (and optional sliding-window) masking to a [bq, bk] score
-    block whose top-left element is (q0, k0). ``window`` = W keeps
+def _window_mask(s, plan: "_BlockPlan", qb, kb):
+    """Apply causal (and optional sliding-window) masking to the [bq, bk] score
+    block of pair (``qb``, ``kb``) of a masked plan. ``window`` = W keeps
     ``q_pos - k_pos < W`` (self + W-1 predecessors), the Mistral convention."""
-    if not causal and window is None:
-        return s
-    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (q_block, block_k), 0)
-    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (q_block, block_k), 1)
-    keep = q_pos >= k_pos if causal else None
-    if window is not None:
-        wkeep = (q_pos - k_pos) < window
+    shape = (plan.block_q, plan.block_k)
+    q_pos = qb * plan.block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    k_pos = kb * plan.block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    keep = q_pos >= k_pos if plan.causal else None
+    if plan.window is not None:
+        wkeep = (q_pos - k_pos) < plan.window
         keep = wkeep if keep is None else keep & wkeep
     return jnp.where(keep, s, _NEG_INF)
 
@@ -114,48 +131,133 @@ def _segment_mask(s, seg_q_ref, seg_kv_ref):
     return jnp.where(q_ids == k_ids, s, _NEG_INF)
 
 
-def _maybe_when(cond, fn):
-    """Run ``fn`` under ``pl.when`` unless the condition is statically True."""
-    if cond is True:
-        fn()
-    else:
-        pl.when(cond)(fn)
+def _least(a, b):
+    """min() over Python ints (the tests' and the wrappers' reading of the
+    plan) or traced scalars (the kernels' and index maps')."""
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
 
 
-def _kv_skip_cond(qi, kb, q_block: int, block_k: int, causal: bool, window: int | None):
-    """Participation condition for a (q-block, streamed K-block) pair —
-    shared by the forward and dQ kernels so their skip bounds can never
-    drift from each other (a divergence would feed exp(s - lse) garbage
-    into whichever side still ran the block)."""
-    cond = True
-    if causal:
-        cond = kb * block_k <= qi * q_block + q_block - 1
-    if window is not None:
-        cond &= kb * block_k + block_k - 1 >= qi * q_block - window + 1
-    return cond
+def _most(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
 
 
-def _q_skip_cond(qb, kb, block_q: int, k_block: int, causal: bool, window: int | None):
-    """The dK/dV kernel's transposed participation condition (fixed KV
-    block, streamed Q block) — the mirror of :func:`_kv_skip_cond`."""
-    cond = True
-    if causal:
-        cond = (qb + 1) * block_q - 1 >= kb * k_block
-    if window is not None:
-        cond &= qb * block_q <= kb * k_block + k_block + window - 2
-    return cond
+@dataclasses.dataclass(frozen=True)
+class _BlockPlan:
+    """Which (query block, key block) pairs the causal-and-window mask keeps,
+    and which of those its edge crosses — made once at trace time from the
+    shapes and the mask arguments, and read by all three kernels, their index
+    maps and the tests, so forward and backward can never drift (a divergence
+    would feed exp(s - lse) garbage into whichever side still ran the block).
+
+    The mask keeps (q, k) iff ``k <= q`` (causal) and ``q - k < window``
+    (window; the ring's behind-hops call with ``causal=False`` and a shifted,
+    possibly negative, window). The pairs a query block holds are one band of
+    consecutive key blocks, and the mirror for a key block: the grids' inner
+    axis runs over the widest band only, offset by the band's first block.
+    Every method is plain integer arithmetic that takes Python ints or traced
+    scalars alike.
+    """
+
+    t: int
+    s: int
+    block_q: int
+    block_k: int
+    causal: bool
+    window: int | None
+
+    @property
+    def masked(self) -> bool:
+        """False when no pair is ever masked or skipped (the full rectangle)."""
+        return self.causal or self.window is not None
+
+    @property
+    def num_qb(self) -> int:
+        return self.t // self.block_q
+
+    @property
+    def num_kb(self) -> int:
+        return self.s // self.block_k
+
+    def kv_band(self, qi):
+        """(first, last) key block holding a pair of query block ``qi``;
+        ``last < first`` when none does. ``last`` is always a valid block."""
+        first, last = 0, self.num_kb - 1
+        if self.window is not None:
+            first = _most(qi * self.block_q - self.window + 1, 0) // self.block_k
+        if self.causal:
+            last = _least((qi * self.block_q + self.block_q - 1) // self.block_k, last)
+        return first, last
+
+    def q_band(self, kb):
+        """The mirror, for the dK/dV kernel: (first, last) query block holding
+        a pair of key block ``kb``. ``first`` is always a valid block; ``last``
+        is negative when a negative window empties the band."""
+        first, last = 0, self.num_qb - 1
+        if self.causal:
+            first = (kb * self.block_k) // self.block_q
+        if self.window is not None:
+            last = _least((kb * self.block_k + self.block_k + self.window - 2) // self.block_q, last)
+        return first, last
+
+    @functools.cached_property
+    def kv_width(self) -> int:
+        """Key blocks in the widest band: the forward and dQ grids' inner axis."""
+        return max(1, max(last - first + 1 for first, last in map(self.kv_band, range(self.num_qb))))
+
+    @functools.cached_property
+    def q_width(self) -> int:
+        return max(1, max(last - first + 1 for first, last in map(self.q_band, range(self.num_kb))))
+
+    def interior(self, qi, kb):
+        """True when no element of pair (``qi``, ``kb``) is masked: its body
+        needs no :func:`_window_mask`."""
+        inside = True
+        if self.causal:
+            inside = kb * self.block_k + self.block_k - 1 <= qi * self.block_q
+        if self.window is not None:
+            inside &= qi * self.block_q + self.block_q - 1 - kb * self.block_k < self.window
+        return inside
+
+    @staticmethod
+    def _step(band, j):
+        first, last = band
+        blk = first + j
+        return blk, blk <= last, _most(_least(blk, last), 0)
+
+    def kv_step(self, qi, j):
+        """Inner grid step ``j`` of query block ``qi``'s band: (the key block
+        it stands for, whether the band holds it, the block its DMA asks for).
+        The padded steps of a short band re-request the band's last block —
+        Mosaic elides the DMA when consecutive steps map to the same block,
+        saving the HBM traffic that ``pl.when`` alone would still copy and
+        discard."""
+        return self._step(self.kv_band(qi), j)
+
+    def q_step(self, kb, j):
+        """The mirror: step ``j`` of key block ``kb``'s band of query blocks."""
+        return self._step(self.q_band(kb), j)
+
+
+def _visit(plan: _BlockPlan, held, interior, body):
+    """Run ``body(masked)`` for one grid step: not at all for a padded step,
+    without the window mask for an interior pair, with it for a pair the
+    mask's edge crosses. The conditions are scalars, so exactly one body runs."""
+    if not plan.masked:
+        body(False)
+        return
+    pl.when(held & interior)(lambda: body(False))
+    pl.when(held & jnp.logical_not(interior))(lambda: body(True))
 
 
 def _attn_kernel(
-    q_ref, k_ref, v_ref, *rest, block_k: int, causal: bool, sm_scale: float, q_block: int,
-    num_kb: int, window: int | None, with_segments: bool = False
+    q_ref, k_ref, v_ref, *rest, plan: _BlockPlan, sm_scale: float, with_segments: bool = False
 ):
-    # Grid (B*H, T/block_q, S/block_k) — K/V STREAM through the innermost
-    # grid axis, so VMEM holds one [block_k, D] tile of each at a time (plus
-    # Mosaic's pipeline double-buffer) regardless of sequence length; the
-    # whole-sequence layout of the first design collided with the ~16 MB VMEM
-    # budget around S≈32k. The online-softmax carry (m, l, acc) lives in VMEM
-    # scratch, persisting across the kb axis for a fixed (bh, qi).
+    # Grid (B*H, T/block_q, plan.kv_width) — K/V STREAM through the innermost
+    # grid axis, one step per key block of the query block's band, so VMEM
+    # holds one [block_k, D] tile of each at a time (plus Mosaic's pipeline
+    # double-buffer) regardless of sequence length. The online-softmax carry
+    # (m, l, acc) lives in VMEM scratch, persisting across the band for a
+    # fixed (bh, qi).
     #
     # q_ref: [1, block_q, D]; k_ref/v_ref: [1, block_k, D]; o_ref: [1, block_q, D];
     # optional lse_ref: [1, block_q, _LANES] — the FlashAttention-2 residual,
@@ -171,15 +273,16 @@ def _attn_kernel(
     else:
         (m_ref, l_ref, acc_ref), lse_ref = rest, None
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    j = pl.program_id(2)
+    kb, held, _ = plan.kv_step(qi, j)
 
-    @pl.when(kb == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _accumulate():
+    def _accumulate(masked: bool):
         q = q_ref[0]  # [bq, D] — native dtype: bf16 operands keep the MXU fast
         k = k_ref[0]  # [bk, D]
         v = v_ref[0]
@@ -187,7 +290,8 @@ def _attn_kernel(
             jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             * sm_scale
         )  # [bq, bk] fp32
-        s = _window_mask(s, qi * q_block, kb * block_k, q_block, block_k, causal, window)
+        if masked:
+            s = _window_mask(s, plan, qi, kb)
         s = _segment_mask(s, seg_q_ref, seg_kv_ref)
         m_prev = m_ref[:, :1]  # [bq, 1]
         l_prev = l_ref[:, :1]
@@ -195,10 +299,12 @@ def _attn_kernel(
         new_m = jnp.maximum(m_prev, blk_max)
         correction = jnp.exp(m_prev - new_m)
         p = jnp.exp(s - new_m)  # [bq, bk]
-        # a row fully masked within this visited block has s == new_m ==
-        # _NEG_INF, making p == exp(0) == 1 per masked entry — zero it so
-        # dead rows really keep l == 0 / out == 0 (not a mean of V)
-        p = jnp.where(blk_max > _NEG_INF / 2, p, 0.0)
+        if masked or with_segments:
+            # a row fully masked within this visited block has s == new_m ==
+            # _NEG_INF, making p == exp(0) == 1 per masked entry — zero it so
+            # dead rows really keep l == 0 / out == 0 (not a mean of V). An
+            # interior pair of an unpacked batch masks nothing: no dead rows
+            p = jnp.where(blk_max > _NEG_INF / 2, p, 0.0)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -206,20 +312,20 @@ def _attn_kernel(
         l_ref[...] = jnp.broadcast_to(l_prev * correction + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
         acc_ref[...] = acc_ref[...] * correction + pv
 
-    # K blocks fully past the diagonal (causal) or entirely older than the
-    # window contribute nothing — skip them (window applies without causal
-    # too: the ring's behind-hops call with causal=False and a shifted
-    # window)
-    _maybe_when(_kv_skip_cond(qi, kb, q_block, block_k, causal, window), _accumulate)
+    # only the key blocks of this query block's band are grid steps at all:
+    # none past the diagonal (causal) or entirely older than the window
+    # (window applies without causal too: the ring's behind-hops call with
+    # causal=False and a shifted window)
+    _visit(plan, held, plan.interior(qi, kb), _accumulate)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(j == plan.kv_width - 1)
     def _write():
-        # dead rows (every K block skipped, or fully masked in every block
-        # actually visited — both possible for windowed non-causal ring
-        # hops) keep l == 0 thanks to the dead-row p-zeroing above: the tiny
-        # floor makes their output 0 and their lse ~ -1e30 - 69 (FINITE, so
-        # the ring merge weight underflows to exactly 0 and the backward's
-        # exp(s - lse) stays finite); live rows always have l >~ 1, untouched
+        # dead rows (an empty band, or fully masked in every block actually
+        # visited — both possible for windowed non-causal ring hops) keep
+        # l == 0 thanks to the dead-row p-zeroing above: the tiny floor makes
+        # their output 0 and their lse ~ -1e30 - 69 (FINITE, so the ring merge
+        # weight underflows to exactly 0 and the backward's exp(s - lse) stays
+        # finite); live rows always have l >~ 1, untouched
         l_safe = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l_safe[:, :1]).astype(o_ref.dtype)
         if lse_ref is not None:
@@ -228,25 +334,25 @@ def _attn_kernel(
 
 def _dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_k: int, causal: bool, sm_scale: float, q_block: int, num_kb: int, window: int | None,
-    with_segments: bool = False
+    plan: _BlockPlan, sm_scale: float, with_segments: bool = False
 ):
     if with_segments:
         seg_q_ref, seg_kv_ref, dq_ref, acc_ref = rest
     else:
         (dq_ref, acc_ref), seg_q_ref, seg_kv_ref = rest, None, None
-    # Grid (B*H, T/block_q, S/block_k): K/V stream through the innermost grid
-    # axis (same VMEM-bounded layout as the forward); dq accumulates in fp32
-    # VMEM scratch across kb and is written once at the last K block.
+    # Grid (B*H, T/block_q, plan.kv_width): the forward's band of K/V blocks
+    # on the innermost grid axis (same VMEM-bounded layout); dq accumulates in
+    # fp32 VMEM scratch across the band and is written once at its last step.
     # lse_ref/delta_ref: [1, block_q, _LANES], lane-broadcast per-row stats.
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
+    j = pl.program_id(2)
+    kb, held, _ = plan.kv_step(qi, j)
 
-    @pl.when(kb == 0)
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _accumulate():
+    def _accumulate(masked: bool):
         q = q_ref[0]  # [bq, D] — native dtype operands, fp32 accumulation
         do = do_ref[0]  # [bq, D]
         lse = lse_ref[0][:, :1]  # [bq, 1]
@@ -257,7 +363,8 @@ def _dq_kernel(
             jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             * sm_scale
         )  # [bq, bk]
-        s = _window_mask(s, qi * q_block, kb * block_k, q_block, block_k, causal, window)
+        if masked:
+            s = _window_mask(s, plan, qi, kb)
         s = _segment_mask(s, seg_q_ref, seg_kv_ref)
         p = jnp.exp(s - lse)  # [bq, bk] fp32; masked entries underflow to 0
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -266,35 +373,35 @@ def _dq_kernel(
             ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
-    _maybe_when(_kv_skip_cond(qi, kb, q_block, block_k, causal, window), _accumulate)
+    _visit(plan, held, plan.interior(qi, kb), _accumulate)
 
-    @pl.when(kb == num_kb - 1)
+    @pl.when(j == plan.kv_width - 1)
     def _write():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_q: int, causal: bool, sm_scale: float, k_block: int, window: int | None,
-    with_segments: bool = False
+    plan: _BlockPlan, sm_scale: float, with_segments: bool = False
 ):
     if with_segments:
         seg_q_ref, seg_kv_ref, dk_ref, dv_ref = rest
     else:
         (dk_ref, dv_ref), seg_q_ref, seg_kv_ref = rest, None, None
-    # grid (B*H, S/block_k, T/block_q): one KV block accumulates across the
-    # innermost q-block dimension (dk/dv output blocks are revisited — they
-    # stay resident in VMEM until kb advances). Q/dO/stats stream per step,
-    # so VMEM use is O(block) regardless of sequence length.
+    # grid (B*H, S/block_k, plan.q_width): one KV block accumulates across
+    # its band of q blocks on the innermost axis (dk/dv output blocks are
+    # revisited — they stay resident in VMEM until kb advances). Q/dO/stats
+    # stream per step, so VMEM use is O(block) regardless of sequence length.
     kb = pl.program_id(1)
-    qb = pl.program_id(2)
+    j = pl.program_id(2)
+    qb, held, _ = plan.q_step(kb, j)
 
-    @pl.when(qb == 0)
+    @pl.when(j == 0)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
 
-    def _accumulate():
+    def _accumulate(masked: bool):
         k = k_ref[0]  # [bk, D] — native dtype operands, fp32 accumulation
         v = v_ref[0]
         q = q_ref[0]  # [bq, D]
@@ -305,7 +412,8 @@ def _dkv_kernel(
             jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             * sm_scale
         )  # [bq, bk]
-        s = _window_mask(s, qb * block_q, kb * k_block, block_q, k_block, causal, window)
+        if masked:
+            s = _window_mask(s, plan, qb, kb)
         s = _segment_mask(s, seg_q_ref, seg_kv_ref)
         p = jnp.exp(s - lse)  # [bq, bk] fp32
         dv_ref[0] += jax.lax.dot_general(
@@ -317,9 +425,10 @@ def _dkv_kernel(
             ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
         ).astype(dk_ref.dtype)
 
-    # skip q blocks entirely above the diagonal (causal — their p is all
-    # zero) or entirely past k_last + window (windowed, causal or not)
-    _maybe_when(_q_skip_cond(qb, kb, block_q, k_block, causal, window), _accumulate)
+    # only q blocks that hold a pair are steps: none entirely above the
+    # diagonal (causal — their p is all zero) or entirely past
+    # k_last + window (windowed, causal or not)
+    _visit(plan, held, plan.interior(qb, kb), _accumulate)
 
 
 def _auto_block(requested: int, seq: int) -> int:
@@ -337,7 +446,32 @@ def _auto_block(requested: int, seq: int) -> int:
     return blk
 
 
-def _reference_attention(q, k, v, causal: bool, sm_scale: float, window: int | None = None):
+#: the block shape of every call whose shapes no sweep covered
+_DEFAULT_BLOCKS = (512, 1024)
+#: (block_q, block_k) where a sweep on the v5e chose them (PERF.md section 6,
+#: PR 27: each of the three kernels was fastest at this shape), keyed on what
+#: the call can see: (head_dim, T, S, causal, window). Constants: nothing is
+#: timed at run time.
+_SWEPT_BLOCKS: dict[tuple, tuple[int, int]] = {
+    (128, 8192, 8192, True, 4096): (1024, 1024),
+}
+
+
+def _plan_for(block_q, block_k, d, t, s, causal, window) -> _BlockPlan:
+    """The block plan of one call's kernels: an explicit block wins, then the
+    sweep's choice for exactly these shapes, then the default; each shrunk to
+    divide its sequence."""
+    swept = _SWEPT_BLOCKS.get((d, t, s, causal, window), _DEFAULT_BLOCKS)
+    block_q = _auto_block(swept[0] if block_q is None else block_q, t)
+    block_k = _auto_block(swept[1] if block_k is None else block_k, s)
+    if t % block_q or s % block_k:
+        raise ValueError(f"seq lens ({t}, {s}) must be multiples of block sizes ({block_q}, {block_k})")
+    return _BlockPlan(t, s, block_q, block_k, causal, window)
+
+
+def _reference_attention(
+    q, k, v, causal: bool, sm_scale: float, window: int | None = None, segment_ids=None
+):
     """Unfused GQA attention (fp32 softmax) — the numerical reference for tests."""
     b, t, h, d = q.shape
     s, kh = k.shape[1], k.shape[2]
@@ -350,6 +484,9 @@ def _reference_attention(q, k, v, causal: bool, sm_scale: float, window: int | N
             dist = jnp.arange(t)[:, None] - jnp.arange(s)[None, :] + (s - t)
             mask = mask & (dist < window)
         scores = jnp.where(mask[None, None, None], scores, _NEG_INF)
+    if segment_ids is not None:
+        same = segment_ids[:, :, None] == segment_ids[:, None, :]  # [B, T, S], T == S
+        scores = jnp.where(same[:, None, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", probs, v)
     return out.reshape(b, t, h, d)
@@ -374,8 +511,8 @@ def flash_attention(
     ``window`` = W enables sliding-window attention (requires ``causal``):
     each query attends to itself and its W-1 predecessors
     (``q_pos - k_pos < W``, the Mistral convention). K/V blocks entirely
-    older than the window are skipped in the grid AND their DMAs elided, so
-    compute and HBM traffic scale with O(T·W) instead of O(T²).
+    older than the window are not grid steps at all, so compute and HBM
+    traffic scale with O(T·W) instead of O(T²).
 
     ``segment_ids`` ([B, T] int32, requires T == S) masks cross-segment
     pairs for packed-sequence training; composes with ``causal`` and
@@ -399,9 +536,12 @@ def flash_attention(
     Default Pallas blocks are large (512x1024) because the grid-step
     overhead, not VMEM, is the binding constraint on TPU: measured on v5e,
     256x256 blocks LOSE to the unfused einsum path while 512x1024 is ~1.5x
-    faster at S=4k and ~2.3x at S=8k (fwd, causal, d=64..128). The XLA path
-    defaults to 128-row query blocks (block_k is ignored there: each query
-    block reads its causally/window-truncated K slice in one piece).
+    faster at S=4k and ~2.3x at S=8k (fwd, causal, d=64..128). Where a sweep
+    on the v5e covered exactly this call's shapes, the kernels take the
+    sweep's choice instead (``_SWEPT_BLOCKS``); an explicit ``block_q`` /
+    ``block_k`` wins over both. The XLA path defaults to
+    128-row query blocks (block_k is ignored there: each query block reads
+    its causally/window-truncated K slice in one piece).
 
     With ``return_lse=True`` returns ``(out, lse)`` where ``lse`` is the
     per-row logsumexp of the scaled scores, shape [B, T, H] — the residual a
@@ -426,10 +566,6 @@ def flash_attention(
         mode = bool(interpret)
     else:
         raise ValueError(f"impl must be 'pallas', 'xla' or None, got {impl!r}")
-    if block_q is None:
-        block_q = _XLA_BLOCK_Q if mode == "xla" else 512
-    if block_k is None:
-        block_k = 1024
     if causal and t != k.shape[1]:
         # the kernels mask with top-left alignment (q_pos >= k_pos); a
         # KV-cache-style bottom-right alignment for T != S is a different
@@ -449,11 +585,13 @@ def flash_attention(
             raise ValueError(f"segment_ids must be [B, T] == {(b, t)}, got {segment_ids.shape}")
         if t != k.shape[1]:
             raise ValueError("segment_ids require equal Q/KV sequence lengths (self-attention packing)")
-    bq, bk = _auto_block(block_q, t), _auto_block(block_k, k.shape[1])
+    if mode == "xla":
+        block_q = _auto_block(_XLA_BLOCK_Q if block_q is None else block_q, t)
+    # the Pallas impls resolve a block left None themselves (_plan_for)
     if return_lse:
-        out, lse = _flash_lse(q, k, v, segment_ids, causal, float(sm_scale), bq, bk, mode, window)
+        out, lse = _flash_lse(q, k, v, segment_ids, causal, float(sm_scale), block_q, block_k, mode, window)
         return out, lse.reshape(b, h, t).transpose(0, 2, 1)  # [B, T, H]
-    return _flash(q, k, v, segment_ids, causal, float(sm_scale), bq, bk, mode, window)
+    return _flash(q, k, v, segment_ids, causal, float(sm_scale), block_q, block_k, mode, window)
 
 
 def dividing_batch_axes(mesh, batch_size: int) -> tuple | None:
@@ -575,46 +713,6 @@ def _make_kv_index(h: int, kh: int):
         return (bh // h) * kh + (bh % h) // group
 
     return kv_index
-
-
-def _clamp_kv_stream(kb, qi, block_q: int, block_k: int, causal: bool, window: int | None = None, num_kb: int = 1):
-    """Clamp the streamed K-block index under causal masking so fully skipped
-    grid steps (past the diagonal — and, with a sliding window, older than
-    the window) re-request an adjacent participating block index — Mosaic
-    elides the DMA when consecutive steps map to the same block, saving the
-    K/V HBM traffic that `pl.when` alone would still copy and discard."""
-    if not causal and window is None:
-        return kb
-    lo = None
-    if window is not None:
-        # cap inside the grid: a strongly negative shifted window can push
-        # the raw lo past the last block — the pl.when skip covers those
-        # steps, but the INDEX handed to the DMA must still be in range
-        lo = jnp.minimum(jnp.maximum(qi * block_q - window + 1, 0) // block_k, num_kb - 1)
-    if not causal:
-        return jnp.maximum(kb, lo)
-    hi = ((qi + 1) * block_q - 1) // block_k
-    if lo is not None:
-        return jnp.clip(kb, lo, hi)
-    return jnp.minimum(kb, hi)
-
-
-def _clamp_q_stream(qb, kb, block_q: int, block_k: int, causal: bool, window: int | None = None):
-    """Same trick for the dK/dV kernel's streamed Q axis: Q blocks entirely
-    above the diagonal (or, with a sliding window, entirely past
-    k_last + window) for this KV block are clamped to an adjacent
-    participating block."""
-    if not causal and window is None:
-        return qb
-    hi = None
-    if window is not None:
-        hi = jnp.maximum(kb * block_k + block_k - 1 + window - 1, 0) // block_q
-    if not causal:
-        return jnp.clip(qb, 0, hi)
-    lo = (kb * block_k) // block_q
-    if hi is not None:
-        return jnp.clip(qb, lo, hi)
-    return jnp.maximum(qb, lo)
 
 
 def _seg_layouts(seg, b, t, s):
@@ -768,6 +866,12 @@ def _xla_bwd(
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+# The two impls are jitted for set-up's sake, not for speed: under an outer
+# jit the call is inlined all the same, but jit keeps the traced kernels, so a
+# step that calls the op once a layer — and is traced twice before it runs,
+# by the stage's eval_shape and by .lower() — traces and lowers each kernel
+# once, not once a layer and a trace (PERF.md, PR 27: the set-up split).
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8), static_argnames=("with_residuals",))
 def _flash_fwd_impl(
     q, k, v, causal, sm_scale, block_q, block_k, mode, window=None, seg=None, with_residuals=False
 ):
@@ -779,55 +883,42 @@ def _flash_fwd_impl(
             "flash_attention needs jax.experimental.pallas.tpu (VMEM scratch accumulators); "
             "it failed to import in this jax build"
         )
+    if q.shape[2] % k.shape[2]:
+        raise ValueError(f"query heads {q.shape[2]} not a multiple of kv heads {k.shape[2]}")
     b, t, h, d = q.shape
     s, kh = k.shape[1], k.shape[2]
-    if h % kh:
-        raise ValueError(f"query heads {h} not a multiple of kv heads {kh}")
-    if t % block_q or s % block_k:
-        raise ValueError(f"seq lens ({t}, {s}) must be multiples of block sizes ({block_q}, {block_k})")
+    plan = _plan_for(block_q, block_k, d, t, s, causal, window)
+    block_q, block_k = plan.block_q, plan.block_k
 
     qt = _fold_heads(q)
     kt = _fold_heads(k)
     vt = _fold_heads(v)
     kv_index = _make_kv_index(h, kh)
-    num_kb = s // block_k
-
-    kernel = functools.partial(
-        _attn_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale, q_block=block_q,
-        num_kb=num_kb, window=window, with_segments=seg is not None,
-    )
     vmem = {"memory_space": _VMEM}
 
-    def kv_block(bh, qi, kb):
-        return (kv_index(bh), _clamp_kv_stream(kb, qi, block_q, block_k, causal, window, num_kb), 0)
-
     in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem),
-        pl.BlockSpec((1, block_k, d), kv_block, **vmem),
-        pl.BlockSpec((1, block_k, d), kv_block, **vmem),
+        pl.BlockSpec((1, block_q, d), lambda bh, qi, j: (bh, qi, 0), **vmem),
+        pl.BlockSpec((1, block_k, d), lambda bh, qi, j: (kv_index(bh), plan.kv_step(qi, j)[2], 0), **vmem),
+        pl.BlockSpec((1, block_k, d), lambda bh, qi, j: (kv_index(bh), plan.kv_step(qi, j)[2], 0), **vmem),
     ]
     operands = [qt, kt, vt]
     if seg is not None:
         seg_q3, seg_kv3 = _seg_layouts(seg, b, t, s)
-        in_specs.append(pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, kb: (bh // h, qi, 0), **vmem))
+        in_specs.append(pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, j: (bh // h, qi, 0), **vmem))
         in_specs.append(
-            pl.BlockSpec(
-                (1, _SUBLANES, block_k),
-                lambda bh, qi, kb: (bh // h, 0, _clamp_kv_stream(kb, qi, block_q, block_k, causal, window, num_kb)),
-                **vmem,
-            )
+            pl.BlockSpec((1, _SUBLANES, block_k), lambda bh, qi, j: (bh // h, 0, plan.kv_step(qi, j)[2]), **vmem)
         )
         operands += [seg_q3, seg_kv3]
 
     out_shape = [jax.ShapeDtypeStruct((b * h, t, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem)]
+    out_specs = [pl.BlockSpec((1, block_q, d), lambda bh, qi, j: (bh, qi, 0), **vmem)]
     if with_residuals:
         out_shape.append(jax.ShapeDtypeStruct((b * h, t, _LANES), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, kb: (bh, qi, 0), **vmem))
+        out_specs.append(pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, j: (bh, qi, 0), **vmem))
     results = pl.pallas_call(
-        kernel,
+        functools.partial(_attn_kernel, plan=plan, sm_scale=sm_scale, with_segments=seg is not None),
         out_shape=out_shape,
-        grid=(b * h, t // block_q, num_kb),
+        grid=(b * h, plan.num_qb, plan.kv_width),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
@@ -847,6 +938,7 @@ def _flash_fwd_impl(
     return out
 
 
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11))
 def _flash_bwd_impl(
     q, k, v, out, lse, g, causal, sm_scale, block_q, block_k, mode, window=None, seg=None,
     lse_cotangent=None,
@@ -871,89 +963,73 @@ def _flash_bwd_impl(
         delta = delta - lse_cotangent.astype(jnp.float32)
     delta3 = jnp.broadcast_to(delta[:, :, None], (b * h, t, _LANES))
     lse3 = jnp.broadcast_to(lse[:, :, None], (b * h, t, _LANES))
+    operands = [qt, kt, vt, dot, lse3, delta3]
+    if seg is not None:
+        operands += _seg_layouts(seg, b, t, s)
     kv_index = _make_kv_index(h, kh)
-
     vmem = {"memory_space": _VMEM}
 
-    def kv_block(bh, qi, kb):
-        return (kv_index(bh), _clamp_kv_stream(kb, qi, block_q, block_k, causal, window, num_kb), 0)
+    plan = _plan_for(block_q, block_k, d, t, s, causal, window)
+    bq, bk = plan.block_q, plan.block_k
 
-    num_kb = s // block_k
+    q_rows = lambda bh, qi, j: (bh, qi, 0)
+    kv_rows = lambda bh, qi, j: (kv_index(bh), plan.kv_step(qi, j)[2], 0)
     dq_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem),  # q
-        pl.BlockSpec((1, block_k, d), kv_block, **vmem),  # k
-        pl.BlockSpec((1, block_k, d), kv_block, **vmem),  # v
-        pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem),  # dO
-        pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, kb: (bh, qi, 0), **vmem),  # lse
-        pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, kb: (bh, qi, 0), **vmem),  # delta
+        pl.BlockSpec((1, bq, d), q_rows, **vmem),  # q
+        pl.BlockSpec((1, bk, d), kv_rows, **vmem),  # k
+        pl.BlockSpec((1, bk, d), kv_rows, **vmem),  # v
+        pl.BlockSpec((1, bq, d), q_rows, **vmem),  # dO
+        pl.BlockSpec((1, bq, _LANES), q_rows, **vmem),  # lse
+        pl.BlockSpec((1, bq, _LANES), q_rows, **vmem),  # delta
     ]
-    dq_operands = [qt, kt, vt, dot, lse3, delta3]
     if seg is not None:
-        seg_q3, seg_kv3 = _seg_layouts(seg, b, t, s)
-        dq_in_specs.append(pl.BlockSpec((1, block_q, _LANES), lambda bh, qi, kb: (bh // h, qi, 0), **vmem))
+        dq_in_specs.append(pl.BlockSpec((1, bq, _LANES), lambda bh, qi, j: (bh // h, qi, 0), **vmem))
         dq_in_specs.append(
-            pl.BlockSpec(
-                (1, _SUBLANES, block_k),
-                lambda bh, qi, kb: (bh // h, 0, _clamp_kv_stream(kb, qi, block_q, block_k, causal, window, num_kb)),
-                **vmem,
-            )
+            pl.BlockSpec((1, _SUBLANES, bk), lambda bh, qi, j: (bh // h, 0, plan.kv_step(qi, j)[2]), **vmem)
         )
-        dq_operands += [seg_q3, seg_kv3]
     dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel, block_k=block_k, causal=causal, sm_scale=sm_scale, q_block=block_q,
-            num_kb=num_kb, window=window, with_segments=seg is not None,
-        ),
+        functools.partial(_dq_kernel, plan=plan, sm_scale=sm_scale, with_segments=seg is not None),
         out_shape=jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
-        grid=(b * h, t // block_q, num_kb),
+        grid=(b * h, plan.num_qb, plan.kv_width),
         in_specs=dq_in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi, kb: (bh, qi, 0), **vmem),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],  # dq accumulator
+        out_specs=pl.BlockSpec((1, bq, d), q_rows, **vmem),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],  # dq accumulator
         interpret=interpret,
         name="flash_bwd_dq",
-    )(*dq_operands)
+    )(*operands)
 
     # per-query-head dK/dV; group-summed below for GQA. 3D grid: the q-block
     # axis is innermost so dk/dv output blocks accumulate in VMEM.
-    def q_stream(qb, kb):
-        return _clamp_q_stream(qb, kb, block_q, block_k, causal, window)
-
+    q_rows = lambda bh, kb, j: (bh, plan.q_step(kb, j)[2], 0)
+    kv_rows = lambda bh, kb, j: (kv_index(bh), kb, 0)
     dkv_in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, kb, qb: (bh, q_stream(qb, kb), 0), **vmem),  # q
-        pl.BlockSpec((1, block_k, d), lambda bh, kb, qb: (kv_index(bh, kb), kb, 0), **vmem),  # k
-        pl.BlockSpec((1, block_k, d), lambda bh, kb, qb: (kv_index(bh, kb), kb, 0), **vmem),  # v
-        pl.BlockSpec((1, block_q, d), lambda bh, kb, qb: (bh, q_stream(qb, kb), 0), **vmem),  # dO
-        pl.BlockSpec((1, block_q, _LANES), lambda bh, kb, qb: (bh, q_stream(qb, kb), 0), **vmem),  # lse
-        pl.BlockSpec((1, block_q, _LANES), lambda bh, kb, qb: (bh, q_stream(qb, kb), 0), **vmem),  # delta
+        pl.BlockSpec((1, bq, d), q_rows, **vmem),  # q
+        pl.BlockSpec((1, bk, d), kv_rows, **vmem),  # k
+        pl.BlockSpec((1, bk, d), kv_rows, **vmem),  # v
+        pl.BlockSpec((1, bq, d), q_rows, **vmem),  # dO
+        pl.BlockSpec((1, bq, _LANES), q_rows, **vmem),  # lse
+        pl.BlockSpec((1, bq, _LANES), q_rows, **vmem),  # delta
     ]
-    dkv_operands = [qt, kt, vt, dot, lse3, delta3]
     if seg is not None:
-        seg_q3, seg_kv3 = _seg_layouts(seg, b, t, s)
         dkv_in_specs.append(
-            pl.BlockSpec((1, block_q, _LANES), lambda bh, kb, qb: (bh // h, q_stream(qb, kb), 0), **vmem)
+            pl.BlockSpec((1, bq, _LANES), lambda bh, kb, j: (bh // h, plan.q_step(kb, j)[2], 0), **vmem)
         )
-        dkv_in_specs.append(
-            pl.BlockSpec((1, _SUBLANES, block_k), lambda bh, kb, qb: (bh // h, 0, kb), **vmem)
-        )
-        dkv_operands += [seg_q3, seg_kv3]
+        dkv_in_specs.append(pl.BlockSpec((1, _SUBLANES, bk), lambda bh, kb, j: (bh // h, 0, kb), **vmem))
     dk_h, dv_h = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, block_q=block_q, causal=causal, sm_scale=sm_scale, k_block=block_k,
-            window=window, with_segments=seg is not None,
-        ),
+        functools.partial(_dkv_kernel, plan=plan, sm_scale=sm_scale, with_segments=seg is not None),
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
             jax.ShapeDtypeStruct((b * h, s, d), jnp.float32),
         ],
-        grid=(b * h, s // block_k, t // block_q),
+        grid=(b * h, plan.num_kb, plan.q_width),
         in_specs=dkv_in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qb: (bh, kb, 0), **vmem),
-            pl.BlockSpec((1, block_k, d), lambda bh, kb, qb: (bh, kb, 0), **vmem),
+            pl.BlockSpec((1, bk, d), lambda bh, kb, j: (bh, kb, 0), **vmem),
+            pl.BlockSpec((1, bk, d), lambda bh, kb, j: (bh, kb, 0), **vmem),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(*dkv_operands)
+    )(*operands)
 
     dq = dq.reshape(b, h, t, d).transpose(0, 2, 1, 3)
     dk = dk_h.reshape(b, kh, group, s, d).sum(axis=2).transpose(0, 2, 1, 3).astype(k.dtype)
