@@ -38,6 +38,7 @@ _DECODE_CHUNKS = 8
 
 def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int | None = None, dtype=jnp.bfloat16):
     """Zeroed KV cache pytree: ``{layer_i: {k, v: [B, S, KH, D]}}``."""
+    cfg.require_attention_only("a KV cache")
     s = max_len or cfg.max_seq_len
     shape = (batch_size, s, cfg.kv_heads, cfg.head_dim)
     return {

@@ -45,23 +45,48 @@ def _interleave_rope_rows(w: np.ndarray) -> np.ndarray:
 def transformer_config_from_hf(hf_config: Any, **overrides) -> TransformerConfig:
     """Build a :class:`TransformerConfig` from a HF ``LlamaConfig`` /
     ``MistralConfig`` (same architecture; Mistral's ``sliding_window``
-    carries over into the model's windowed attention paths)."""
+    carries over into the model's windowed attention paths) or an
+    ``Lfm2MoeConfig`` (``model_type`` ``lfm2_moe``: per-layer operators from
+    ``layer_types``, RMSNorm over the head dimension of q and k, sigmoid-scored
+    experts with a selection bias after ``num_dense_layers`` dense layers).
+    ``experts_held`` is no published key: pass it as an override for one
+    chip's share of the experts."""
+    get = lambda key, default=None: getattr(hf_config, key, default)
+    rope = get("rope_parameters") or {}
+    scaling = get("rope_scaling") or (rope if rope.get("rope_type", "default") != "default" else None)
     base = dict(
         vocab_size=hf_config.vocab_size,
         num_layers=hf_config.num_hidden_layers,
         num_heads=hf_config.num_attention_heads,
-        num_kv_heads=getattr(hf_config, "num_key_value_heads", None),
+        num_kv_heads=get("num_key_value_heads"),
         # some Mistral-family configs decouple head_dim from hidden/heads
-        head_dim=getattr(hf_config, "head_dim", None)
-        or hf_config.hidden_size // hf_config.num_attention_heads,
+        head_dim=get("head_dim") or hf_config.hidden_size // hf_config.num_attention_heads,
         hidden_dim=hf_config.hidden_size,
         mlp_dim=hf_config.intermediate_size,
         max_seq_len=hf_config.max_position_embeddings,
-        rope_theta=getattr(hf_config, "rope_theta", 10000.0),
-        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
-        sliding_window=getattr(hf_config, "sliding_window", None),
-        rope_scaling=_rope_scaling_from_hf(getattr(hf_config, "rope_scaling", None)),
+        rope_theta=float(get("rope_theta") or rope.get("rope_theta", 10000.0)),
+        tie_embeddings=bool(get("tie_word_embeddings", False)),
+        sliding_window=get("sliding_window"),
+        rope_scaling=_rope_scaling_from_hf(scaling),
+        norm_eps=float(get("rms_norm_eps") or get("norm_eps") or 1e-6),
     )
+    if get("model_type") == "lfm2_moe":
+        if get("conv_bias", False):
+            raise ValueError("conv_bias=True is not supported: ShortConv has no bias")
+        base.update(
+            layer_types=tuple(hf_config.layer_types),
+            conv_L_cache=int(hf_config.conv_L_cache),
+            qk_norm=True,
+            # the family ties its embeddings; a config that says otherwise is followed
+            tie_embeddings=bool(get("tie_word_embeddings", True)),
+            num_experts=int(hf_config.num_experts),
+            num_dense_layers=int(hf_config.num_dense_layers),
+            num_experts_per_tok=int(hf_config.num_experts_per_tok),
+            moe_intermediate_size=int(hf_config.moe_intermediate_size),
+            use_expert_bias=bool(get("use_expert_bias", False)),
+            norm_topk_prob=bool(get("norm_topk_prob", True)),
+            routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
+        )
     base.update(overrides)
     return TransformerConfig(**base)
 

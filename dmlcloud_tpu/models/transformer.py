@@ -28,6 +28,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 
+#: the operators a block can have, by the names published configs give them
+LAYER_KINDS = ("full_attention", "conv")
+
+
 @dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
@@ -52,13 +56,29 @@ class TransformerConfig:
     # only 1 + ceil((W-1)/Tl) blocks — O(W) communication), and the decode
     # cache.
     sliding_window: int | None = None
-    # MoE: replace the dense MLP with an expert-parallel MoEMLP (models/moe.py)
-    # in every ``moe_every``-th block (0 = dense everywhere). Experts shard
-    # over the ``expert`` mesh axis via moe_partition_rules().
+    # RMSNorm's epsilon, every norm of the model (published as rms_norm_eps / norm_eps)
+    norm_eps: float = 1e-6
+    # RMSNorm with a learned scale over the head dimension of q and of k, before RoPE
+    qk_norm: bool = False
+    # The operator of each block, by the published names: "full_attention"
+    # (Attention) or "conv" (ShortConv, the gated short convolution of the
+    # LFM2 family, kernel length ``conv_L_cache``). None = attention everywhere.
+    layer_types: tuple[str, ...] | None = None
+    conv_L_cache: int = 3
+    # MoE (models/moe.py): with ``num_experts`` > 0 every block after the first
+    # ``num_dense_layers`` has the dropless expert layer in the dense MLP's
+    # place. ``num_experts`` is the router's width; ``experts_held = (a, b)``
+    # keeps experts [a, b) only, one chip's share of an expert-parallel layer
+    # (None = all). Names and meanings are the published configs'. Experts
+    # shard over the ``expert`` mesh axis via moe_partition_rules().
     num_experts: int = 0
-    moe_every: int = 2
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
+    num_dense_layers: int = 0
+    num_experts_per_tok: int = 2
+    moe_intermediate_size: int | None = None  # one expert's width; None = mlp_dim
+    use_expert_bias: bool = False
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    experts_held: tuple[int, int] | None = None
     seq_axis: str = "seq"  # mesh axis used when attn_impl == 'ring'
     # Mesh for attn_impl='ring' and 'flash' under plain jit: both wrap
     # themselves in shard_map over it (ring to split the sequence; flash
@@ -85,10 +105,32 @@ class TransformerConfig:
             raise ValueError(f"attn_impl must be 'dot', 'flash' or 'ring', got {self.attn_impl!r}")
         if self.sliding_window is not None and self.sliding_window < 1:
             raise ValueError(f"sliding_window must be >= 1, got {self.sliding_window}")
+        if self.layer_types is not None:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"layer_types names {len(self.layer_types)} layers, num_layers is {self.num_layers}")
+            unknown = sorted(set(self.layer_types) - set(LAYER_KINDS))
+            if unknown:
+                raise ValueError(f"layer_types holds {unknown}; the kinds this model has are {LAYER_KINDS}")
 
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    def layer_kind(self, i: int) -> str:
+        return "full_attention" if self.layer_types is None else self.layer_types[i]
+
+    def is_expert_layer(self, i: int) -> bool:
+        return self.num_experts > 0 and i >= self.num_dense_layers
+
+    def require_attention_only(self, what: str) -> None:
+        """Decoding keeps keys and values per sequence and nothing else: a
+        layer kind with state of another sort cannot be served yet (ROADMAP M5)."""
+        other = sorted({k for k in self.layer_types or () if k != "full_attention"})
+        if other:
+            raise NotImplementedError(
+                f"{what} cannot run a model with layers of kind {other[0]!r}: their per-sequence state "
+                "is not a KV cache (ROADMAP M5); only the training path runs them"
+            )
 
 
 def llama_partition_rules() -> list[tuple[str, P]]:
@@ -108,6 +150,8 @@ def llama_partition_rules() -> list[tuple[str, P]]:
         ("mlp/(gate|up)_proj/kernel", P("fsdp", "model")),
         ("mlp/down_proj/kernel", P("model", "fsdp")),
         ("lm_head/kernel", P("fsdp", "model")),
+        ("conv/in_proj/kernel", P("fsdp", "model")),
+        ("conv/out_proj/kernel", P("model", "fsdp")),
         ("norm", P()),
         (".*", P()),
     ]
@@ -265,6 +309,9 @@ class Attention(nn.Module):
         q = _adapter_add(dense((cfg.num_heads, cfg.head_dim), "q_proj")(x), x, "q_proj", adapters)
         k = _adapter_add(dense((cfg.kv_heads, cfg.head_dim), "k_proj")(x), x, "k_proj", adapters)
         v = _adapter_add(dense((cfg.kv_heads, cfg.head_dim), "v_proj")(x), x, "v_proj", adapters)
+        if cfg.qk_norm:
+            q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
 
         if seg_info is None and decode_pad is None and paged is None:
             q = apply_rope(q, cos, sin, offset=offset)
@@ -396,9 +443,41 @@ class MLP(nn.Module):
         return _adapter_add(dense(cfg.hidden_dim, "down_proj")(h), h, "down_proj", adapters)
 
 
+class ShortConv(nn.Module):
+    """The gated short convolution (LFM2): ``(B, C, X) = split3(u @ W_in)``,
+    ``z_t = sum_j w_j * (B * X)_{t-(L-1)+j}`` (depthwise, causal, zeros before
+    the sequence starts, ``L = conv_L_cache``), ``out = (C * z) @ W_out``. No
+    bias, no activation inside. Three shifted multiply-adds that XLA fuses;
+    the whole operator is the profile's phase ``conv_op``."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from .quant import QuantDense
+
+        cfg = self.cfg
+        taps, d = cfg.conv_L_cache, cfg.hidden_dim
+        dense = lambda feats, name: QuantDense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32, name=name
+        )
+        with jax.named_scope("conv_op"):
+            b_gate, c_gate, xs = jnp.split(dense(3 * d, "in_proj")(x), 3, axis=-1)
+            w = self.param("conv_weight", nn.initializers.normal(taps**-0.5), (taps, d), jnp.float32).astype(cfg.dtype)
+            bx = jnp.pad(b_gate * xs, ((0, 0), (taps - 1, 0), (0, 0)))
+            t = x.shape[1]
+            z = sum(w[j] * jax.lax.slice_in_dim(bx, j, j + t, axis=1) for j in range(taps))
+            return dense(d, "out_proj")(c_gate * z)
+
+
 class DecoderBlock(nn.Module):
+    """``h = x + Op(norm(x))``, ``y = h + FFN(norm(h))``. ``kind`` names the
+    operator (``LAYER_KINDS``), which owns its projections and its call;
+    ``use_moe`` puts the expert layer in the dense MLP's place."""
+
     cfg: TransformerConfig
     use_moe: bool = False
+    kind: str = "full_attention"
 
     @nn.compact
     def __call__(
@@ -413,33 +492,41 @@ class DecoderBlock(nn.Module):
             sub, ids = adapters
             attn_ad = ((sub or {}).get("attn"), ids)
             mlp_ad = ((sub or {}).get("mlp"), ids)
+        norm = lambda name: RMSNorm(eps=cfg.norm_eps, name=name)
         new_cache = None
-        if cache is not None:
+        if self.kind == "conv":
+            if cache is not None or seg_info is not None or paged is not None or attn_ad is not None:
+                raise NotImplementedError("a 'conv' layer takes no cache, packed rows, pages or attention adapters")
+            x = x + ShortConv(cfg, name="conv")(norm("conv_norm")(x))
+        elif cache is not None:
             attn_out, new_cache = Attention(cfg, name="attn")(
-                RMSNorm(name="attn_norm")(x), cos, sin, cache=cache, offset=offset,
+                norm("attn_norm")(x), cos, sin, cache=cache, offset=offset,
                 decode_pad=decode_pad, attend_len=attend_len, paged=paged, adapters=attn_ad,
             )
             x = x + attn_out
         else:
             x = x + Attention(cfg, name="attn")(
-                RMSNorm(name="attn_norm")(x), cos, sin, seg_info=seg_info, adapters=attn_ad
+                norm("attn_norm")(x), cos, sin, seg_info=seg_info, adapters=attn_ad
             )
         if self.use_moe:
             from .moe import MoEConfig, MoEMLP
 
             moe_cfg = MoEConfig(
                 num_experts=cfg.num_experts,
-                top_k=cfg.moe_top_k,
-                capacity_factor=cfg.moe_capacity_factor,
+                top_k=cfg.num_experts_per_tok,
                 hidden_dim=cfg.hidden_dim,
-                mlp_dim=cfg.mlp_dim,
+                mlp_dim=cfg.moe_intermediate_size or cfg.mlp_dim,
+                use_expert_bias=cfg.use_expert_bias,
+                norm_topk_prob=cfg.norm_topk_prob,
+                routed_scaling_factor=cfg.routed_scaling_factor,
+                experts_held=cfg.experts_held,
                 dtype=cfg.dtype,
             )
             # MoE blocks carry no per-request adapters (expert routing and
             # LoRA-per-tenant compose poorly; dense layers cover serving)
-            x = x + MoEMLP(moe_cfg, name="moe")(RMSNorm(name="mlp_norm")(x))
+            x = x + MoEMLP(moe_cfg, name="moe")(norm("mlp_norm")(x))
         else:
-            x = x + MLP(cfg, name="mlp")(RMSNorm(name="mlp_norm")(x), adapters=mlp_ad)
+            x = x + MLP(cfg, name="mlp")(norm("mlp_norm")(x), adapters=mlp_ad)
         return x if new_cache is None else (x, new_cache)
 
 
@@ -475,6 +562,8 @@ class DecoderLM(nn.Module):
             raise ValueError("pad_len (left-padded ragged prompts) is a decode-mode feature")
         if attend_len is not None and cache is None:
             raise ValueError("attend_len (bounded cache reads) is a decode-mode feature")
+        if cache is not None or segment_ids is not None:
+            cfg.require_attention_only("decoding" if cache is not None else "a packed row")
         paged = None
         if pages is not None:
             # Paged decode (serving engine): ``cache`` holds the POOL pages
@@ -535,25 +624,25 @@ class DecoderLM(nn.Module):
         new_cache = {} if cache is not None else None
         adapter_tree, adapter_ids = adapters if adapters is not None else (None, None)
         for i in range(cfg.num_layers):
-            use_moe = cfg.num_experts > 0 and cfg.moe_every > 0 and (i % cfg.moe_every == cfg.moe_every - 1)
+            use_moe, kind = cfg.is_expert_layer(i), cfg.layer_kind(i)
             name = f"layer_{i}"
             layer_ad = None
             if adapter_tree is not None and adapter_tree.get(name) is not None:
                 layer_ad = (adapter_tree[name], adapter_ids)
             if cache is not None:
-                x, new_cache[name] = DecoderBlock(cfg, use_moe=use_moe, name=name)(
+                x, new_cache[name] = DecoderBlock(cfg, use_moe=use_moe, kind=kind, name=name)(
                     x, cos, sin, cache=cache[name], offset=offset, decode_pad=decode_pad,
                     attend_len=attend_len, paged=paged, adapters=layer_ad,
                 )
                 x = constrain(x)
             else:
                 x = constrain(
-                    block_cls(cfg, use_moe=use_moe, name=name)(
+                    block_cls(cfg, use_moe=use_moe, kind=kind, name=name)(
                         x, cos, sin, seg_info=seg_info, adapters=layer_ad
                     )
                 )
 
-        x = RMSNorm(name="final_norm")(x)
+        x = RMSNorm(eps=cfg.norm_eps, name="final_norm")(x)
         if return_hidden and new_cache is None:
             # the chunked-vocab loss path (chunked_lm_loss) consumes the
             # final hidden states directly and never materializes logits

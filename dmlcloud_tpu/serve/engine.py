@@ -454,6 +454,7 @@ class ServeEngine:
             raise ValueError("medusa_heads need medusa_k >= 1")
         self.model = model
         cfg = model.cfg
+        cfg.require_attention_only("ServeEngine")
         # one-time host-side preparation: int8 kernels stay fused-quantized
         # and the off-TPU GEMM-operand widen is pre-paid (models/quant.py)
         self.params = prepare_decode_params(params, cfg.dtype)

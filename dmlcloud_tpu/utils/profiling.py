@@ -204,17 +204,24 @@ def chip_peak_flops(device=None) -> float:
 #: (``attn_kernel``, ``loss_head``, ``grad_clip``, ``optimizer``, ``kv_write``,
 #: ``kv_gather``, ``attention``, ``head``, ``sampling``) or a Flax module's
 #: name (``embed``, ``mlp``; ``attn`` and ``*_norm`` through the aliases below).
+#: The expert layer and the short convolution open scopes of their own
+#: (``moe_route``: scores, top-k, sort and the two moves of rows; ``moe_experts``:
+#: the grouped products; ``conv_op``: the whole operator).
 PHASES = (
     "embed", "norm", "attn_proj", "attn_kernel", "kv_write", "kv_gather", "attention",
-    "mlp", "loss_head", "head", "sampling", "grad_clip", "optimizer",
+    "mlp", "conv_op", "moe_route", "moe_experts", "loss_head", "head", "sampling", "grad_clip", "optimizer",
 )
 #: Flax module names that are not themselves phase names.
-_PHASE_ALIASES = {"attn": "attn_proj", "moe": "mlp"}
+_PHASE_ALIASES = {"attn": "attn_proj"}
 #: what jax wraps round a path component when a transformation passes over it
 _TRANSFORMS = frozenset(
     {"jit", "pjit", "jvp", "transpose", "vmap", "pmap", "shard_map", "checkpoint", "remat",
      "custom_jvp", "custom_vjp"}
 )
+#: Kernels XLA itself puts in a program carry no scope: their phase goes by the
+#: instruction's name. ``jax.lax.ragged_dot`` is a grouped-matmul custom call
+#: on the TPU (``%ragged-dot-none.7``) whose ``op_name`` is that name alone.
+_KERNEL_PHASES = (("ragged-dot", "moe_experts"),)
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=")
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -285,7 +292,8 @@ def phase_map(compiled) -> dict[str, tuple[str | None, str]]:
     instruction the metadata of its root, so a fusion's phase is its root's;
     where the root carries none (a tuple of several outputs), the phase most
     of the fused instructions carry. A fused body that spans two phases is
-    not split: its whole time goes to that one phase. Instructions whose
+    not split: its whole time goes to that one phase. A kernel XLA places
+    itself goes by its instruction's name (``_KERNEL_PHASES``). Instructions whose
     ``op_name`` holds no phase (parameters, copies XLA added, the key's
     fold-in) map to ``(None, direction)`` and are the table's
     ``unattributed`` row."""
@@ -303,6 +311,8 @@ def phase_map(compiled) -> dict[str, tuple[str | None, str]]:
                     phase, direction = root
                 elif named:
                     phase, direction = collections.Counter(named).most_common(1)[0][0]
+            if phase is None:
+                phase = next((p for prefix, p in _KERNEL_PHASES if name.startswith(prefix)), None)
             out[name] = (phase, direction)
     return out
 

@@ -33,9 +33,10 @@ MODULES = [
     ("dmlcloud_tpu.parallel.pipeline_parallel", "GPipe pipeline parallelism as one XLA program."),
     ("dmlcloud_tpu.ops.flash_attention", "Fused Pallas flash-attention kernels (fwd + bwd)."),
     ("dmlcloud_tpu.ops.ring_attention", "Ring attention: sequence parallelism over the mesh."),
+    ("dmlcloud_tpu.ops.grouped_matmul", "Grouped products over ragged groups, and the moves of rows to experts and back."),
     ("dmlcloud_tpu.models.transformer", "Llama-style decoder LM building blocks."),
     ("dmlcloud_tpu.models.generate", "Autoregressive generation: sampling + beam search."),
-    ("dmlcloud_tpu.models.moe", "Mixture-of-experts layers with expert parallelism."),
+    ("dmlcloud_tpu.models.moe", "Dropless mixture-of-experts layer: a chip's share of the experts, expert parallelism."),
     ("dmlcloud_tpu.models.resnet", "ResNet family (NHWC, bf16-friendly)."),
     ("dmlcloud_tpu.models.cnn", "Small CNNs for the example flows."),
     ("dmlcloud_tpu.models.encoder", "Transformer encoder blocks."),

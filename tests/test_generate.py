@@ -91,7 +91,7 @@ def test_sampling_deterministic_under_rng():
 
 @pytest.mark.slow
 def test_moe_decode_runs():
-    cfg = _tiny_cfg(num_experts=2, moe_every=2)
+    cfg = _tiny_cfg(num_experts=2, num_dense_layers=1)
     model, params, prompt = _init(cfg)
     out = generate(model, params, prompt, max_new_tokens=4)
     assert np.asarray(out).shape == (2, 4)
