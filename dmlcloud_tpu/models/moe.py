@@ -11,16 +11,22 @@ The shares of all holders of one layer add up to the whole layer's output;
 nothing stands in for the absent experts. ``None`` holds them all.
 
 How: the ``N * k`` pairs are sorted by expert (pairs of experts not held sort
-to the end), the tokens of the sorted pairs gathered once, the gate/up and
-down products run as grouped products over the ragged groups
-(``ops/grouped_matmul.py``), the gate weights applied and the rows added back
-to their tokens (a permutation, so both moves and both their transposes are
-gathers: no scatter-add runs). Shapes are static (``N * k`` rows: the
-worst routing sends every pair to a held expert and none may be dropped); the
-grouped products do work for the live rows only, and every other pass (the two
-gathers, the SwiGLU, the masks) touches all ``N * k`` rows: outside the
-grouped products the layer is O(N * k), whatever share of the pairs is live
-(PERF.md section 7 has what compacting the buffers would take).
+to the end, so the live rows are a prefix of the sorted order), the tokens of
+the sorted pairs gathered, the gate/up and down products run as grouped
+products over the ragged groups (``ops/grouped_matmul.py``), the gate weights
+applied and the rows added back to their tokens (gathers both ways, forward
+and backward: no scatter-add runs). No pair may be dropped and the worst
+routing sends all ``N * k`` to held experts, but a layer that holds ``h`` of
+``E`` experts expects ``N * k * h / E``. So the layer works in a buffer of
+``R = row_bound(N * k, h, E)`` rows, twice that even share, whenever a step's
+live rows fit it, and in the full ``N * k`` buffer when they do not: both
+compiled, chosen on the device by a ``jax.lax.cond`` on the step's own
+``group_sizes``, the same output and gradients wherever both apply. The usual
+path reads and writes ``R``-row arrays only (beside the index vectors), keeps
+``R``-row residuals for its hand-written backward, and the rare path recomputes
+its forward in the backward, so a step that fits pays nothing for the buffer
+behind it. Where the layer holds every expert (or half of them) ``R`` is
+``N * k``: one path, no ``cond``.
 
 - ``expert_bias`` (``use_expert_bias``) takes part in the choice of experts
   only. It is a buffer, not a parameter (collection ``buffers``, as the
@@ -29,7 +35,8 @@ grouped products the layer is O(N * k), whatever share of the pairs is live
   but ``params`` in ``state.extras``.
 - Counters, sown into the collection ``moe_stats`` (read with
   ``mutable=["moe_stats"]`` and :func:`moe_counters`): the pairs sent to held
-  experts and the fullest held expert's load over the mean of the held.
+  experts, the fullest held expert's load over the mean of the held, and
+  whether the layer's live rows overflowed ``R`` (it then took the full path).
 - The load-balancing auxiliary loss (Switch Transformer eq. 4) and router
   z-loss are sown under ``losses`` as before (:func:`total_aux_loss`).
 - With the expert axis of the three matrices sharded over the ``expert`` mesh
@@ -39,6 +46,7 @@ grouped products the layer is O(N * k), whatever share of the pairs is live
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -47,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.grouped_matmul import collect, grouped_matmul, spread
+from ..ops.grouped_matmul import collect, collect_rows, grouped_matmul, spread, spread_rows
 
 
 def moe_partition_rules() -> list[tuple[str, P]]:
@@ -114,6 +122,128 @@ def sort_pairs(chosen, gates, held: tuple[int, int]):
     return order, jnp.argsort(order), weight, group_sizes
 
 
+#: The bounded buffer holds this many times the even share of the held experts.
+#: With the loads levelled by ``expert_bias`` the live rows of a layer stay within
+#: a tenth of that share in every step (15,348-17,461 pairs a step over four
+#: layers against 16,384 in ``lfm2-train-8k``; PERF.md section 6, PRs 30 and 31),
+#: so twice is far off there, while a routing that drifts or a skewed batch has
+#: room before it pays for the full path. What the layer does outside the grouped
+#: kernels goes with the buffer's rows, the kernels hardly at all (they skip dead
+#: tiles): PERF.md section 6, PR 31, has the layer timed at other factors.
+_ROW_BOUND_FACTOR = 2
+_ROW_TILE = 512  # the grouped product's row tile on the TPU (``ragged_dot_tiling``)
+
+
+def row_bound(pairs: int, held: int, num_experts: int) -> int:
+    """Rows of the buffer a layer with ``held`` of ``num_experts`` experts works
+    in when a step's live rows fit it: ``_ROW_BOUND_FACTOR`` times the even
+    share of its ``pairs``, rounded up to the row tile, and never more than ``pairs``."""
+    tiles = -(-_ROW_BOUND_FACTOR * pairs * held // (num_experts * _ROW_TILE))
+    return min(pairs, tiles * _ROW_TILE)
+
+
+def _ffn(rows, gate_w, up_w, down_w, group_sizes):
+    with jax.named_scope("moe_experts"):
+        gate = grouped_matmul(rows, gate_w, group_sizes)
+        up = grouped_matmul(rows, up_w, group_sizes)
+        return gate, up, grouped_matmul(nn.silu(gate) * up, down_w, group_sizes)
+
+
+def _live_rows(group_sizes, rows: int):
+    # rows past the live ones belong to no group: a grouped product leaves there whatever it likes, forward
+    # and backward, so they are cut off on the way in (their gradient) and on the way out (their value)
+    return (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+
+
+def _full_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k):
+    """The held experts' share on the ``N * k`` buffer: ``[N, D]`` float32."""
+    with jax.named_scope("moe_route"):
+        live = _live_rows(group_sizes, order.shape[0])
+        rows = jnp.where(live, spread(tokens, order, inverse, k), 0)  # [N*k, D], held experts first
+    _, _, out_rows = _ffn(rows, gate_w, up_w, down_w, group_sizes)
+    with jax.named_scope("moe_route"):
+        out_rows = jnp.where(live, out_rows.astype(jnp.float32) * weight[:, None], 0.0)
+        return collect(out_rows.astype(tokens.dtype), order, inverse, k)
+
+
+# The two paths of a bounded layer, forward and backward: each body is traced once
+# a process (an inner jit, inlined under the step's) and shared by the expert
+# layers and by the traces a step goes through before it runs.
+
+
+@functools.partial(jax.jit, static_argnames=("k", "bound"))
+def _usual_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, *, k, bound):
+    with jax.named_scope("moe_route"):
+        live = _live_rows(group_sizes, bound)
+        rows = jnp.where(live, spread_rows(tokens, order, k, bound), 0)  # [R, D]
+    gate, up, out_rows = _ffn(rows, gate_w, up_w, down_w, group_sizes)
+    with jax.named_scope("moe_route"):
+        weighted = jnp.where(live, out_rows.astype(jnp.float32) * weight[:bound, None], 0.0)
+        return collect_rows(weighted.astype(tokens.dtype), inverse, k), (rows, gate, up, out_rows)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "bound"))
+def _full_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, *, k, bound):
+    out = _full_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k)
+    rows = jnp.zeros((bound, tokens.shape[1]), tokens.dtype)  # nothing is kept: the backward computes this path again
+    wide = jnp.zeros((bound, gate_w.shape[2]), tokens.dtype)
+    return out, (rows, wide, wide, rows)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "bound"))
+def _usual_bwd(saved, tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, d_out, *, k, bound):
+    del tokens
+    rows, gate, up, out_rows = saved
+    product = lambda lhs, rhs: grouped_matmul(lhs, rhs, group_sizes)
+    with jax.named_scope("moe_route"):
+        live = _live_rows(group_sizes, bound)
+        d_weighted = jnp.where(live, spread_rows(d_out.astype(rows.dtype), order, k, bound).astype(jnp.float32), 0.0)
+        d_weight = jnp.sum(d_weighted * out_rows.astype(jnp.float32), axis=-1)
+        d_out_rows = (d_weighted * weight[:bound, None]).astype(rows.dtype)
+    with jax.named_scope("moe_experts"):
+        hidden, swiglu_vjp = jax.vjp(lambda g, u: nn.silu(g) * u, gate, up)
+        d_hidden, d_down = jax.vjp(product, hidden, down_w)[1](d_out_rows)  # a product's own result is not needed
+        d_gate, d_up = swiglu_vjp(d_hidden)
+        d_rows_gate, d_gate_w = jax.vjp(product, rows, gate_w)[1](d_gate)
+        d_rows_up, d_up_w = jax.vjp(product, rows, up_w)[1](d_up)
+    with jax.named_scope("moe_route"):
+        d_rows = jnp.where(live, d_rows_gate + d_rows_up, 0)
+        d_tokens = collect_rows(d_rows, inverse, k).astype(rows.dtype)
+        return d_tokens, jnp.pad(d_weight, (0, weight.shape[0] - bound)), d_gate_w, d_up_w, d_down
+
+
+@functools.partial(jax.jit, static_argnames=("k", "bound"))
+def _full_bwd(saved, tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, d_out, *, k, bound):
+    del saved, bound
+    path = lambda *diff: _full_path(*diff, order, inverse, group_sizes, k)
+    return jax.vjp(path, tokens, weight, gate_w, up_w, down_w)[1](d_out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _bounded_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound):
+    """:func:`_full_path`'s result, computed in ``bound`` rows when the live rows fit them."""
+    return _bounded_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound)[0]
+
+
+def _bounded_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound):
+    args = (tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes)
+    # no scope of the layer's own round a cond: XLA's grouped kernel takes the path of names round it for its
+    # ``op_name``, and is given its phase by its instruction's name only where that path holds none
+    out, saved = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_fwd, k=k, bound=bound),
+                              functools.partial(_full_fwd, k=k, bound=bound), *args)
+    return out, (saved, *args)
+
+
+def _bounded_bwd(k, bound, residuals, d_out):
+    group_sizes = residuals[-1]
+    grads = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_bwd, k=k, bound=bound),
+                         functools.partial(_full_bwd, k=k, bound=bound), *residuals, d_out)
+    return (*grads, None, None, None)
+
+
+_bounded_path.defvjp(_bounded_fwd, _bounded_bwd)
+
+
 class MoEMLP(nn.Module):
     """Dropless expert SwiGLU block: ``[B, T, D] -> [B, T, D]``, the share of
     the experts held (module docstring)."""
@@ -142,28 +272,26 @@ class MoEMLP(nn.Module):
             scores, chosen, gates = route(cfg, logits, bias)
             k = chosen.shape[1]
             order, inverse, weight, group_sizes = sort_pairs(chosen, gates, (lo, hi))
-            # rows past the live ones belong to no group: a grouped product leaves there whatever it likes, forward
-            # and backward, so they are cut off on the way in (their gradient) and on the way out (their value)
-            live = (jnp.arange(n_tok * k) < jnp.sum(group_sizes))[:, None]
-            rows = jnp.where(live, spread(tokens.astype(cfg.dtype), order, inverse, k), 0)  # [N*k, D], held experts first
 
         wi_init = nn.initializers.variance_scaling(1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1, batch_axis=0)
         gate_w = self.param("moe/gate_proj", wi_init, (held, d, cfg.mlp_dim), jnp.float32)
         up_w = self.param("moe/up_proj", wi_init, (held, d, cfg.mlp_dim), jnp.float32)
         down_w = self.param("moe/down_proj", wi_init, (held, cfg.mlp_dim, d), jnp.float32)
-        with jax.named_scope("moe_experts"):
-            gate = grouped_matmul(rows, gate_w.astype(cfg.dtype), group_sizes)
-            up = grouped_matmul(rows, up_w.astype(cfg.dtype), group_sizes)
-            out_rows = grouped_matmul(nn.silu(gate) * up, down_w.astype(cfg.dtype), group_sizes)
+        with jax.named_scope("moe_experts"):  # cast once a step, for either path
+            matrices = gate_w.astype(cfg.dtype), up_w.astype(cfg.dtype), down_w.astype(cfg.dtype)
+        bound = row_bound(n_tok * k, held, e)
+        if bound == n_tok * k:
+            out = _full_path(tokens.astype(cfg.dtype), weight, *matrices, order, inverse, group_sizes, k)
+        else:
+            out = _bounded_path(tokens.astype(cfg.dtype), weight, *matrices, order, inverse, group_sizes, k, bound)
 
         with jax.named_scope("moe_route"):
-            out_rows = jnp.where(live, out_rows.astype(jnp.float32) * weight[:, None], 0.0)
-            out = collect(out_rows.astype(cfg.dtype), order, inverse, k)  # [N, D] float32
-
             load = group_sizes.astype(jnp.float32)
             self.sow("moe_stats", "pairs_held", jnp.sum(load), init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.add)
             self.sow("moe_stats", "load_max_over_mean", jnp.max(load) / jnp.maximum(jnp.mean(load), 1e-9),
                      init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.maximum)
+            self.sow("moe_stats", "overflow", (jnp.sum(group_sizes) > bound).astype(jnp.float32),
+                     init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.add)
             # Switch balance loss: E * sum_e (fraction routed to e) * (mean score share of e)
             picks = jnp.sum(jax.nn.one_hot(chosen, e, dtype=jnp.float32), axis=(0, 1))
             share = scores / jnp.sum(scores, -1, keepdims=True)
@@ -175,18 +303,22 @@ class MoEMLP(nn.Module):
 
 
 def moe_counters(variables: Any) -> dict:
-    """``{"moe/pairs_held", "moe/load_max_over_mean"}`` of one forward, from the
-    variables a ``mutable=["moe_stats"]`` apply returned: the pairs sent to
-    held experts summed over the expert layers, and the largest ratio of the
-    fullest held expert to the mean of the held. Device scalars: a train step
-    returns them beside its loss and the tracker fetches them with it."""
+    """``{"moe/pairs_held", "moe/load_max_over_mean", "moe/overflow_layers"}`` of
+    one forward, from the variables a ``mutable=["moe_stats"]`` apply returned:
+    the pairs sent to held experts summed over the expert layers, the largest
+    ratio of the fullest held expert to the mean of the held, and how many
+    expert layers' live rows overflowed their row bound and took the full
+    ``N * k`` path (0 where the loads are level; nothing is dropped either way).
+    Device scalars: a train step returns them beside its loss and the tracker
+    fetches them with it."""
     stats = variables.get("moe_stats", {}) if isinstance(variables, dict) else {}
     flat = jax.tree_util.tree_flatten_with_path(stats)[0]
-    pairs = [v for p, v in flat if "pairs_held" in jax.tree_util.keystr(p)]
-    ratio = [v for p, v in flat if "load_max_over_mean" in jax.tree_util.keystr(p)]
+    named = lambda name: [v for p, v in flat if name in jax.tree_util.keystr(p)]
+    pairs = named("pairs_held")
     if not pairs:
         return {}
-    return {"moe/pairs_held": sum(pairs), "moe/load_max_over_mean": jnp.max(jnp.stack(ratio))}
+    return {"moe/pairs_held": sum(pairs), "moe/load_max_over_mean": jnp.max(jnp.stack(named("load_max_over_mean"))),
+            "moe/overflow_layers": sum(named("overflow"))}
 
 
 def total_aux_loss(variables: Any) -> jnp.ndarray:

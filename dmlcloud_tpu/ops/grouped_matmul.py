@@ -1,5 +1,5 @@
-"""The grouped product of a dropless expert layer, and the permutation pair
-that carries tokens to their experts' rows and back.
+"""The grouped product of a dropless expert layer, and the moves that carry
+tokens to their experts' rows and back.
 
 ``grouped_matmul(lhs [M, K], rhs [G, K, N], group_sizes [G]) -> [M, N]``: the
 rows of ``lhs`` lie sorted by group, ``group_sizes[g]`` of them belonging to
@@ -12,6 +12,14 @@ It is ``jax.lax.ragged_dot``: XLA's own ragged product on the TPU, whose
 transposes (a ragged product for the rows' gradient, one with the ragged axis
 contracted for the weights') jax derives itself. PERF.md section 6, PR 30,
 has its times beside a Pallas grouped matmul's at the benchmark's shapes.
+
+Two pairs of moves. ``spread`` / ``collect`` carry all ``N * k`` pairs (a
+permutation, so both directions of both are gathers). ``spread_rows`` /
+``collect_rows`` carry the first ``R`` sorted pairs only, for a layer whose
+live rows fit a buffer of ``R`` (``models/moe.py``): ``R``-row gathers out,
+and back ``k`` gathers of ``N`` rows from the ``R``-row buffer (PERF.md
+section 6, PR 31, has the forms of that move timed alone). No scatter-add runs
+in either pair, forward or backward.
 """
 
 from __future__ import annotations
@@ -27,8 +35,7 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 
 # The ``N * k`` (token, expert) pairs in sorted order are a permutation of the
-# pairs in token order, so both directions of both moves are gathers: no
-# scatter-add runs, forward or backward.
+# pairs in token order, so both directions of both moves are gathers.
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -69,3 +76,26 @@ def _collect_bwd(k, saved, d_out):
 
 
 collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+# The first ``R`` sorted pairs alone. Neither move is differentiated by jax: the
+# expert layer's bounded path writes its own backward, in which each is the
+# other's transpose.
+
+
+def spread_rows(tokens, order, k: int, bound: int):
+    """``tokens [N, D] -> rows [R, D]``: the tokens of the first ``R = bound`` sorted pairs."""
+    return tokens[order[:bound] // k]
+
+
+def collect_rows(rows, inverse, k: int):
+    """``rows [R, D] -> [N, D]`` float32: each token's rows among the first ``R``
+    sorted pairs added up (``inverse[p]`` is where pair ``p`` lies in sorted
+    order; a pair that lies past ``R`` adds nothing)."""
+    bound = rows.shape[0]
+    place = inverse.reshape(-1, k)
+    out = 0.0
+    for j in range(k):
+        held = (place[:, j] < bound)[:, None]
+        out = out + jnp.where(held, rows[jnp.minimum(place[:, j], bound - 1)].astype(jnp.float32), 0.0)
+    return out

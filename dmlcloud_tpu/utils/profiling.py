@@ -220,7 +220,8 @@ _TRANSFORMS = frozenset(
 )
 #: Kernels XLA itself puts in a program carry no scope: their phase goes by the
 #: instruction's name. ``jax.lax.ragged_dot`` is a grouped-matmul custom call
-#: on the TPU (``%ragged-dot-none.7``) whose ``op_name`` is that name alone.
+#: on the TPU (``%ragged-dot-none.7``) whose ``op_name`` is that name alone or,
+#: in a branch of the expert layer's ``cond``, the names round the ``cond``.
 _KERNEL_PHASES = (("ragged-dot", "moe_experts"),)
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=")

@@ -7,21 +7,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from dmlcloud_tpu.models import moe
 from dmlcloud_tpu.models.moe import (
-    MoEConfig, MoEMLP, moe_counters, moe_partition_rules, route, sort_pairs, total_aux_loss,
+    MoEConfig, MoEMLP, moe_counters, moe_partition_rules, route, row_bound, sort_pairs, total_aux_loss,
 )
-from dmlcloud_tpu.ops.grouped_matmul import collect, grouped_matmul, spread
+from dmlcloud_tpu.ops.grouped_matmul import collect, collect_rows, grouped_matmul, spread, spread_rows
 from dmlcloud_tpu.parallel import mesh as mesh_lib
+from dmlcloud_tpu.utils.profiling import phase_of
 
 B, T, D = 2, 16, 8
+TRUE_ROUTE = route
 
 
-def make_layer(**overrides):
+def make_layer(tokens=T, **overrides):
     kwargs = dict(num_experts=4, top_k=2, hidden_dim=D, mlp_dim=16, dtype=jnp.float32)
     kwargs.update(overrides)
     cfg = MoEConfig(**kwargs)
     model = MoEMLP(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(0), (B, T, D))
+    x = jax.random.normal(jax.random.PRNGKey(0), (B, tokens, D))
     variables = model.init(jax.random.PRNGKey(1), x)
     return model, {k: v for k, v in variables.items() if k in ("params", "buffers")}, x
 
@@ -110,6 +113,174 @@ class TestMoEMLP:
         _, variables, _ = make_layer(num_experts=8, experts_held=(2, 5))
         assert variables["params"]["moe/gate_proj"].shape == (3, D, 16)
         assert variables["params"]["router"]["kernel"].shape == (D, 8)  # the router keeps its width
+
+
+# A layer that holds 2 of 8 experts over 512 tokens with top-2: 1,024 pairs, 256 of them live with even
+# loads, and a row bound of 512 = row_bound(1024, 2, 8), so the layer has two compiled paths.
+BOUNDED = dict(tokens=256, num_experts=8, experts_held=(0, 2))
+PAIRS, BOUND = 2 * B * 256, 512
+
+
+def routed_to(live_pairs):
+    """``route`` with ``live_pairs`` of the 1,024 pairs sent to the held experts 0 and 1, whatever the scores."""
+    def fixed(cfg, logits, bias=None):
+        scores, _, _ = TRUE_ROUTE(cfg, logits, bias)
+        n = logits.shape[0]
+        first = np.where(np.arange(n) < min(live_pairs, n), np.arange(n) % 2, 2 + np.arange(n) % 6)
+        second = np.where(np.arange(n) < live_pairs - n, 1 - np.arange(n) % 2, 2 + (np.arange(n) + 1) % 6)
+        chosen = jnp.asarray(np.stack([first, second], axis=1), jnp.int32)
+        gates = jnp.sum(jax.nn.one_hot(chosen, cfg.num_experts) * scores[:, None, :], axis=-1)
+        return scores, chosen, gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
+    return fixed
+
+
+def value_and_grads(fn, variables, x):
+    return jax.value_and_grad(lambda v, x: jnp.sum(fn(v, x) ** 2), argnums=(0, 1))(variables, x)
+
+
+def assert_same_as_the_loop(model, variables, x):
+    """Output, and the gradients of every parameter and of the input, against the loop over experts;
+    returns the layer's counters."""
+    (y, stats) = model.apply(variables, x, mutable=["moe_stats"])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(by_hand(model.cfg, variables, x)), atol=1e-5)
+    _, got = value_and_grads(model.apply, variables, x)
+    _, want = value_and_grads(lambda v, x: by_hand(model.cfg, v, x), variables, x)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4, err_msg=str(path))
+        assert np.abs(np.asarray(w)).sum() > 0, path
+    return {name: float(v) for name, v in moe_counters(stats).items()}
+
+
+def eqns_in(jaxpr):
+    """Every equation of a jaxpr, its inner jaxprs' included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns_in(inner)
+
+
+def shapes_in(jaxpr):
+    return {tuple(var.aval.shape) for eqn in eqns_in(jaxpr) for var in eqn.outvars}
+
+
+class TestRowBound:
+    """The layer that holds a share of the experts works in ``row_bound`` rows when the live rows fit them,
+    and in all ``N * k`` when they do not: no pair dropped either way."""
+
+    @pytest.mark.parametrize("pairs, held, experts, want", [
+        (32768, 8, 64, 8192), (32768, 64, 64, 32768), (32768, 32, 64, 32768), (1024, 2, 8, 512), (2048, 4, 16, 1024),
+        (64, 2, 8, 64), (384, 4, 16, 384), (5000, 1, 64, 512),
+    ])
+    def test_the_bound_is_twice_the_even_share_in_whole_tiles_and_never_over_all_pairs(self, pairs, held, experts, want):
+        assert row_bound(pairs, held, experts) == want
+
+    def test_random_routing_takes_the_usual_path_and_is_the_loop_over_experts(self):
+        model, variables, x = make_layer(**BOUNDED)
+        counters = assert_same_as_the_loop(model, variables, x)
+        assert 0 < counters["moe/pairs_held"] <= BOUND and counters["moe/overflow_layers"] == 0
+
+    def test_every_pair_to_a_held_expert_takes_the_full_path_and_none_is_dropped(self, monkeypatch):
+        model, variables, x = make_layer(**BOUNDED)
+        monkeypatch.setattr(moe, "route", routed_to(PAIRS))
+        monkeypatch.setitem(globals(), "route", routed_to(PAIRS))  # by_hand routes the same way
+        counters = assert_same_as_the_loop(model, variables, x)
+        assert counters["moe/pairs_held"] == PAIRS and counters["moe/overflow_layers"] == 1
+        assert (np.abs(np.asarray(model.apply(variables, x))).sum(axis=-1) > 0).all()
+
+    @pytest.mark.parametrize("live_pairs, overflow", [(BOUND, 0), (BOUND + 1, 1), (BOUND - 1, 0), (0, 0)],
+                             ids=["L=R", "L=R+1", "L=R-1", "none-live"])
+    def test_the_edge_of_the_bound(self, monkeypatch, live_pairs, overflow):
+        model, variables, x = make_layer(**BOUNDED)
+        monkeypatch.setattr(moe, "route", routed_to(live_pairs))
+        monkeypatch.setitem(globals(), "route", routed_to(live_pairs))
+        if live_pairs == 0:  # nothing held: a zero output, zero gradients, and nothing not finite
+            y, stats = model.apply(variables, x, mutable=["moe_stats"])
+            grads = jax.grad(lambda v: jnp.sum(model.apply(v, x) ** 2))(variables)
+            assert not np.asarray(y).any() and all(np.isfinite(np.asarray(g)).all() for g in jax.tree_util.tree_leaves(grads))
+            counters = {name: float(v) for name, v in moe_counters(stats).items()}
+        else:
+            counters = assert_same_as_the_loop(model, variables, x)
+        assert counters["moe/pairs_held"] == live_pairs and counters["moe/overflow_layers"] == overflow
+
+    @pytest.mark.parametrize("overrides, conds", [(dict(), 0), (dict(num_experts=8, experts_held=(2, 6)), 0), (BOUNDED, 2)],
+                             ids=["all-held", "half-held", "quarter-held"])
+    def test_a_layer_whose_bound_is_all_pairs_has_one_path_and_no_cond(self, overrides, conds):
+        model, variables, x = make_layer(**overrides)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(model.apply(v, x) ** 2)))(variables)
+        conditionals = [eqn for eqn in eqns_in(jaxpr.jaxpr) if eqn.primitive.name == "cond"]
+        assert len(conditionals) == conds  # one forward, one backward
+        # on the TPU the grouped kernel's op_name is the path of names round it, and it is given its phase by its
+        # instruction's name only where that path holds none: no scope of the layer's own may lie round a cond
+        for eqn in conditionals:
+            assert phase_of(f"jit(step)/{eqn.source_info.name_stack}/cond/branch_1_fun/ragged-dot-none")[0] is None
+
+    @pytest.mark.parametrize("body", ["forward", "backward"])
+    def test_the_usual_path_holds_no_array_of_all_pairs_rows_with_a_feature_axis(self, body):
+        n, k, f = B * 256, 2, 16
+        group_sizes = jnp.asarray([100, 120], jnp.int32)
+        order = jax.random.permutation(jax.random.PRNGKey(0), n * k).astype(jnp.int32)
+        args = (jnp.ones((n, D)), jnp.ones((n * k,)), jnp.ones((2, D, f)), jnp.ones((2, D, f)), jnp.ones((2, f, D)),
+                order, jnp.argsort(order).astype(jnp.int32), group_sizes)
+        if body == "forward":
+            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_fwd(*a, k=k, bound=BOUND))(*args)
+        else:
+            saved = (jnp.ones((BOUND, D)), jnp.ones((BOUND, f)), jnp.ones((BOUND, f)), jnp.ones((BOUND, D)))
+            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_bwd(*a, k=k, bound=BOUND))(saved, *args, jnp.ones((n, D)))
+        shapes = shapes_in(jaxpr.jaxpr)
+        # index vectors and [N, k] arrays may have N * k entries; nothing may have N * k rows of features
+        assert max(int(np.prod(shape)) for shape in shapes) <= max(n * k, BOUND * f, n * D)
+        assert not [shape for shape in shapes if len(shape) > 1 and shape[0] == n * k]
+        # and the same check does see the full path's buffers
+        full = jax.make_jaxpr(lambda *a: moe._full_fwd(*a, k=k, bound=BOUND))(*args)
+        assert (n * k, D) in shapes_in(full.jaxpr)
+
+    @pytest.mark.parametrize("live_pairs", [200, PAIRS - 24], ids=["R-row-buffer", "N*k-row-buffer"])
+    def test_rows_past_the_live_ones_are_not_read(self, monkeypatch, live_pairs):
+        """On the TPU a grouped product leaves the rows past its groups as they were, forward and backward
+        (PR 30's NaN): whatever it leaves there must reach neither the output nor a gradient, in either buffer."""
+        model, variables, x = make_layer(**BOUNDED)
+        monkeypatch.setattr(moe, "route", routed_to(live_pairs))
+        want = value_and_grads(model.apply, variables, x)
+
+        @jax.custom_vjp
+        def poisoned(lhs, rhs, group_sizes):
+            out = grouped_matmul(lhs, rhs, group_sizes)
+            return jnp.where((jnp.arange(out.shape[0]) < jnp.sum(group_sizes))[:, None], out, jnp.nan)
+
+        def fwd(lhs, rhs, group_sizes):
+            return poisoned(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+        def bwd(saved, d_out):
+            lhs, rhs, group_sizes = saved
+            live = (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None]
+            d_lhs, d_rhs = jax.vjp(lambda a, b: grouped_matmul(a, b, group_sizes), lhs, rhs)[1](jnp.where(live, d_out, 0))
+            # a NaN among the dead rows of either operand of the weights' product would show in d_rhs already
+            return jnp.where(live, d_lhs, jnp.nan), d_rhs, None
+
+        poisoned.defvjp(fwd, bwd)
+        monkeypatch.setattr(moe, "grouped_matmul", poisoned)
+        for body in (moe._usual_fwd, moe._usual_bwd, moe._full_fwd, moe._full_bwd):
+            body.clear_cache()  # the bodies are traced once a process: not with the poison, and not kept with it
+        try:
+            got = value_and_grads(model.apply, variables, x)
+        finally:
+            for body in (moe._usual_fwd, moe._usual_bwd, moe._full_fwd, moe._full_bwd):
+                body.clear_cache()
+        for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-6)
+
+    def test_the_moves_of_the_first_rows_are_each_others_transposes(self):
+        n, k, d, bound = 12, 2, 4, 8
+        order = jax.random.permutation(jax.random.PRNGKey(0), n * k)
+        inverse = jnp.argsort(order)
+        tokens = jax.random.normal(jax.random.PRNGKey(1), (n, d))
+        rows = jax.random.normal(jax.random.PRNGKey(2), (bound, d))
+        np.testing.assert_array_equal(np.asarray(spread_rows(tokens, order, k, bound)),
+                                      np.asarray(spread(tokens, order, inverse, k))[:bound])
+        padded = jnp.concatenate([rows, jnp.zeros((n * k - bound, d))])
+        np.testing.assert_allclose(np.asarray(collect_rows(rows, inverse, k)), np.asarray(collect(padded, order, inverse, k)), rtol=1e-6)
+        np.testing.assert_allclose(float(jnp.vdot(spread_rows(tokens, order, k, bound), rows)),
+                                   float(jnp.vdot(tokens, collect_rows(rows, inverse, k))), rtol=1e-5)
 
 
 class TestSortedPairs:

@@ -167,3 +167,47 @@ def test_int8_training_dot_compiles_for_v5e(v5e, monkeypatch):
     loss = lambda x, w, scale: quant.quant_train_dot(x, w, scale).astype(jnp.float32).sum()
     hlo = _compile(jax.grad(loss, argnums=(0, 1)), x, w, scale)
     assert "s8[" in hlo  # the quantized kernel really is int8 in the program
+
+
+def test_a_bounded_expert_layer_writes_no_array_of_all_pairs_rows_on_its_usual_path(v5e):
+    """One expert layer of ``lfm2-train-8k`` (8 of 64 experts held, 8192 tokens, top-4), forward and
+    backward: the grouped products are the TPU's own kernel, each direction is one conditional, and the
+    branch taken when the live rows fit the row bound holds no ``[32768, features]`` array."""
+    import re
+
+    from dmlcloud_tpu.models.moe import MoEConfig, MoEMLP, row_bound
+    from dmlcloud_tpu.utils.profiling import phase_map
+
+    n, k, d, f, held, experts = 8192, 4, 2048, 1536, 8, 64
+    assert row_bound(n * k, held, experts) == 8192
+    model = MoEMLP(MoEConfig(num_experts=experts, top_k=k, hidden_dim=d, mlp_dim=f, use_expert_bias=True, experts_held=(0, held)))
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    x = jax.ShapeDtypeStruct((1, n, d), jnp.bfloat16, sharding=one_chip)
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)
+    variables = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), variables)
+    loss = lambda v, x: model.apply(v, x).astype(jnp.float32).sum()
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), {"params": variables["params"], "buffers": variables["buffers"]}, x)
+    # XLA's grouped kernel carries the names round it and none of the layer's own: its phase goes by its
+    # instruction's name (``_KERNEL_PHASES``), which a scope of the layer's round the conditional would override
+    kernels = {name: phase for name, (phase, _) in phase_map(text).items() if name.startswith("ragged-dot")}
+    assert kernels and set(kernels.values()) == {"moe_experts"}, kernels
+    computations = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \([^\n]*\) -> [^\n]*\{\n)", text):
+        computations[re.match(r"(?:ENTRY )?%?([\w.\-]+)", block).group(1)] = block
+    conditionals = re.findall(r"conditional\([^\n]*?(?:branch_computations=\{%?([\w.\-]+), %?([\w.\-]+)\}|"
+                              r"true_computation=%?([\w.\-]+), false_computation=%?([\w.\-]+))", text)
+    assert len(conditionals) == 2, conditionals  # forward and backward
+
+    def reach(name, seen):
+        if name in seen or name not in computations:
+            return seen
+        seen.add(name)
+        for called in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", computations[name]):
+            reach(called, seen)
+        return seen
+
+    wide = re.compile(rf"= \(?\w+\[{n * k},(?:{d}|{f})\]")
+    for first, second, true, false in conditionals:
+        full, usual = (first, second) if first else (false, true)  # branch 0 is the false one: the full path
+        assert not [c for c in reach(usual, set()) if wide.search(computations[c])]
+        assert [c for c in reach(full, set()) if wide.search(computations[c])]  # the pattern does see such arrays
