@@ -280,8 +280,8 @@ class DataPipeline(_DatasetBase):
         fraction that shrinks as ``chunk_docs`` grows. The returned
         pipeline's ``pack_stats`` (a :class:`PackStats`, live-updated
         during iteration) accounts for it: total padding-waste fraction
-        and the chunk-boundary share, the numbers the ``BENCH_data_*``
-        receipts report (doc/data.md).
+        and the chunk-boundary share (doc/data.md;
+        tests/test_data.py::TestPackStream counts both).
 
         ``pack_window > 0`` switches to **window-based first-fit-decreasing
         packing** (:func:`_pack_ffd_iter`): documents are buffered in
@@ -290,8 +290,9 @@ class DataPipeline(_DatasetBase):
         persist ACROSS windows, so there is no chunk-boundary tail waste
         at all — the only padding left is the end-of-stream flush and the
         slivers no remaining document fits. This reclaims most of the
-        ~19% greedy pad_fraction (BENCH_data_pr18 measures ≤ 0.10 on the
-        pinned corpus) at the cost of reordering rows WITHIN a window
+        greedy packer's pad_fraction (≤ 0.10 on the pinned corpus:
+        tests/test_data_store.py::TestFFDPacking::test_reclaims_greedy_padding)
+        at the cost of reordering rows WITHIN a window
         horizon; the emitted row sequence is still bit-deterministic given
         the input stream and ``pack_window`` (doc/data.md, "FFD window
         semantics"). ``chunk_docs`` is ignored in this mode."""

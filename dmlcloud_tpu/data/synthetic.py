@@ -1,9 +1,9 @@
-"""Synthetic corpora with learnable structure, for examples and benches.
+"""Synthetic corpora with learnable structure, for examples and tests.
 
 The reference ships no data generators (its examples download MNIST —
 /root/reference/examples/mnist.py); this module exists because several
 in-repo surfaces (examples/train_lm.py, examples/pod_llama_fsdp.py,
-bench.py's speculative bench) need a corpus a small model can actually
+tests/test_data.py) need a corpus a small model can actually
 LEARN — so losses drop, accept rates mean something, and smoke runs
 demonstrate optimisation rather than noise — without any network access.
 """
@@ -24,8 +24,8 @@ def markov_tokens(
 
     At the default ``noise=0.1`` the per-token entropy floor is
     ``0.9*ln(1/0.9) + 0.1*ln(vocab)`` ≈ 0.9 nats at vocab 512 — a trained
-    model's loss near that value means the chain was learned, which is the
-    learnedness gate bench.py's speculative bench prints.
+    model's loss near that value means the chain was learned
+    (tests/test_data.py::test_markov_tokens_learnable_structure).
 
     ``table_seed`` decouples the successor TABLE from the sequences: ranks
     of one training job (or a train corpus and its eval prompts) must share

@@ -1,7 +1,7 @@
 """``dmlcloud_tpu.lint`` — AST-based TPU-hazard linter.
 
-PR 1's overlap engine removed every host-sync point from the hot loop
-(1.65x steps/s on the CPU smoke A/B); this package keeps it that way. A
+PR 1's overlap engine removed every host-sync point from the hot loop;
+this package keeps it that way. A
 pure-stdlib AST pass detects, at review time and on CPU, the hazard
 patterns the framework exists to avoid — the things that silently claw the
 win back when the next ``Stage`` subclass reintroduces them:

@@ -80,7 +80,7 @@ def module_name(path: str) -> str:
     """Dotted module name of ``path``, walking up while ``__init__.py``
     marks a package (``.../dmlcloud_tpu/serve/kv_pool.py`` →
     ``dmlcloud_tpu.serve.kv_pool``). Scripts and loose files get their
-    stem (``bench.py`` → ``bench``)."""
+    stem (``chip_smoke.py`` → ``chip_smoke``)."""
     path = os.path.abspath(os.fspath(path))
     parts = [os.path.splitext(os.path.basename(path))[0]]
     d = os.path.dirname(path)

@@ -899,8 +899,8 @@ def lint_paths(
 
     ``jobs > 1`` fans the per-file pass out over a ``ProcessPoolExecutor``
     whose initializer installs the shared pass-1 registries once per
-    worker; on a single-core host the pool is a pure loss (measured in
-    BENCH_lint_pr05) so ``jobs`` silently collapses to 1 there. Findings
+    worker; on a single-core host the pool is a pure loss, so ``jobs``
+    silently collapses to 1 there. Findings
     merge in path order either way, so output is deterministic.
 
     ``ir=True`` adds the DML6xx IR pass (lint/ir.py — the ONE jax-needing

@@ -371,8 +371,8 @@ def check_dishonest_timing(ctx: ModuleCtx):
                 and len(sub.args) == 1
             ):
                 # a value fetch (`float(loss)`) forces the whole dependency
-                # chain — bench.py's documented completion sync on platforms
-                # where block_until_ready is unreliable
+                # chain — the completion sync on platforms where
+                # block_until_ready is unreliable
                 synced = True
             elif "step" in last.lower() or last in ctx.jitted_names:
                 dispatchy = True

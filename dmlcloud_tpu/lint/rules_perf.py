@@ -17,8 +17,8 @@ contracts that keep them fast checkable on CPU:
           pool (serve/kv_pool.py) or rewinding (generate.rewind_cache)
 - DML210  host readback of an on-device accept/round COUNTER inside a
           serve/decode loop (``.item()``/``int()``/``np.asarray()`` on
-          accept counts per round) — the extra per-round device sync that
-          made the r05 speculative path 0.19×; counters must stay on
+          accept counts per round) — one extra device sync per round;
+          counters must stay on
           device or ride the loop's one token fetch (packed columns,
           serve/engine.py's pattern)
 - DML211  a paged-scatter call (or a block-table-entry write) with NO
@@ -396,10 +396,9 @@ def check_counter_readback_in_loop(ctx: ModuleCtx):
     host EVERY iteration — ``counter.item()``, ``int(counter)``,
     ``float(counter)``, ``np.asarray(counter)``, ``jax.device_get(counter)``
     inside a ``for``/``while`` body — pays one extra device sync per
-    round on top of the loop's one sanctioned token fetch. That is the
-    exact regression that put the r05 speculative path at 0.19× plain:
-    per-round counter readbacks serialized every round against the
-    dispatch queue. Keep the counters on device across rounds, or pack
+    round on top of the loop's one sanctioned token fetch: per-round
+    counter readbacks serialize every round against the dispatch queue.
+    Keep the counters on device across rounds, or pack
     them into the same array the loop already fetches (the serving
     engine returns ``[tokens | n_new | n_accept]`` as ONE fetch —
     serve/engine.py). Flow-aware: a bare name is chased to its binding

@@ -2,9 +2,10 @@
 
 Decode is HBM-bandwidth-bound: every generated token streams the full
 weight set from HBM once, so halving the bytes (bf16 -> int8 + per-channel
-fp32 scales) is a direct throughput lever on the MEASURED bottleneck
-(bench.py's decode path runs at ~60% of the HBM roofline in bf16). The
-reference has no inference path at all, let alone a quantized one.
+fp32 scales) is the lever on a memory-bound decode step (int8 decode is not
+measured on the chip; PERF.md section 5 has the bf16 step's roofline
+share). The reference has no inference path at all, let alone a quantized
+one.
 
 Design:
 
@@ -18,8 +19,7 @@ Design:
   the fp32 accumulator — no dequantized weight copy is ever materialised,
   so the weight stream stays 1 byte/element end to end. (The pre-PR-6
   design dequantized the whole tree at program entry; XLA hoisted the
-  copies and the bandwidth saving never showed up — 1.02x in the r05
-  receipts, vs >= 1.2x fused.)
+  copies and the weight stream was bf16 again.)
 - Symmetric per-channel quantization: ``w ~= q * scale`` with the amax
   reduced over the kernel's leading input axes, so every trailing output
   coordinate keeps its own scale (see :func:`quantize`).
@@ -118,8 +118,7 @@ def _fused_quant_dot(x: jax.Array, qt: QuantizedTensor, dtype) -> jax.Array:
     # decode runs the native fp32 GEMM directly. The widen itself is hoisted
     # out of the decode loop by :func:`widen_quant_tree` (q arrives here
     # already fp32 and the astype below is a no-op); the bf16 baseline
-    # cannot hoist its emulation widen, and skipping that per-step tax is
-    # where the measured CPU decode win comes from.
+    # cannot hoist its emulation widen.
     if not jnp.issubdtype(q.dtype, jnp.integer):
         op_dtype = q.dtype  # pre-widened by widen_quant_tree — use as-is
     else:
@@ -150,7 +149,7 @@ def _train_op_dtype(dtype):
     # the same per-backend operand choice _fused_quant_dot makes: int8 is
     # exact in bf16 and fp32, TPU MXUs eat narrow operands natively,
     # XLA:CPU widens to the fp32 accumulator dtype (skipping the bf16
-    # GEMM-emulation tax — the measured CPU training win)
+    # GEMM-emulation tax)
     return dtype if jax.default_backend() == "tpu" else jnp.promote_types(jnp.float32, dtype)
 
 

@@ -15,8 +15,8 @@ where plain decode runs one, so a bf16 near-tie between two logits can
 reduce in a different order and flip an argmax; exact-arithmetic (fp32)
 configs are bitwise-identical. Decode cost per accepted token drops from one full
 weight-stream of the target to ``~1/(n_accept+1)`` of one, plus k+1 cheap
-draft passes; with a well-matched draft this is a 2-3x wall-clock win on
-the weight-bandwidth-bound decode path. (The reference has no inference
+draft passes (the wall-clock gain on the weight-bandwidth-bound decode
+path is not measured on the chip). (The reference has no inference
 path at all; this composes with the int8 weight-only quantization in
 ``models/quant.py`` — pass quantized trees for either model.)
 

@@ -9,8 +9,8 @@ to cover get their own small C++ library (``libdmltpu.so``, built by
   of ``data.interleave_batches``).
 - ``pack``: the greedy sequence packer (``pack_sequences_fast`` /
   ``pack_flat``) — bit-identical to ``data.pack_sequences``, one memcpy
-  pass instead of a per-document Python loop (19x on a 200k-doc corpus
-  via the flat-buffer path).
+  pass instead of a per-document Python loop (tests/test_native.py holds
+  the identity; its speed is not measured on the chip).
 
 Every entry point degrades gracefully to Python/numpy when the library
 isn't built.

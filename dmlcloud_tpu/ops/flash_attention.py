@@ -47,9 +47,8 @@ Kernel design (online-softmax, Dao-style but TPU-shaped):
   kernel.
 
 Off-TPU the op does NOT interpret the Pallas kernels by default any more:
-interpret mode emulates the grid step by step and LOSES to the unfused
-einsum path (measured 0.90x fwd / 0.48x fwd+bwd on the CPU smoke config —
-the PR 6 receipts). Instead ``impl="xla"`` (the off-TPU default) lowers the
+interpret mode emulates the grid step by step, far slower than the unfused
+einsum path on a CPU. Instead ``impl="xla"`` (the off-TPU default) lowers the
 SAME blockwise algorithm to plain XLA ops: a static Python loop over query
 blocks, causal/window K-truncation per block (the compute saving survives),
 the identical LSE residual, and the identical recompute-from-statistics
@@ -84,10 +83,10 @@ _NEG_INF = -1e30
 _LANES = 128
 
 #: default query-block of the XLA (off-TPU) path: small enough that causal
-#: K-truncation prunes ~40% of the score matmuls at CPU-bench sequence
-#: lengths, large enough to keep per-block dispatch negligible. Measured on
-#: the CPU smoke config (S=512): 128-blocks run the fwd at ~1.4x the unfused
-#: einsum where a single 512 block only breaks even.
+#: K-truncation prunes ~40% of the score matmuls at tier-1 sequence
+#: lengths, large enough to keep per-block dispatch negligible (a single
+#: 512 block prunes nothing at S=512). A CPU-side choice: tier-1's wall time
+#: is all it moves.
 _XLA_BLOCK_Q = 128
 
 
@@ -534,9 +533,8 @@ def flash_attention(
     ``"pallas"``.
 
     Default Pallas blocks are large (512x1024) because the grid-step
-    overhead, not VMEM, is the binding constraint on TPU: measured on v5e,
-    256x256 blocks LOSE to the unfused einsum path while 512x1024 is ~1.5x
-    faster at S=4k and ~2.3x at S=8k (fwd, causal, d=64..128). Where a sweep
+    overhead, not VMEM, is the binding constraint on TPU: 256-wide blocks
+    lose most in the v5e sweep of PERF.md section 6 (PR 27). Where a sweep
     on the v5e covered exactly this call's shapes, the kernels take the
     sweep's choice instead (``_SWEPT_BLOCKS``); an explicit ``block_q`` /
     ``block_k`` wins over both. The XLA path defaults to

@@ -73,7 +73,8 @@ Quick start::
     engine.run()
     tokens = engine.output(rid)
 
-See doc/serving.md for the architecture, memory math and bench receipts.
+See doc/serving.md for the architecture, the memory math and the tests that
+hold each contract.
 """
 
 from .adapters import AdapterSet

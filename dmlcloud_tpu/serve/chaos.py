@@ -39,7 +39,7 @@ events from the same seeded RNG into the same replayable log:
 Everything draws from ``numpy.random.RandomState(seed)`` in a fixed
 per-step order, so a drill is REPLAYABLE: the same seed over the same
 trace injects the same faults at the same points. The drill's acceptance
-bar (tests/test_serve.py, ``BENCH_serve_chaos_*``): every request ends
+bar (tests/test_serve.py::TestChaosDrill): every request ends
 terminal, ``free + unique-live == capacity`` in every pool (checked with
 ``assert_consistent`` after every step, squat included), zero prefix
 lock leaks, and greedy SURVIVORS are token-identical to a fault-free run

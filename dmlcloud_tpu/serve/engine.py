@@ -63,8 +63,7 @@ first (``_cow_guard``: fork any refcount>1 block the scatter would touch
 — one traced ``_copy_block`` signature for every fork ever; lint DML211
 enforces the ordering), and the pool evicts leaf-first by LRU over
 refcount when the free list runs dry. Greedy output stays token-identical
-to the uncached engine — the committed ``BENCH_serve_prefix_*.json``
-receipt re-asserts it on an 80%-shared-template trace.
+to the uncached engine (tests/test_serve_prefix.py::TestPrefixEngine).
 
 **Per-request sampling.** ``temperature``/``top_k``/``top_p``/``eos_id``
 ride each :class:`Request` and enter the compiled steps as per-row traced
@@ -107,8 +106,8 @@ production traffic.
 (tokens), and in spec mode the per-row ``n_new``/``n_accept`` counters
 ride THAT SAME fetch as two extra packed columns — no separate
 ``.item()``/``int()`` readback of accept counters anywhere in the loop
-(lint rule DML210 exists because a per-round counter readback is exactly
-the host sync that made the r05 speculative path 0.19×).
+(lint rule DML210 exists because a per-round counter readback is one more
+host sync a round).
 
 The decode math itself is :func:`models.generate.decode_step` — the same
 primitive ``generate``/``beam_search``/``speculative_generate`` run — with
@@ -1291,8 +1290,7 @@ class ServeEngine:
         keywords — ``tenant``/``deadline_s``/``priority``/sampling).
         Requests are submitted when the wall reaches their offset; the
         engine steps continuously in between. ``clock`` defaults to the
-        engine's own (injectable) clock. Returns the ledger summary — the
-        bench receipt's engine side."""
+        engine's own (injectable) clock. Returns the ledger summary."""
         if clock is None:
             clock = self.clock
         pending = sorted(trace, key=lambda e: e[0])

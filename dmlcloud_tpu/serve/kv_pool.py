@@ -136,7 +136,7 @@ class KVBlockPool:
     def stats(self) -> dict:
         """The pool's accounting snapshot (``free + live == capacity`` by
         construction): the utilization observable the serving scorecard
-        and bench receipts record — a draft-model speculative engine pays
+        records — a draft-model speculative engine pays
         for TWO of these (target + draft pages), and this is the number
         that says what the draft pool actually costs (and what Medusa
         mode, which has no second pool, wins back)."""

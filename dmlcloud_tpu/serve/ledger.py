@@ -28,8 +28,8 @@ Prefix sharing adds the cache observables: per request, the tokens the
 radix tree matched at admission (``cached_tokens``), the prefill tokens
 the skip actually saved (``saved_tokens`` — the divergence point), and
 the prompt length, reduced in ``summary()`` to the hit rate, the
-cached-token fraction and the prefill-tokens-saved fraction — the numbers
-the ``BENCH_serve_prefix_*`` receipt gates.
+cached-token fraction and the prefill-tokens-saved fraction
+(tests/test_serve_prefix.py::TestPrefixEngine counts them on a fixed trace).
 
 Speculative serving adds the accept-rate observables: per request, the
 tokens proposed per round (``drafted`` — the spec draft model's, or the
@@ -93,7 +93,7 @@ class ServeLedger:
         self._waits: collections.deque[float] = collections.deque(maxlen=window)
         # per-tenant TTFT windows: same event-time windowing as _ttfts, so
         # the per-tenant percentiles in summary() survive record eviction
-        # (the router's fairness receipt reads these)
+        # (the fairness observable: tests/test_serve_router.py::TestLedgerTenantPercentiles)
         self._tenant_ttfts: dict[str, collections.deque] = {}
         self._agg = {
             "requests": 0, "completed": 0, "tokens": 0, "ok_tokens": 0,
@@ -293,7 +293,6 @@ class ServeLedger:
             # per-tenant TTFT percentiles over the same windowed samples
             # (exactly what callers used to re-derive by hand from
             # ttfts(tenant=), but eviction-proof): the fairness observable
-            # the router receipt gates on
             "tenant_ttft": {
                 tenant: {
                     "n": len(dq),

@@ -60,10 +60,10 @@ deterministic, replayable event log as the engine-level faults.
 replica closes, its QUEUED requests migrate to siblings (cancel +
 resubmit — they hold nothing yet), its RUNNING requests finish in place,
 and when it empties the replica is removed and a PR-7 ``requeue.json``
-verdict records the drain. The receipt (``BENCH_serve_router_pr15``)
-drills exactly this: a 3-replica Poisson multi-tenant trace, one replica
-killed mid-trace and one drained, gated on every-request-terminal, zero
-leaks, survivor token-identity and bounded cold-tenant TTFT.
+verdict records the drain. tests/test_serve_router.py::TestRouterIntegration
+drills exactly this over three real engines, one replica killed mid-flight
+and one drained: every request terminal, zero leaked blocks across live and
+dead replicas, survivors token-identical to a fault-free engine.
 """
 
 from __future__ import annotations
@@ -195,7 +195,7 @@ class Router:
         #: stall replicas (serve/chaos.py attach_router)
         self.fault_injector: Callable[[str, Any], None] | None = None
         self.steps = 0
-        #: failure-handling counters (the receipt's observables)
+        #: failure-handling counters (``metrics_text`` exports them)
         self.failovers = 0
         self.kills = 0
 

@@ -374,7 +374,7 @@ class TrainValStage(Stage):
         #: padding accounting over this epoch's HOST batches (telemetry
         #: only): slots whose ``segment_ids`` mark padding vs all token
         #: slots — ``misc/pad_fraction``, the signal the goodput advisor
-        #: and the data-plane receipts read (doc/data.md)
+        #: reads (doc/data.md)
         self._gp_pad_slots = 0
         self._gp_token_slots = 0
 
@@ -465,7 +465,8 @@ class TrainValStage(Stage):
 
         Rules of thumb: transformer training ≈ ``6 * params * tokens_per_
         batch`` (PaLM convention, embedding lookups excluded); ResNet-50 @
-        224² ≈ ``24.6e9 * images_per_batch`` (see bench.py)."""
+        224² ≈ ``24.6e9 * images_per_batch`` (forward and backward, a
+        multiply-add counted as two operations)."""
         return 0.0
 
     def model_name(self) -> str | None:

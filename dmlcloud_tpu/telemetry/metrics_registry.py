@@ -30,8 +30,7 @@ per family, ``name{label="v"} value`` samples, histograms as cumulative
 merges MULTIPLE snapshots into one page — the router passes each
 replica's snapshot tagged with a ``replica`` label and its own on top,
 one scrape surface for the whole pool. :func:`parse_prometheus_text` is
-the strict round-trip validator the bench receipt and the schema-locked
-tests share.
+the strict round-trip validator the schema-locked tests use.
 """
 
 from __future__ import annotations
@@ -58,7 +57,7 @@ _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
 
 #: Fixed bucket sets for the serving latency histograms. Fixed (not
-#: adaptive) so dashboards and receipts compare across runs and hosts.
+#: adaptive) so dashboards compare across runs and hosts.
 TTFT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 ITL_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
 QUEUE_DEPTH_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0)
@@ -361,9 +360,9 @@ def parse_prometheus_text(text: str) -> dict:
     ``{family: {"type": kind, "samples": [(name, labels, value), ...]}}``.
     Raises ``ValueError`` on any malformed line, a sample without a
     preceding ``# TYPE``, a duplicate ``# TYPE``, or a histogram missing
-    its ``_sum``/``_count``/``+Inf`` bucket — the round-trip validator
-    the receipt's ``obs_metrics_valid`` key and the schema-locked tests
-    share."""
+    its ``_sum``/``_count``/``+Inf`` bucket — the round-trip validator of
+    the schema-locked tests (tests/test_observability.py, and the router's
+    page in tests/test_serve_router.py::TestRouterIntegration)."""
     families: dict[str, dict] = {}
     current: str | None = None
 

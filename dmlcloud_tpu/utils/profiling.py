@@ -10,11 +10,10 @@ SURVEY.md §5.1): capture real XLA traces viewable in TensorBoard/Perfetto.
 - ``phase_table(trace_dir, phases)``: device time by phase and by kernel from
   a trace, read with ``jax.profiler.ProfileData`` alone.
 - ``StepTimer``: dispatch-to-dispatch wall timer with p50/p95 summaries, the
-  host-side complement used by bench.py.
+  host-side complement of a device trace.
 - ``StallTimer``: accumulates the wall-clock the host spends *blocked* on
   device results or pending checkpoint commits — the overlap engine's
-  ``misc/host_stall_ms`` metric (stage.py) and the host-stall fraction
-  ``bench.py --overlap-child`` reports.
+  ``misc/host_stall_ms`` metric (stage.py).
 """
 
 from __future__ import annotations
@@ -160,8 +159,8 @@ def profile_steps(fn, n: int, logdir: str, *args, **kwargs):
 
 
 #: bf16 peak FLOP/s by TPU device_kind substring (Google Cloud's published
-#: per-chip peaks). The same table bench.py uses for its MFU lines; a kind
-#: that is not here has no peak — ``chip_peak_flops`` raises.
+#: per-chip peaks), what ``misc/mfu`` divides by; a kind that is not here
+#: has no peak — ``chip_peak_flops`` raises.
 PEAK_BF16_FLOPS = {
     "v4": 275e12,
     "v5 lite": 197e12,

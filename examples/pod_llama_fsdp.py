@@ -36,8 +36,8 @@ Every choice, spelled out:
   batch stays ``--global-batch`` while activation memory drops ~N×, so use
   it to fit a bigger global batch than activations would otherwise allow.
 - **`--remat`**: block-granular rematerialisation; at 8B/s4096 activations
-  without remat exceed HBM. Costs ~30% step time for ~3.4x activation
-  memory (measured: bench.py lm_scale).
+  without remat exceed HBM. It trades recomputed forward work for
+  activation memory (neither is measured on the chip: no cell turns remat on).
 - **`--chunked-loss 8192`**: the 128k-vocab logits tensor ([8, 4096,
   128256] bf16 = 8 GB per chip) is never materialised; chunked_lm_loss
   streams vocab blocks (models/transformer.py chunked_lm_loss).
@@ -135,8 +135,7 @@ class LlamaStage(dml.TrainValStage):
     def step_flops(self):
         import jax.tree_util as jtu
 
-        # 6*params*tokens, embedding lookups excluded (PaLM convention —
-        # same accounting as bench.py's MFU)
+        # 6*params*tokens, embedding lookups excluded (PaLM convention)
         n = sum(int(x.size) for x in jtu.tree_leaves(self.state.params)) - int(
             self.state.params["embed"]["embedding"].size
         )

@@ -10,7 +10,7 @@ Two input modes:
   ids or an object with a ``"tokens"`` key. ``-`` reads stdin, so any
   tokenizer can pipe straight in.
 - ``--synthetic N``: N documents with lognormal lengths from a pinned
-  seed — the same generator family as the BENCH_data_* receipts, handy
+  seed (as tests/test_data_store.py builds its corpus) — handy
   for smoke-testing the disk plane without a real corpus.
 
     python scripts/build_corpus.py --synthetic 768 --out /tmp/corpus

@@ -366,3 +366,62 @@ class TestBuilderEdgeCases:
         a = reader_activity()
         list(ShardReader(d, rank=0, world_size=1, read_ahead=32))
         assert reader_activity() > a
+
+
+class TestFixedShapeRows:
+    """The data plane's compile contract: every arm hands the stage rows of
+    ONE shape, so a step precompiled from the first batch is the only
+    program the run ever builds."""
+
+    @pytest.mark.parametrize("arm", ["pad", "packed", "disk"])
+    def test_stream_through_a_precompiled_stage_never_recompiles(self, corpus, single_runtime, arm):
+        import jax
+        import jax.numpy as jnp
+        import optax
+
+        import dmlcloud_tpu as dml
+        from dmlcloud_tpu.parallel import mesh as mesh_lib
+
+        d, docs, _ = corpus
+        seq_len = 64
+
+        def pad_row(doc):
+            doc = doc[:seq_len]
+            tokens = np.zeros(seq_len, np.int32)
+            segs = np.zeros(seq_len, np.int32)
+            tokens[: doc.size] = doc
+            segs[: doc.size] = 1
+            return {"tokens": tokens, "segment_ids": segs}
+
+        if arm == "pad":
+            stream = DataPipeline.from_source(docs[:48]).map(pad_row)
+        elif arm == "packed":
+            stream = DataPipeline.from_source(docs[:48]).pack_stream(seq_len, chunk_docs=16)
+        else:
+            stream = ShardReader(d).pack_stream(seq_len, pack_window=32)
+        ds = stream.batch(
+            4, drop_remainder=True,
+            collate=lambda rows: {k: np.stack([r[k] for r in rows]) for k in ("tokens", "segment_ids")},
+        )
+
+        class Stage(dml.TrainValStage):
+            def pre_stage(self):
+                self.pipeline.register_model(
+                    "emb", apply_fn=lambda p, t: p["emb"][t], params={"emb": jnp.ones((512, 4))}, verbose=False
+                )
+                self.pipeline.register_optimizer("sgd", optax.sgd(0.01))
+                self.pipeline.register_dataset("train", ds, verbose=False)
+
+            def step(self, state, batch):
+                real = (batch["segment_ids"] > 0)[..., None]
+                return jnp.sum(jnp.where(real, state.apply_fn(state.params, batch["tokens"]) ** 2, 0.0)) / jnp.sum(real)
+
+            def val_epoch(self):
+                pass
+
+        pipeline = dml.TrainingPipeline(name=f"rows-{arm}", precompile=True)
+        pipeline.set_mesh(mesh_lib.create_mesh({"data": 1}, devices=jax.devices()[:1]))
+        pipeline.append_stage(Stage(), max_epochs=2)
+        pipeline.run()
+        assert pipeline.tracker["misc/worker_train_batches"][-1] >= 3
+        assert pipeline.tracker["misc/recompiles"] == [0, 0]

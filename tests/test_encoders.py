@@ -104,7 +104,6 @@ def test_bert_classifier_shapes():
     assert out.shape == (2, 3)
 
 
-@pytest.mark.slow
 def test_bert_sharded_finetune_step():
     """BERT fine-tune (the BASELINE ladder rung) on a data+model mesh."""
     mesh = mesh_lib.create_mesh({"data": 4, "model": 2})
@@ -190,7 +189,6 @@ def _clip_batch(n):
     return images, tokens
 
 
-@pytest.mark.slow
 def test_clip_forward_and_loss():
     model = CLIP(CLIP_TINY)
     images, tokens = _clip_batch(4)
@@ -229,7 +227,6 @@ def test_clip_global_batch_loss_matches_single_device():
     assert got == pytest.approx(expected, rel=1e-5)
 
 
-@pytest.mark.slow
 def test_encoder_flash_attention_matches_dot():
     """attn_impl='flash' (unmasked path) must match the einsum softmax, in
     both directions, causal and not."""

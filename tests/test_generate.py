@@ -46,7 +46,6 @@ def _greedy_no_cache(model, params, prompt, n):
     return jnp.stack(out, axis=1)
 
 
-@pytest.mark.slow
 def test_greedy_matches_no_cache():
     cfg = _tiny_cfg()
     model, params, prompt = _init(cfg)
@@ -55,7 +54,6 @@ def test_greedy_matches_no_cache():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.slow
 def test_gqa_greedy_matches_no_cache():
     cfg = _tiny_cfg(num_kv_heads=2)
     model, params, prompt = _init(cfg)
@@ -89,7 +87,6 @@ def test_sampling_deterministic_under_rng():
     assert not np.array_equal(np.asarray(a), np.asarray(c))
 
 
-@pytest.mark.slow
 def test_moe_decode_runs():
     cfg = _tiny_cfg(num_experts=2, num_dense_layers=1)
     model, params, prompt = _init(cfg)
@@ -111,7 +108,6 @@ def test_init_cache_shapes():
     assert cache["layer_0"]["k"].shape == (3, 32, 2, 8)
 
 
-@pytest.mark.slow
 def test_top_p_sampling():
     cfg = _tiny_cfg()
     model, params, prompt = _init(cfg)
@@ -137,7 +133,6 @@ class TestBeamSearch:
         np.testing.assert_array_equal(np.asarray(beams), np.asarray(greedy))
         assert np.isfinite(np.asarray(scores)).all()
 
-    @pytest.mark.slow
     def test_full_beam_finds_global_optimum(self):
         """With K = V^(N-1) beams, beam search is exhaustive: its winner must
         be the true argmax over all V^N continuations, scored by rerunning
@@ -162,7 +157,6 @@ class TestBeamSearch:
         assert tuple(np.asarray(beams)[0].tolist()) == best_cont
         assert abs(float(score[0]) - all_scores[best_cont] / n) < 1e-4  # len-normalised
 
-    @pytest.mark.slow
     def test_beam_scores_are_honest(self):
         """The reported score must equal rescoring the winning continuation
         with the full model (beam >= greedy is NOT asserted — the greedy
@@ -208,7 +202,6 @@ class TestBeamSearch:
         with pytest.raises(ValueError, match="vocab"):
             beam_search(model, params, prompt, 4, num_beams=100)
 
-    @pytest.mark.slow
     def test_eos_freezes_multi_beam(self):
         """With k > 1, any beam that emits eos must continue as pure pad
         (exercises reorder + freeze interaction, not just the k=1 identity)."""
@@ -248,7 +241,6 @@ class TestBeamSearch:
 
 
 class TestRaggedPrompts:
-    @pytest.mark.slow
     def test_left_padded_rows_match_unpadded(self):
         """Each left-padded row must decode exactly as its unpadded self."""
         cfg = _tiny_cfg()
@@ -306,7 +298,6 @@ class TestRaggedPrompts:
             generate(model, params, prompt, 4, prompt_mask=np.ones(7, np.int32))
 
 
-@pytest.mark.slow
 def test_ragged_beam_rows_match_unpadded():
     from dmlcloud_tpu.models.generate import beam_search
 
@@ -372,7 +363,6 @@ def test_attend_len_bounds_cache_reads():
     assert new_cache["layer_0"]["k"].shape[1] == 32
 
 
-@pytest.mark.slow
 def test_long_generation_exercises_multi_step_segments():
     """max_new_tokens > _DECODE_CHUNKS forces scan segments longer than one
     step, where attend_len runs AHEAD of the fill inside a segment — greedy

@@ -33,7 +33,6 @@ def _tiny_hf(tie=False, kv_heads=2):
     return hf_cfg, model
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("tie,kv_heads", [(False, 2), (False, 4), (True, 2)])
 def test_logits_match_hf(tie, kv_heads):
     hf_cfg, hf_model = _tiny_hf(tie=tie, kv_heads=kv_heads)
@@ -50,7 +49,6 @@ def test_logits_match_hf(tie, kv_heads):
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_generate_from_hf_weights():
     """Converted weights drive the KV-cache decode loop: greedy generation
     equals HF's own greedy generation."""

@@ -390,10 +390,9 @@ class TestCliWorkflow:
 # self-analysis lock: the codebase itself must hold its own contracts
 # --------------------------------------------------------------------------
 class TestSelfAnalysis:
-    @pytest.mark.slow
     def test_whole_program_pass_is_clean_on_repo(self):
         repo = Path(__file__).parent.parent
-        targets = [repo / "dmlcloud_tpu", repo / "examples", repo / "bench.py", repo / "scripts"]
+        targets = [repo / "dmlcloud_tpu", repo / "examples", repo / "chip_smoke.py", repo / "scripts"]
         findings = lint_paths([t for t in targets if t.exists()])
         dml5 = [f for f in findings if f.rule.startswith("DML5")]
         assert dml5 == [], [f.format() for f in dml5]

@@ -40,7 +40,6 @@ def test_mnist_cnn_shapes():
     assert out.shape == (2, 10)
 
 
-@pytest.mark.slow
 def test_resnet18_forward():
     model = ResNet18(num_classes=10, dtype=jnp.float32)
     vars_ = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)), train=False)
@@ -83,7 +82,6 @@ def test_decoder_causality():
     )
 
 
-@pytest.mark.slow
 def test_decoder_sharded_train_step_dp_fsdp_tp():
     """Full dp+fsdp+tp train step on a 2x2x2 mesh: compiles, runs, loss drops."""
     mesh = mesh_lib.create_mesh({"data": 2, "fsdp": 2, "model": 2})
@@ -122,7 +120,6 @@ def test_decoder_sharded_train_step_dp_fsdp_tp():
     assert losses[-1] < losses[0]
 
 
-@pytest.mark.slow
 def test_decoder_ring_attention_matches_dot():
     """The full model with ring attention over the seq axis == dot attention."""
     mesh = mesh_lib.create_mesh({"data": 2, "seq": 4})
@@ -146,7 +143,6 @@ def test_decoder_flash_attention_matches_dot():
     np.testing.assert_allclose(np.asarray(logits_dot), np.asarray(logits_flash), atol=2e-4, rtol=2e-4)
 
 
-@pytest.mark.slow
 def test_decoder_remat_matches_no_remat():
     """Gradient rematerialisation must be numerics-neutral: same logits,
     same gradients, only the backward memory schedule changes."""
@@ -164,7 +160,6 @@ def test_decoder_remat_matches_no_remat():
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=1e-5, rtol=1e-5)
 
 
-@pytest.mark.slow
 def test_encoder_remat_matches_no_remat():
     from dmlcloud_tpu.models.encoder import EncoderConfig, TransformerEncoder
 

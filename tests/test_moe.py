@@ -45,7 +45,6 @@ def by_hand(cfg, variables, x):
 
 
 class TestMoEMLP:
-    @pytest.mark.slow
     def test_forward_shape_and_finite(self):
         model, params, x = make_layer()
         y = model.apply(params, x)
@@ -345,7 +344,6 @@ class TestExpertParallel:
 
 
 class TestMoETransformer:
-    @pytest.mark.slow
     def test_decoder_lm_with_moe(self):
         from dmlcloud_tpu.models.transformer import DecoderLM, TransformerConfig, lm_loss
 

@@ -19,7 +19,7 @@ import pytest
 
 from dmlcloud_tpu.utils.tcp import find_free_port
 
-pytestmark = [pytest.mark.multiprocess, pytest.mark.slow]
+pytestmark = pytest.mark.multiprocess
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

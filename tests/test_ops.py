@@ -11,8 +11,6 @@ from dmlcloud_tpu.ops.flash_attention import flash_attention
 from dmlcloud_tpu.ops.ring_attention import ring_attention_sharded
 from dmlcloud_tpu.parallel import mesh as mesh_lib
 
-pytestmark = pytest.mark.slow
-
 
 def _qkv(b=2, t=128, h=4, kh=None, d=32, seed=0, dtype=jnp.float32):
     kh = kh or h

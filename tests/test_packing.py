@@ -10,8 +10,6 @@ import pytest
 
 from dmlcloud_tpu.models.transformer import DecoderLM, TransformerConfig, lm_loss
 
-pytestmark = pytest.mark.slow
-
 
 def _cfg(**kw):
     base = dict(

@@ -6,12 +6,8 @@ chunked loss + Orbax step checkpointing), only the sizes differ."""
 import os
 import sys
 
-import pytest
-
 _EXAMPLES = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "examples")
 sys.path.insert(0, _EXAMPLES)
-
-pytestmark = pytest.mark.slow
 
 
 def _run(module_name, argv, monkeypatch):

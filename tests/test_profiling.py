@@ -8,7 +8,6 @@ import pytest
 from dmlcloud_tpu.utils.profiling import StepTimer, profile_steps, trace
 
 
-@pytest.mark.slow
 def test_trace_writes_profile(tmp_path):
     logdir = tmp_path / "prof"
     with trace(str(logdir)):

@@ -4,7 +4,6 @@ size accounting, and quantized decode through the real generate path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from dmlcloud_tpu.models.quant import (
     QuantizedTensor,
@@ -55,7 +54,6 @@ def test_quantize_tree_matches_kernels_only():
 # quant_lm (the 64-vocab decode LM) comes from conftest.py, session-scoped.
 
 
-@pytest.mark.slow
 def test_quantized_generate_matches_shapes_and_tracks_full(quant_lm):
     from dmlcloud_tpu.models.generate import generate
 

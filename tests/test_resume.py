@@ -423,7 +423,6 @@ class _PreemptAtEpoch(_ToyStage):
             os.kill(os.getpid(), signal.SIGUSR1)
 
 
-@pytest.mark.slow
 def test_preemption_exits_cleanly_and_resumes(tmp_path, single_runtime):
     # run 1: signal arrives during epoch 2 of 5 -> clean exit, NOT stopped
     p1 = dml.TrainingPipeline(name="toy")
@@ -467,7 +466,6 @@ def test_preemption_skips_remaining_stages(tmp_path, single_runtime):
     p.checkpoint_dir.close()
 
 
-@pytest.mark.slow
 def test_preemption_forces_save_despite_checkpoint_every(tmp_path, single_runtime):
     """checkpoint_every() > 1 must not lose the preempted epoch: the
     preemption exit is 'final' for the save decision."""

@@ -16,6 +16,9 @@ The load-bearing contracts, each tested here:
   exactly the base model;
 - the latency ledger and the ``queue_wait``/``prefill``/``decode_batch``
   journal spans record what actually happened.
+
+The prefix cache is in test_serve_prefix.py, the speculative and Medusa
+modes in test_serve_spec.py (both import this file's helpers).
 """
 
 import numpy as np
@@ -26,15 +29,13 @@ import jax.numpy as jnp
 
 from dmlcloud_tpu.models.generate import decode_step, generate, init_cache
 from dmlcloud_tpu.models.lora import LoraPair, lora_init, lora_merge
-from dmlcloud_tpu.models.speculative import init_medusa_heads
-from dmlcloud_tpu.models.transformer import DecoderLM, TransformerConfig
+from dmlcloud_tpu.models.transformer import TransformerConfig
 from dmlcloud_tpu.ops.paged_attention import gather_pages, scatter_tokens
 from dmlcloud_tpu.serve import (
     AdapterSet,
     ChaosMonkey,
     KVBlockPool,
     PoolExhausted,
-    PrefixCache,
     ServeEngine,
     TERMINAL_STATUSES,
 )
@@ -277,7 +278,6 @@ class TestEngineIdentity:
 
 
 class TestSchedulerProperties:
-    @pytest.mark.slow  # random-load property drill; per-step invariants also locked by the cheap FIFO/EOS unit tests
     def test_no_starvation_under_random_load(self, tiny_model):
         """30 random requests into 3 slots over a tight pool: every
         admitted request finishes, admissions are strict FIFO, the pool
@@ -319,7 +319,6 @@ class TestSchedulerProperties:
 
 
 class TestBucketing:
-    @pytest.mark.slow  # shape-churn property drill; the spec/medusa budget + warm-replay locks stay tier-1
     def test_churning_traffic_stays_inside_the_signature_budget(self, tiny_model):
         """Random churn (ragged prompts, ragged budgets, slots freeing and
         refilling) never compiles past max_signatures — TraceGuard is
@@ -347,199 +346,6 @@ class TestBucketing:
             engine.run(max_steps=5000)
             if assert_warm:
                 assert engine.compiled_signatures() == before
-
-
-# ---------------------------------------------------------------------------
-# speculative decoding inside the engine (draft/verify over paged KV)
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def tiny_draft():
-    """An INDEPENDENT random-init draft (different arch): near-zero accept
-    rate, so every round exercises the partial-accept rewind."""
-    cfg = _tiny_cfg(num_layers=1, num_heads=2, num_kv_heads=1, hidden_dim=16, mlp_dim=32)
-    model = DecoderLM(cfg)
-    params = model.init(jax.random.PRNGKey(9), jnp.ones((1, 4), jnp.int32))["params"]
-    return model, params
-
-
-class TestSpeculativeEngine:
-    def test_self_draft_identity_and_exact_full_accept(self, tiny_model):
-        """Shared-model self-draft (the smoke config): greedy output
-        token-identical to serial generate, accept rate EXACTLY 1.0, both
-        pools drained clean."""
-        model, params = tiny_model
-        specs = [(7, 6), (13, 4), (5, 9), (22, 5)]
-        engine = _engine(model, params, spec_k=3)
-        rids = [engine.submit(_prompt(n, seed=i), m) for i, (n, m) in enumerate(specs)]
-        out = engine.run(max_steps=5000)
-        for rid, (n, m) in zip(rids, specs):
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(n, seed=rid))[None], m)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        s = engine.ledger.summary()
-        assert s["accept_rate"] == 1.0
-        assert s["drafted_tokens"] > 0
-        assert engine.pool.num_free == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-
-    @pytest.mark.slow  # heavyweight random-draft drill; accept~0 identity also locked by the self-draft + eos round tests
-    def test_partial_accepts_stay_token_identical(self, tiny_model, tiny_draft):
-        """An independent random draft disagrees with the target almost
-        everywhere — near-zero accept — yet greedy output must STILL be
-        token-identical to serial generate: rejected proposals leave stale
-        K/V that the rewind contract (fill counters roll back, contiguous
-        rewrites beat the causal mask) must fully hide."""
-        model, params = tiny_model
-        draft, dparams = tiny_draft
-        specs = [(7, 6), (13, 4), (5, 9), (22, 5), (3, 8)]
-        engine = _engine(
-            model, params, max_slots=3, spec_k=4, draft_model=draft, draft_params=dparams
-        )
-        rids = [engine.submit(_prompt(n, seed=i), m) for i, (n, m) in enumerate(specs)]
-        out = engine.run(max_steps=5000)
-        for rid, (n, m) in zip(rids, specs):
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(n, seed=rid))[None], m)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        assert engine.ledger.summary()["accept_rate"] < 0.5  # genuinely partial
-
-    @pytest.mark.slow  # random-load property drill over both pools
-    def test_spec_random_load_invariants(self, tiny_model, tiny_draft):
-        """The satellite property test: random spec-decode load with
-        partial accepts — after EVERY engine step both pools hold
-        free + live == capacity, admissions stay strict FIFO, every
-        request finishes (starvation-free), and the drained pools are
-        pristine."""
-        model, params = tiny_model
-        draft, dparams = tiny_draft
-        rs = np.random.RandomState(13)
-        engine = ServeEngine(
-            model, params, num_blocks=28, block_size=4, max_slots=3, prefill_chunk=8,
-            spec_k=3, draft_model=draft, draft_params=dparams,
-        )
-        specs = [(int(rs.randint(1, 18)), int(rs.randint(1, 8))) for _ in range(24)]
-        rids = [
-            engine.submit(_prompt(n, seed=300 + i), m) for i, (n, m) in enumerate(specs)
-        ]
-        steps = 0
-        while not engine.idle and steps < 5000:
-            engine.step()
-            steps += 1
-            for pool in (engine.pool, engine.draft_pool):
-                assert pool.num_free + pool.num_live == pool.num_blocks
-        out = engine.results()
-        assert sorted(out) == sorted(rids), "an admitted request starved"
-        for rid, (_, m) in zip(rids, specs):
-            assert len(out[rid]) == m
-        assert engine.pool.num_free == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-        admits = [engine.ledger.records[r]["admitted"] for r in rids]
-        assert admits == sorted(admits)  # strict FIFO held
-
-    def test_spec_signature_budget_and_warm_replay(self, tiny_model):
-        """Churning spec traffic stays inside the enlarged (draft +
-        verify + two-model prefill) TraceGuard budget, and a warm engine
-        replaying the same shapes compiles NOTHING new."""
-        model, params = tiny_model
-        engine = _engine(model, params, max_slots=4, spec_k=3, guard="raise")
-        specs = [(5 + 3 * (i % 4), 3 + (i % 3)) for i in range(8)]
-        for wave, assert_warm in ((0, False), (1, True)):
-            before = engine.compiled_signatures()
-            for i, (n, m) in enumerate(specs):
-                engine.submit(_prompt(n, seed=100 * wave + i), m)
-            engine.run(max_steps=5000)
-            if assert_warm:
-                assert engine.compiled_signatures() == before
-        assert engine.compiled_signatures() <= engine.max_signatures
-
-    def test_spec_eos_truncates_inside_a_round(self, tiny_model):
-        """A row whose eos lands mid-round must stop at the eos token
-        exactly (device-side in-round truncation + host finish)."""
-        model, params = tiny_model
-        prompt = _prompt(9, seed=3)
-        ref = np.asarray(generate(model, params, jnp.asarray(prompt)[None], 8))[0]
-        eos = int(ref[2])
-        assert eos not in ref[:2]
-        engine = _engine(model, params, spec_k=3, eos_id=eos)
-        rid = engine.submit(prompt, 8)
-        out = engine.run(max_steps=2000)[rid]
-        np.testing.assert_array_equal(out, ref[:3])
-        assert engine.pool.num_free == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-
-    def test_reservation_accounts_spec_lookahead(self, tiny_model):
-        """Admission reserves prompt + max_new + k worst case; the
-        max_seq_len check carries the k+1 speculative slack; and
-        needed_blocks covers this round's k-token overshoot."""
-        from dmlcloud_tpu.serve.scheduler import _Sequence
-
-        model, params = tiny_model
-        engine = _engine(model, params, spec_k=3)  # block_size 4
-        rid = engine.submit(_prompt(4), 4)
-        seq = engine.scheduler.waiting[0]
-        assert engine.scheduler.reservation(seq) == -(-(4 + 4 + 3) // 4)  # 11 slots
-        # plain engine reserves less for the same request
-        plain = _engine(model, params)
-        plain.submit(_prompt(4), 4)
-        assert plain.scheduler.reservation(plain.scheduler.waiting[0]) == 2
-        # max_seq_len check is spec-aware: 31 + 30 fits plain (61 <= 64)
-        # but not with the +k+1 speculative slack (65 > 64)
-        with pytest.raises(ValueError, match="spec_k"):
-            engine.submit(_prompt(31), 30)
-        # needed_blocks: lookahead widens the table the round gathers
-        s = _Sequence(req=seq.req, arrival=0.0)
-        s.fill = 7
-        assert s.needed_blocks(4) == 2  # plain: slots 0..7
-        assert s.needed_blocks(4, lookahead=3) == 3  # spec: writes to 10
-
-    def test_spec_rejects_bad_args(self, tiny_model):
-        model, params = tiny_model
-        with pytest.raises(ValueError, match="together"):
-            _engine(model, params, spec_k=2, draft_model=model)
-        with pytest.raises(ValueError, match="spec_k"):
-            _engine(model, params, draft_model=model, draft_params=params)
-
-    def test_ledger_accept_counters_are_exact(self, tiny_model):
-        """Self-draft greedy accepts everything: drafted == rounds * k,
-        accepted == drafted, per-request accept_rate == 1.0 — the exact
-        on-device counters, fetched once per round with the tokens."""
-        model, params = tiny_model
-        engine = _engine(model, params, spec_k=3)
-        rid = engine.submit(_prompt(6, seed=2), 9)
-        engine.run(max_steps=2000)
-        rec = engine.ledger.records[rid]
-        assert rec["drafted"] > 0 and rec["drafted"] % 3 == 0
-        assert rec["accepted"] == rec["drafted"]
-        assert engine.ledger.accept_rate(rid) == 1.0
-        s = engine.ledger.summary()
-        assert s["mean_request_accept_rate"] == 1.0
-        assert s["accepted_tokens"] == s["drafted_tokens"]
-
-    @pytest.mark.slow  # span-kind drill over a full spec run; journal emission locked by the cheap telemetry test
-    def test_spec_journal_spans(self, tiny_model, tmp_path):
-        from dmlcloud_tpu.telemetry import journal as journal_mod
-
-        model, params = tiny_model
-        j = journal_mod.SpanJournal(tmp_path, rank=0)
-        journal_mod.activate(j)
-        try:
-            engine = _engine(model, params, spec_k=2)
-            engine.submit(_prompt(12, seed=1), 5)
-            engine.run(max_steps=2000)
-        finally:
-            journal_mod.deactivate()
-        spans = j.tail(512)
-        kinds = {rec["kind"] for rec in spans}
-        assert {"queue_wait", "prefill", "draft", "verify"} <= kinds
-        assert "decode_batch" not in kinds  # spec rounds replace plain decode
-        # every verify round pairs with a draft call; prefill drafts are extra
-        n_verify = sum(1 for r in spans if r["kind"] == "verify")
-        n_draft = sum(1 for r in spans if r["kind"] == "draft")
-        assert n_verify >= 1 and n_draft >= n_verify
 
 
 # ---------------------------------------------------------------------------
@@ -590,7 +396,6 @@ class TestPerRequestSampling:
         assert seq.top_k == 7  # engine default inherited
         assert seq.eos_id == -1
 
-    @pytest.mark.slow  # mixed-sampling drill; greedy-row bit-identity and medusa mixed-sampling locks stay tier-1
     def test_spec_mixed_sampling_batch(self, tiny_model):
         """Per-row params flow through the spec verify step too: a greedy
         and a sampled row share a spec batch; the greedy row stays
@@ -642,7 +447,6 @@ class TestAdapterSet:
         out = engine.run()
         return [out[r] for r in rids]
 
-    @pytest.mark.slow  # heavyweight two-tenant drill; adapter math locked by the lora-merge/null-adapter units
     def test_two_tenants_in_one_batch_match_each_alone(self, tiny_model, adapters):
         _, _, aset = adapters
         both = self._run(tiny_model, aset, ["a", "b", None])
@@ -654,7 +458,7 @@ class TestAdapterSet:
         np.testing.assert_array_equal(both[2], alone_base)
         # and the tenants genuinely decode differently (non-vacuous)
         assert not np.array_equal(alone_a, alone_b)
-        assert not np.array_equal(alone_a, alone_base)
+        assert not np.array_equal(alone_b, alone_base)
 
     def test_null_adapter_is_exactly_the_base_model(self, tiny_model, adapters):
         model, params = tiny_model
@@ -874,719 +678,6 @@ class TestServeTelemetry:
 
 
 # ---------------------------------------------------------------------------
-# refcounted pool: the free + unique-live == capacity invariant under sharing
-# ---------------------------------------------------------------------------
-
-
-class TestRefcountedPool:
-    def _pool(self, n=8):
-        return KVBlockPool(2, 2, 8, num_blocks=n, block_size=4, dtype=jnp.float32)
-
-    def test_retain_release_roundtrip(self):
-        pool = self._pool()
-        [b] = pool.alloc(1)
-        assert pool.refcount(b) == 1 and not pool.is_shared(b)
-        pool.retain([b])
-        assert pool.refcount(b) == 2 and pool.is_shared(b)
-        pool.release([b])  # one holder left: still live
-        assert pool.refcount(b) == 1 and pool.num_live == 1
-        pool.release([b])  # last holder: back on the free list
-        assert pool.refcount(b) == 0 and pool.num_free == 8 and pool.num_live == 0
-
-    def test_release_below_zero_raises(self):
-        pool = self._pool()
-        [b] = pool.alloc(1)
-        pool.release([b])
-        with pytest.raises(ValueError, match="not live"):
-            pool.release([b])  # refcount already hit zero
-
-    def test_double_release_in_one_call_raises_and_releases_nothing(self):
-        pool = self._pool()
-        [b] = pool.alloc(1)
-        with pytest.raises(ValueError, match="not live"):
-            pool.release([b, b])  # one holder, two releases: below zero
-        # validated atomically up front: NOTHING was released
-        assert pool.refcount(b) == 1 and pool.num_live == 1
-        assert pool.num_free + pool.num_live == 8
-        # with two holders the same call is legal and drains both
-        pool.retain([b])
-        pool.release([b, b])
-        assert pool.num_free == 8 and pool.num_live == 0
-
-    def test_retain_free_block_raises(self):
-        pool = self._pool()
-        with pytest.raises(ValueError, match="retain"):
-            pool.retain([3])  # never allocated: no content to share
-
-    def test_shared_block_counts_once_in_live(self):
-        pool = self._pool()
-        blocks = pool.alloc(3)
-        pool.retain(blocks)  # a second table maps all three
-        pool.retain([blocks[0]])  # and the radix tree pins one
-        assert pool.num_live == 3  # unique blocks, not references
-        assert pool.num_free + pool.num_live == 8
-        pool.release(blocks)
-        pool.release(blocks)
-        assert pool.num_live == 1  # the tree still pins blocks[0]
-        pool.release([blocks[0]])
-        assert pool.num_free == 8 and pool.num_live == 0
-
-    def test_random_1k_ops_refcounted_invariant(self):
-        """The satellite property test: 1k random admit/share/fork/finish
-        operations over refcounted blocks. At every step ``free + (unique
-        live) == capacity``, refcounts equal the number of holders, and a
-        full drain restores the pristine pool."""
-        rs = np.random.RandomState(23)
-        pool = self._pool(16)
-        holders: list[list[int]] = []  # each entry: one holder's block list
-        for _ in range(1000):
-            ops = ["admit", "finish", "share", "fork"]
-            op = ops[rs.randint(4)]
-            if op == "admit":
-                want = int(rs.randint(1, 4))
-                if want > pool.num_free:
-                    with pytest.raises(PoolExhausted):
-                        pool.alloc(want)
-                else:
-                    holders.append(pool.alloc(want))
-            elif op == "finish" and holders:
-                pool.release(holders.pop(rs.randint(len(holders))))
-            elif op == "share" and holders:
-                src = holders[rs.randint(len(holders))]
-                take = [b for b in src if rs.rand() < 0.5] or src[:1]
-                pool.retain(take)  # a prefix hit maps them into a new table
-                holders.append(list(take))
-            elif op == "fork" and holders:
-                h = holders[rs.randint(len(holders))]
-                i = rs.randint(len(h))
-                if pool.is_shared(h[i]) and pool.num_free >= 1:
-                    [new] = pool.alloc(1)  # COW: private copy...
-                    pool.release([h[i]])  # ...drop the shared original
-                    h[i] = new
-            # the invariant, after EVERY operation
-            refs: dict[int, int] = {}
-            for h in holders:
-                for b in h:
-                    refs[b] = refs.get(b, 0) + 1
-            assert pool.num_free + pool.num_live == 16
-            assert pool.num_live == len(refs)
-            for b, n in refs.items():
-                assert pool.refcount(b) == n, f"block {b}: {pool.refcount(b)} != {n}"
-        while holders:
-            pool.release(holders.pop())
-        assert pool.num_free == 16 and pool.num_live == 0
-
-
-# ---------------------------------------------------------------------------
-# prefix cache: radix tree, content addressing, LRU-over-refcount eviction
-# ---------------------------------------------------------------------------
-
-
-class TestPrefixCacheUnit:
-    def _setup(self, n=16):
-        pool = KVBlockPool(2, 2, 8, num_blocks=n, block_size=4, dtype=jnp.float32)
-        return pool, PrefixCache(pool)
-
-    def _toks(self, n, seed=0):
-        return np.random.RandomState(seed).randint(0, 61, size=n).astype(np.int32)
-
-    def test_insert_match_lock_roundtrip(self):
-        pool, cache = self._setup()
-        toks = self._toks(10)  # 2 full blocks + 2 trailing tokens
-        blocks = pool.alloc(3)
-        assert cache.insert(toks, blocks) == 2  # only FULL blocks cached
-        assert pool.refcount(blocks[0]) == 2 and pool.refcount(blocks[2]) == 1
-        m = cache.match(toks)
-        assert m.tokens == 8 and m.blocks == blocks[:2]
-        locked, n = cache.lock(m)
-        assert (locked, n) == (blocks[:2], 8)
-        assert pool.refcount(blocks[0]) == 3  # tree + owner + locker
-        pool.release(locked)
-
-    def test_match_is_block_granular_and_prefix_exact(self):
-        pool, cache = self._setup()
-        toks = self._toks(8, seed=1)
-        cache.insert(toks, pool.alloc(2))
-        # same first block, different second block: partial chain match
-        other = np.concatenate([toks[:4], self._toks(4, seed=2)])
-        assert cache.match(other).tokens == 4
-        # divergence INSIDE a block: that block cannot match
-        inner = toks.copy()
-        inner[6] = (inner[6] + 1) % 61
-        assert cache.match(inner).tokens == 4
-        # shorter than a block: no match ever
-        assert cache.match(toks[:3]).tokens == 0
-
-    def test_content_address_chains_from_parent(self):
-        """The same 4 tokens behind two different prefixes are two
-        distinct nodes (chained hash): matching never teleports a block
-        across prefixes."""
-        pool, cache = self._setup()
-        a, b = self._toks(4, seed=3), self._toks(4, seed=4)
-        tail = self._toks(4, seed=5)
-        cache.insert(np.concatenate([a, tail]), pool.alloc(2))
-        cache.insert(np.concatenate([b, tail]), pool.alloc(2))
-        ma = cache.match(np.concatenate([a, tail]))
-        mb = cache.match(np.concatenate([b, tail]))
-        assert ma.tokens == mb.tokens == 8
-        assert ma.nodes[1].block != mb.nodes[1].block
-        assert ma.nodes[1].key != mb.nodes[1].key
-
-    def test_eviction_is_leaf_first_lru_and_respects_pins(self):
-        pool, cache = self._setup(8)
-        cold = self._toks(8, seed=6)
-        hot = self._toks(8, seed=7)
-        for toks in (cold, hot):  # insert, then the "request" finishes:
-            blocks = pool.alloc(2)  # only the tree's reference remains
-            cache.insert(toks, blocks)
-            pool.release(blocks)
-        locked, _ = cache.lock(cache.match(hot))  # pin the hot chain
-        pool.alloc(4)  # pool now full: 4 cached + 4 private
-        # ask for 2 free: must evict the COLD chain (leaf first), never
-        # the pinned hot one
-        assert cache.evict(2) >= 2
-        assert cache.match(cold).tokens == 0  # gone
-        assert cache.match(hot).tokens == 8  # pinned chain intact
-        # with everything else pinned, eviction honestly gives up
-        assert cache.evict(8) < 8
-
-    def test_lock_survives_eviction_race(self):
-        """The adversarial match->admit window: a match taken, then the
-        matched chain evicted, then lock — lock must re-validate and
-        return only the still-cached prefix, never a recycled page."""
-        pool, cache = self._setup(8)
-        toks = self._toks(12, seed=8)
-        owned = pool.alloc(3)
-        cache.insert(toks, owned)
-        pool.release(owned)  # the inserting request finished: tree-only refs
-        m = cache.match(toks)
-        assert m.tokens == 12
-        # eviction invalidates the whole chain between match and lock
-        pool.alloc(pool.num_free)  # drain the free list
-        cache.evict(3)
-        locked, n = cache.lock(m)
-        assert locked == [] and n == 0  # truncated at the first dead node
-        # partial invalidation: re-insert, evict only the tail leaf
-        pool2, cache2 = self._setup(8)
-        blocks = pool2.alloc(3)
-        cache2.insert(toks, blocks)
-        pool2.release(blocks)
-        m2 = cache2.match(toks)
-        cache2._drop(m2.nodes[-1])  # the LRU leaf goes
-        locked2, n2 = cache2.lock(m2)
-        assert locked2 == blocks[:2] and n2 == 8
-        pool2.release(locked2)
-
-    def test_adapter_ids_namespace_the_tree(self):
-        """LoRA deltas change the K/V projections: identical tokens under
-        different adapters must NEVER share blocks."""
-        pool, cache = self._setup()
-        toks = self._toks(8, seed=9)
-        cache.insert(toks, pool.alloc(2), adapter=0)
-        assert cache.match(toks, adapter=0).tokens == 8
-        assert cache.match(toks, adapter=1).tokens == 0
-
-
-# ---------------------------------------------------------------------------
-# prefix sharing through the engine: warm templates, COW, admission
-# ---------------------------------------------------------------------------
-
-
-def _template_prompt(tmpl, n_suffix, seed):
-    return np.concatenate(
-        [tmpl, np.random.RandomState(seed).randint(0, 61, n_suffix).astype(np.int32)]
-    )
-
-
-class TestPrefixEngine:
-    def test_warm_template_identity_and_prefill_skip(self, tiny_model):
-        """Requests sharing a 16-token template: outputs token-identical
-        to serial generate AND to the uncached engine; the warm requests'
-        ledger records show the skipped prefill."""
-        model, params = tiny_model
-        tmpl = _prompt(16, seed=40)
-        specs = [(3, 41), (5, 42), (2, 43)]
-        prompts = [_template_prompt(tmpl, n, s) for n, s in specs]
-        engine = _engine(model, params, max_slots=1, prefix_cache=True)
-        rids = [engine.submit(p, 5) for p in prompts]
-        engine.run(max_steps=4000)
-        plain = _engine(model, params, max_slots=1)
-        prids = [plain.submit(p, 5) for p in prompts]
-        plain.run(max_steps=4000)
-        for rid, prid, p in zip(rids, prids, prompts):
-            ref = np.asarray(generate(model, params, jnp.asarray(p)[None], 5))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-            np.testing.assert_array_equal(plain.output(prid), ref)
-        recs = engine.ledger.records
-        assert recs[rids[0]]["cached_tokens"] == 0  # cold: populated the tree
-        for rid in rids[1:]:  # max_slots=1: strictly after the cold prefill
-            assert recs[rid]["cached_tokens"] == 16
-            assert recs[rid]["saved_tokens"] == 16
-        s = engine.ledger.summary()
-        assert s["prefix_hit_rate"] == pytest.approx(2 / 3, abs=1e-3)
-        assert s["prefill_tokens_saved"] == 32
-        assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-
-    def test_exact_duplicate_prompt_takes_the_cow_fork(self, tiny_model):
-        """A full-block prompt re-requested exactly: every block matches,
-        prefill rolls back ONE token for its logits, and that token's
-        write COW-forks the final shared block — output still
-        token-identical, pools still clean, and the fork replays the one
-        compiled copy signature."""
-        model, params = tiny_model
-        prompt = _prompt(16, seed=44)  # 4 full blocks @ block_size 4
-        engine = _engine(model, params, max_slots=1, prefix_cache=True)
-        r1 = engine.submit(prompt, 5)
-        engine.run(max_steps=2000)
-        r2 = engine.submit(prompt, 5)
-        engine.run(max_steps=2000)
-        ref = np.asarray(generate(model, params, jnp.asarray(prompt)[None], 5))[0]
-        np.testing.assert_array_equal(engine.output(r1), ref)
-        np.testing.assert_array_equal(engine.output(r2), ref)
-        rec = engine.ledger.records[r2]
-        assert rec["cached_tokens"] == 16 and rec["saved_tokens"] == 15
-        assert engine._copy_fn.cache_size() == 1  # the fork compiled once
-        assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-        # a third exact duplicate forks again but compiles NOTHING new
-        before = engine.compiled_signatures()
-        r3 = engine.submit(prompt, 5)
-        engine.run(max_steps=2000)
-        np.testing.assert_array_equal(engine.output(r3), ref)
-        assert engine.compiled_signatures() == before
-
-    @pytest.mark.slow  # eviction-pressure drill; eviction-race lock lives in the prefix-cache unit tests
-    def test_identity_under_eviction_pressure(self, tiny_model):
-        """A pool too small to cache every prompt: LRU leaves evict to
-        admit new requests, and every output stays token-identical."""
-        model, params = tiny_model
-        rs = np.random.RandomState(45)
-        engine = ServeEngine(
-            model, params, num_blocks=16, block_size=4, max_slots=2,
-            prefill_chunk=8, prefix_cache=True,
-        )
-        prompts = [_prompt(int(rs.randint(4, 20)), seed=500 + i) for i in range(12)]
-        rids = [engine.submit(p, 4) for p in prompts]
-        engine.run(max_steps=5000)
-        for rid, p in zip(rids, prompts):
-            ref = np.asarray(generate(model, params, jnp.asarray(p)[None], 4))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-        assert engine.prefix.stats()["evictions"] > 0  # pressure was real
-        assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-
-    @pytest.mark.slow  # admission property drill under sharing
-    def test_admission_property_under_sharing(self, tiny_model):
-        """The satellite property test: random 80%-shared-template load
-        through a TIGHT pool with shared blocks discounted from
-        reservations — strict FIFO holds, nobody starves, and after EVERY
-        engine step ``free + unique live == capacity``."""
-        model, params = tiny_model
-        rs = np.random.RandomState(46)
-        templates = [_prompt(12, seed=600 + t) for t in range(3)]
-        engine = ServeEngine(
-            model, params, num_blocks=20, block_size=4, max_slots=3,
-            prefill_chunk=8, prefix_cache=True,
-        )
-        prompts = []
-        for i in range(24):
-            if i % 5 != 4:  # 80% template-shaped
-                tmpl = templates[int(rs.randint(len(templates)))]
-                prompts.append(_template_prompt(tmpl, int(rs.randint(1, 5)), 700 + i))
-            else:
-                prompts.append(_prompt(int(rs.randint(2, 14)), seed=700 + i))
-        rids = [engine.submit(p, int(rs.randint(1, 6))) for p in prompts]
-        steps = 0
-        while not engine.idle and steps < 5000:
-            engine.step()
-            steps += 1
-            assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-        out = engine.results()
-        assert sorted(out) == sorted(rids), "an admitted request starved"
-        for rid, p in zip(rids, prompts):
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(p)[None], len(out[rid]))
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        admits = [engine.ledger.records[r]["admitted"] for r in rids]
-        assert admits == sorted(admits)  # strict FIFO held
-        assert engine.ledger.summary()["prefix_hit_rate"] > 0.3  # sharing was real
-
-    def test_warm_engine_with_prefix_never_recompiles(self, tiny_model):
-        model, params = tiny_model
-        engine = _engine(model, params, max_slots=4, prefix_cache=True, guard="raise")
-        tmpl = _prompt(12, seed=47)
-        specs = [(2 + (i % 3), 3 + (i % 3)) for i in range(8)]
-        # wave 0 is cold (populates the tree), wave 1 is the FIRST warm
-        # pass — cache hits change batch dynamics, so it may legitimately
-        # touch bucket pairs the cold wave never formed; wave 2 replays
-        # warm-steady-state dynamics and must compile NOTHING
-        for wave, assert_warm in ((0, False), (1, False), (2, True)):
-            before = engine.compiled_signatures()
-            for i, (n, m) in enumerate(specs):
-                engine.submit(_template_prompt(tmpl, n, 800 + 100 * wave + i), m)
-            engine.run(max_steps=5000)
-            if assert_warm:
-                assert engine.compiled_signatures() == before
-        assert engine.compiled_signatures() <= engine.max_signatures
-
-    @pytest.mark.slow  # engine-level tenant-isolation drill; the prefix-cache unit tests lock adapter namespacing
-    def test_prefix_never_crosses_adapter_tenants(self, tiny_model):
-        """Two tenants sending the SAME prompt must not share K/V: the
-        adapter id namespaces the radix tree, so each tenant's output
-        stays identical to that tenant served alone."""
-        model, params = tiny_model
-        a = _randomized_adapter(params, 1, 10)
-        aset = AdapterSet({"a": a}, alpha=4.0, base=params)
-        prompt = _prompt(16, seed=48)
-
-        def run(specs):
-            eng = _engine(
-                model, params, max_slots=1, adapters=aset, prefix_cache=True
-            )
-            rids = [eng.submit(prompt, 6, adapter=s) for s in specs]
-            eng.run(max_steps=4000)
-            return [eng.output(r) for r in rids]
-
-        mixed = run(["a", None, "a", None])  # warm hits inside each tenant
-        alone_a = run(["a"])[0]
-        alone_base = run([None])[0]
-        np.testing.assert_array_equal(mixed[0], alone_a)
-        np.testing.assert_array_equal(mixed[2], alone_a)
-        np.testing.assert_array_equal(mixed[1], alone_base)
-        np.testing.assert_array_equal(mixed[3], alone_base)
-        assert not np.array_equal(alone_a, alone_base)  # non-vacuous
-
-    def test_multi_turn_blocks_published_at_finish(self, tiny_model):
-        """A finished request's decoded full blocks enter the tree: a
-        follow-up whose prompt extends (prompt + output) hits past the
-        original prompt — the multi-turn shape."""
-        model, params = tiny_model
-        prompt = _prompt(8, seed=49)
-        engine = _engine(model, params, max_slots=1, prefix_cache=True)
-        r1 = engine.submit(prompt, 8)
-        engine.run(max_steps=2000)
-        out1 = engine.output(r1)
-        turn2 = np.concatenate([prompt, out1, _prompt(3, seed=50)])
-        r2 = engine.submit(turn2, 4)
-        engine.run(max_steps=2000)
-        ref = np.asarray(generate(model, params, jnp.asarray(turn2)[None], 4))[0]
-        np.testing.assert_array_equal(engine.output(r2), ref)
-        # blocks past the first prompt were served from cache: the hit
-        # covers prompt+output full blocks ((8 + 8 - 1) // 4 * 4 = 12)
-        assert engine.ledger.records[r2]["cached_tokens"] == 12
-
-
-# ---------------------------------------------------------------------------
-# composition: speculative decoding x prefix cache, speculative x LoRA
-# ---------------------------------------------------------------------------
-
-
-class TestSpecPrefixCompose:
-    def test_spec_prefix_identity_with_independent_draft(self, tiny_model, tiny_draft):
-        """Spec engine + prefix cache: the draft pool has no radix tree —
-        draft prefill skips via the TARGET's match length, leaving the
-        skipped draft pages unwritten (zeros). Proposals degrade, accept
-        rate pays, but the verifier keeps greedy output token-identical
-        to serial generate for cold AND warm requests."""
-        model, params = tiny_model
-        draft, dparams = tiny_draft
-        tmpl = _prompt(16, seed=51)
-        prompts = [_template_prompt(tmpl, n, 900 + i) for i, n in enumerate((3, 5, 2))]
-        engine = _engine(
-            model, params, max_slots=1, spec_k=3,
-            draft_model=draft, draft_params=dparams, prefix_cache=True,
-        )
-        rids = [engine.submit(p, 5) for p in prompts]
-        engine.run(max_steps=4000)
-        for rid, p in zip(rids, prompts):
-            ref = np.asarray(generate(model, params, jnp.asarray(p)[None], 5))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-        # the warm requests really skipped: matched the template's blocks
-        assert engine.ledger.records[rids[1]]["cached_tokens"] == 16
-        assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-
-    @pytest.mark.slow  # warm-replay drill; spec x prefix identity kept tier-1 via the independent-draft test
-    def test_spec_prefix_self_draft_warm_replay(self, tiny_model):
-        """Self-draft + prefix: warm template requests stay
-        token-identical, and the draft pool (no tree) never leaks."""
-        model, params = tiny_model
-        tmpl = _prompt(12, seed=52)
-        engine = _engine(model, params, max_slots=2, spec_k=3, prefix_cache=True)
-        prompts = [_template_prompt(tmpl, n, 950 + i) for i, n in enumerate((2, 4, 3, 5))]
-        rids = [engine.submit(p, 6) for p in prompts]
-        engine.run(max_steps=4000)
-        for rid, p in zip(rids, prompts):
-            ref = np.asarray(generate(model, params, jnp.asarray(p)[None], 6))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-        assert engine.pool.num_free + engine.pool.num_live == engine.pool.num_blocks
-
-
-class TestSpecLora:
-    """Speculative decoding x multi-tenant LoRA (the ROADMAP item 5
-    leftover): the base-model draft proposes WITHOUT the tenant's delta;
-    the verify pass scores WITH it — so output must be token-identical to
-    the tenant's own (merged) model, at whatever accept rate the
-    base-draft agreement yields."""
-
-    def test_spec_tenant_identical_to_merged_model(self, tiny_model):
-        model, params = tiny_model
-        ad = _randomized_adapter(params, 1, 10)
-        aset = AdapterSet({"a": ad}, alpha=4.0, base=params)
-        engine = _engine(model, params, spec_k=3, adapters=aset)
-        prompt = _prompt(9, seed=53)
-        ra = engine.submit(prompt, 6, adapter="a")
-        rb = engine.submit(prompt, 6)
-        engine.run(max_steps=4000)
-        merged = lora_merge(params, ad, alpha=4.0)
-        ref_a = np.asarray(generate(model, merged, jnp.asarray(prompt)[None], 6))[0]
-        ref_b = np.asarray(generate(model, params, jnp.asarray(prompt)[None], 6))[0]
-        np.testing.assert_array_equal(engine.output(ra), ref_a)
-        np.testing.assert_array_equal(engine.output(rb), ref_b)
-        assert not np.array_equal(ref_a, ref_b)  # the delta genuinely bites
-        # base row self-drafts against itself: accepts everything; the
-        # tenant row pays accept rate for the delta-blind draft
-        s = engine.ledger.summary()
-        assert s["drafted_tokens"] > 0
-        assert engine.ledger.accept_rate(rb) == 1.0
-
-    @pytest.mark.slow  # mixed-tenant spec x LoRA drill; the all-compose lock stays tier-1
-    def test_spec_lora_mixed_tenants_one_batch(self, tiny_model):
-        """Two adapted tenants + base in ONE spec batch decode exactly
-        what each decodes alone — no cross-row contamination through the
-        shared draft/verify rounds."""
-        model, params = tiny_model
-        a = _randomized_adapter(params, 1, 10)
-        b = _randomized_adapter(params, 2, 20)
-        aset = AdapterSet({"a": a, "b": b}, alpha=4.0, base=params)
-        prompt = _prompt(9, seed=54)
-
-        def run(specs):
-            eng = _engine(model, params, max_slots=4, spec_k=2, adapters=aset)
-            rids = [eng.submit(prompt, 5, adapter=s) for s in specs]
-            eng.run(max_steps=4000)
-            return [eng.output(r) for r in rids]
-
-        together = run(["a", "b", None])
-        np.testing.assert_array_equal(together[0], run(["a"])[0])
-        np.testing.assert_array_equal(together[1], run(["b"])[0])
-        np.testing.assert_array_equal(together[2], run([None])[0])
-
-    def test_spec_lora_prefix_all_compose(self, tiny_model):
-        """All three: spec x LoRA x prefix cache. Tenant-namespaced
-        sharing, delta-blind drafting, adapter-aware verification — and
-        the output is still exactly the merged model's."""
-        model, params = tiny_model
-        ad = _randomized_adapter(params, 1, 10)
-        aset = AdapterSet({"a": ad}, alpha=4.0, base=params)
-        engine = _engine(
-            model, params, max_slots=1, spec_k=2, adapters=aset, prefix_cache=True
-        )
-        tmpl = _prompt(12, seed=55)
-        p1 = _template_prompt(tmpl, 3, 56)
-        p2 = _template_prompt(tmpl, 4, 57)
-        r1 = engine.submit(p1, 5, adapter="a")
-        r2 = engine.submit(p2, 5, adapter="a")
-        r3 = engine.submit(p2, 5)  # base tenant: must not hit "a"'s blocks
-        engine.run(max_steps=4000)
-        merged = lora_merge(params, ad, alpha=4.0)
-        for rid, p in ((r1, p1), (r2, p2)):
-            ref = np.asarray(generate(model, merged, jnp.asarray(p)[None], 5))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-        ref3 = np.asarray(generate(model, params, jnp.asarray(p2)[None], 5))[0]
-        np.testing.assert_array_equal(engine.output(r3), ref3)
-        assert engine.ledger.records[r2]["cached_tokens"] == 12  # tenant-a warm hit
-        assert engine.ledger.records[r3]["cached_tokens"] == 0  # namespaced
-
-
-# ---------------------------------------------------------------------------
-# Medusa mode: draftless speculation off the target's own hidden state (PR 16)
-# ---------------------------------------------------------------------------
-
-
-class TestMedusaEngine:
-    """``medusa_k``: up to k tokens per round from lightweight extra decode
-    heads on the target's last hidden state — ONE model, ONE block pool,
-    ONE k-position forward per round (the next round's proposals ride the
-    current round's packed fetch). Same acceptance contract as spec mode
-    (greedy survivors token-identical to serial generate), none of the
-    draft model's memory."""
-
-    def test_medusa_k1_identity_degenerates_to_plain_decode(self, tiny_model):
-        """k=1 has no heads: every round is one 1-position forward through
-        the medusa signature — exactly plain decode (nothing drafted, so
-        the accept-rate observable is undefined), token-identical to
-        serial generate."""
-        model, params = tiny_model
-        specs = [(7, 6), (13, 4), (5, 9), (22, 5)]
-        engine = _engine(model, params, medusa_k=1)
-        assert engine.draft_pool is None  # the deleted second pool
-        rids = [engine.submit(_prompt(n, seed=i), m) for i, (n, m) in enumerate(specs)]
-        out = engine.run(max_steps=5000)
-        for rid, (n, m) in zip(rids, specs):
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(n, seed=rid))[None], m)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        s = engine.ledger.summary()
-        assert s["accept_rate"] is None
-        assert s["drafted_tokens"] == 0
-        assert engine.pool.num_free == engine.pool.num_blocks
-
-    def test_medusa_random_heads_stay_token_identical(self, tiny_model):
-        """Untrained random heads propose near-garbage — accept collapses
-        toward zero — yet greedy output must STILL be token-identical:
-        rejected proposals leave stale K/V that the fill-counter rewind
-        must fully hide (the spec-mode contract, same verifier)."""
-        model, params = tiny_model
-        # no lm_head warm start: w2 is small random noise, proposals from
-        # heads 1..k-1 are unrelated to the target's argmax
-        heads = init_medusa_heads(model.cfg, 4, jax.random.PRNGKey(7))
-        engine = _engine(model, params, max_slots=3, medusa_k=4, medusa_heads=heads)
-        specs = [(7, 6), (13, 4), (5, 9), (22, 5), (3, 8)]
-        rids = [engine.submit(_prompt(n, seed=i), m) for i, (n, m) in enumerate(specs)]
-        out = engine.run(max_steps=5000)
-        for rid, (n, m) in zip(rids, specs):
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(n, seed=rid))[None], m)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        s = engine.ledger.summary()
-        assert s["drafted_tokens"] > 0  # heads genuinely proposed
-        assert s["accept_rate"] < 0.5  # ... and the garbage mostly rejected
-
-    def test_medusa_warm_start_heads_accept_high_on_repetitive_chain(
-        self, tiny_model
-    ):
-        """The accept≈1 end of the contract: lm_head-warm-started heads
-        predict "the correction token repeats" — on a greedy chain that
-        HAS entered its repeating cycle, that is mostly right, so accept
-        climbs toward 1 while output stays token-identical (the identity
-        proof must not depend on accept being low)."""
-        model, params = tiny_model
-        # walk the chain INTO its fixed point first: this model's greedy
-        # continuation of _prompt(4) goes constant after ~18 tokens, so a
-        # prompt extended by that warmup decodes entirely inside the cycle
-        seed_prompt = _prompt(4, seed=0)
-        warm = np.asarray(
-            generate(model, params, jnp.asarray(seed_prompt)[None], 18)
-        )[0]
-        prompt = np.concatenate([seed_prompt, warm]).astype(np.int32)
-        engine = _engine(model, params, medusa_k=3, num_blocks=48)
-        rid = engine.submit(prompt, 36)
-        out = engine.run(max_steps=5000)
-        ref = np.asarray(generate(model, params, jnp.asarray(prompt)[None], 36))[0]
-        np.testing.assert_array_equal(out[rid], ref)
-        assert engine.ledger.summary()["accept_rate"] > 0.8
-
-    @pytest.mark.slow  # random-load property drill; medusa identity/budget/compose locks stay tier-1
-    def test_medusa_random_load_pool_invariants_per_step(self, tiny_model):
-        """The drill property: random Medusa load — after EVERY engine step
-        the single pool's ``stats()`` balance holds, ``leaked_blocks()`` is
-        zero, and there is never a draft pool. FIFO + starvation-freedom +
-        pristine drain, as in spec mode."""
-        model, params = tiny_model
-        rs = np.random.RandomState(13)
-        engine = ServeEngine(
-            model, params, num_blocks=28, block_size=4, max_slots=3,
-            prefill_chunk=8, medusa_k=3,
-        )
-        specs = [(int(rs.randint(1, 18)), int(rs.randint(1, 8))) for _ in range(24)]
-        rids = [
-            engine.submit(_prompt(n, seed=300 + i), m) for i, (n, m) in enumerate(specs)
-        ]
-        steps = 0
-        while not engine.idle and steps < 5000:
-            engine.step()
-            steps += 1
-            st = engine.pool.stats()
-            assert st["free"] + st["live"] == st["capacity"]
-            assert engine.draft_pool is None
-            if engine.idle:  # leak audit is defined at idle (in-flight != leak)
-                assert engine.leaked_blocks() == 0
-        assert engine.leaked_blocks() == 0
-        out = engine.results()
-        assert sorted(out) == sorted(rids), "an admitted request starved"
-        for rid, (_, m) in zip(rids, specs):
-            assert len(out[rid]) == m
-        assert engine.pool.num_free == engine.pool.num_blocks
-        admits = [engine.ledger.records[r]["admitted"] for r in rids]
-        assert admits == sorted(admits)  # strict FIFO held
-
-    def test_medusa_signature_budget_and_warm_replay(self, tiny_model):
-        """Churning Medusa traffic stays inside its TraceGuard budget —
-        which is SMALLER than spec mode's (no draft signatures, no second
-        prefill mirror) — and a warm engine replaying the same shapes
-        compiles NOTHING new."""
-        model, params = tiny_model
-        engine = _engine(model, params, max_slots=4, medusa_k=3, guard="raise")
-        spec_engine = _engine(model, params, max_slots=4, spec_k=3)
-        assert engine.max_signatures < spec_engine.max_signatures
-        specs = [(5 + 3 * (i % 4), 3 + (i % 3)) for i in range(8)]
-        for wave, assert_warm in ((0, False), (1, True)):
-            before = engine.compiled_signatures()
-            for i, (n, m) in enumerate(specs):
-                engine.submit(_prompt(n, seed=100 * wave + i), m)
-            engine.run(max_steps=5000)
-            if assert_warm:
-                assert engine.compiled_signatures() == before
-        assert engine.compiled_signatures() <= engine.max_signatures
-
-    def test_medusa_mixed_sampling_batch(self, tiny_model):
-        """Per-request sampling params ride the Medusa round too: a greedy
-        and a sampled row share a batch; the greedy row stays identical to
-        serial generate, the sampled row stays in-vocab."""
-        model, params = tiny_model
-        engine = _engine(model, params, medusa_k=3)
-        r_g = engine.submit(_prompt(8, seed=1), 6)
-        r_s = engine.submit(_prompt(8, seed=2), 6, temperature=1.1)
-        out = engine.run(max_steps=2000)
-        ref = np.asarray(generate(model, params, jnp.asarray(_prompt(8, seed=1))[None], 6))[0]
-        np.testing.assert_array_equal(out[r_g], ref)
-        assert ((out[r_s] >= 0) & (out[r_s] < model.cfg.vocab_size)).all()
-
-    def test_medusa_lora_prefix_all_compose(self, tiny_model):
-        """All three: Medusa x LoRA x prefix cache (the Medusa mirror of
-        ``TestSpecLora.test_spec_lora_prefix_all_compose``). The heads
-        propose off the ADAPTED hidden state, verification is adapter-
-        aware, sharing stays tenant-namespaced — and the output is still
-        exactly the merged model's."""
-        model, params = tiny_model
-        ad = _randomized_adapter(params, 1, 10)
-        aset = AdapterSet({"a": ad}, alpha=4.0, base=params)
-        engine = _engine(
-            model, params, max_slots=1, medusa_k=2, adapters=aset, prefix_cache=True
-        )
-        tmpl = _prompt(12, seed=55)
-        p1 = _template_prompt(tmpl, 3, 56)
-        p2 = _template_prompt(tmpl, 4, 57)
-        r1 = engine.submit(p1, 5, adapter="a")
-        r2 = engine.submit(p2, 5, adapter="a")
-        r3 = engine.submit(p2, 5)  # base tenant: must not hit "a"'s blocks
-        engine.run(max_steps=4000)
-        merged = lora_merge(params, ad, alpha=4.0)
-        for rid, p in ((r1, p1), (r2, p2)):
-            ref = np.asarray(generate(model, merged, jnp.asarray(p)[None], 5))[0]
-            np.testing.assert_array_equal(engine.output(rid), ref)
-        ref3 = np.asarray(generate(model, params, jnp.asarray(p2)[None], 5))[0]
-        np.testing.assert_array_equal(engine.output(r3), ref3)
-        assert engine.ledger.records[r2]["cached_tokens"] == 12  # tenant-a warm hit
-        assert engine.ledger.records[r3]["cached_tokens"] == 0  # namespaced
-        assert engine.draft_pool is None
-        assert engine.leaked_blocks() == 0
-
-    def test_medusa_rejects_bad_args(self, tiny_model):
-        model, params = tiny_model
-        with pytest.raises(ValueError, match="medusa_k"):
-            _engine(model, params, medusa_k=-1)
-        with pytest.raises(ValueError, match="mutually exclusive"):
-            _engine(model, params, spec_k=2, medusa_k=2)
-        with pytest.raises(ValueError, match="medusa_heads"):
-            heads = init_medusa_heads(model.cfg, 2, jax.random.PRNGKey(0))
-            _engine(model, params, medusa_heads=heads)
-
-
-# ---------------------------------------------------------------------------
 # request lifecycle: cancel / deadlines / terminal statuses (PR 13)
 # ---------------------------------------------------------------------------
 
@@ -1659,7 +750,6 @@ class TestRequestLifecycle:
         with pytest.raises(ValueError, match="deadline_s"):
             engine.submit(_prompt(4), 4, deadline_s=0.0)
 
-    @pytest.mark.slow  # random cancel/expiry property drill; lifecycle units cover each terminal path
     def test_random_cancel_and_expiry_property(self, tiny_model):
         """The lifecycle property test: random cancels (seeded monkey) and
         random deadlines injected over random load — every request ends
@@ -1816,7 +906,6 @@ class TestChaosDrill:
         for r, rr in survivors:
             np.testing.assert_array_equal(engine.output(r), ref_out[rr])
 
-    @pytest.mark.slow  # replays the seeded drill twice; the single-run contract lock stays tier-1
     def test_drill_is_replayable(self, tiny_model):
         """Same seed, same trace -> same injected events and same terminal
         census: the drill is a deterministic regression test, not a fuzzer."""
@@ -1866,87 +955,6 @@ class TestChaosDrill:
         ref = np.asarray(generate(model, params, jnp.asarray(_prompt(5, seed=801))[None], 6))[0]
         np.testing.assert_array_equal(engine.output(r_ok), ref)
         assert engine.pool.num_free == engine.pool.num_blocks
-
-
-# ---------------------------------------------------------------------------
-# chaos x speculative decoding (PR 13 satellite)
-# ---------------------------------------------------------------------------
-
-
-class TestSpecChaos:
-    def test_draft_fault_degrades_every_round_to_plain_decode(self, tiny_model, tiny_draft):
-        """The draft is an optimization, not a dependency: with EVERY
-        draft call failing, no round drafts a token (accept counters stay
-        exactly zero) yet every request completes token-identical to
-        serial generate."""
-        model, params = tiny_model
-        draft, dparams = tiny_draft
-        engine = _engine(
-            model, params, max_slots=2, spec_k=3, draft_model=draft, draft_params=dparams
-        )
-        monkey = ChaosMonkey(seed=53, p_fault=1.0, fault_points=("draft",))
-        monkey.attach(engine)
-        specs = [(5, 6), (9, 4), (4, 7)]
-        rids = [engine.submit(_prompt(n, seed=900 + i), m) for i, (n, m) in enumerate(specs)]
-        out = engine.run(max_steps=3000)
-        monkey.detach()
-        s = engine.ledger.summary()
-        assert s["drafted_tokens"] == 0 and s["accepted_tokens"] == 0
-        for i, (rid, (n, m)) in enumerate(zip(rids, specs)):
-            assert engine.status(rid) == "ok"
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(n, seed=900 + i))[None], m)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-        assert engine.pool.num_free == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
-
-    def test_draft_fault_once_then_speculation_resumes(self, tiny_model):
-        """After a single degraded round (self-draft engine), later rounds
-        draft again — the accept counters move and output identity holds."""
-        model, params = tiny_model
-        engine = _engine(model, params, max_slots=2, spec_k=2)
-        monkey = ChaosMonkey(seed=59, p_fault=1.0, fault_points=("draft",), max_faults=1)
-        monkey.attach(engine)
-        rids = [engine.submit(_prompt(5 + 2 * i, seed=950 + i), 6) for i in range(3)]
-        out = engine.run(max_steps=3000)
-        monkey.detach()
-        assert monkey.faults == 1
-        s = engine.ledger.summary()
-        assert s["drafted_tokens"] > 0  # speculation resumed after the fault
-        # self-draft: every drafted token the target still needs is accepted;
-        # only end-of-sequence truncation (draft k, need < k) trims the rate
-        assert s["accept_rate"] >= 0.8
-        for i, rid in enumerate(rids):
-            assert engine.status(rid) == "ok"
-            ref = np.asarray(
-                generate(model, params, jnp.asarray(_prompt(5 + 2 * i, seed=950 + i))[None], 6)
-            )[0]
-            np.testing.assert_array_equal(out[rid], ref)
-
-    @pytest.mark.slow  # verify-fault drill; draft-fault degrade/resume + step-fault isolation locks stay tier-1
-    def test_verify_fault_errors_only_its_batch(self, tiny_model):
-        """A verify failure is a REAL step failure: exactly the rows in
-        that round error; requests outside the batch finish ok and both
-        pools drain clean."""
-        model, params = tiny_model
-        engine = _engine(model, params, max_slots=2, spec_k=2)
-        monkey = ChaosMonkey(seed=61, p_fault=1.0, fault_points=("verify",), max_faults=1)
-        monkey.attach(engine)
-        rids = [engine.submit(_prompt(4, seed=970 + i), 5) for i in range(3)]
-        engine.run(max_steps=3000)
-        monkey.detach()
-        statuses = [engine.status(r) for r in rids]
-        assert statuses.count("error") >= 1  # the faulted round's rows
-        assert statuses.count("ok") == len(rids) - statuses.count("error")
-        for i, rid in enumerate(rids):
-            if statuses[i] == "ok":
-                ref = np.asarray(
-                    generate(model, params, jnp.asarray(_prompt(4, seed=970 + i))[None], 5)
-                )[0]
-                np.testing.assert_array_equal(engine.output(rid), ref)
-        assert engine.pool.num_free == engine.pool.num_blocks
-        assert engine.draft_pool.num_free == engine.draft_pool.num_blocks
 
 
 # ---------------------------------------------------------------------------
@@ -2113,7 +1121,6 @@ class TestLedgerRetention:
 
 
 class TestFailedAdmitChaos:
-    @pytest.mark.slow  # failed-admit x chaos property drill
     def test_failed_admits_interleaved_with_chaos(self, tiny_model):
         """Submissions that FAIL validation (oversized prompts) interleave
         with shed arrivals, injected faults and pool squats — failed

@@ -57,6 +57,7 @@ from dmlcloud_tpu.serve import (
 from dmlcloud_tpu.serve.prefix_cache import content_key, prefix_keys, root_key
 from dmlcloud_tpu.telemetry import journal as journal_mod
 from dmlcloud_tpu.telemetry.journal import SpanJournal
+from dmlcloud_tpu.telemetry.metrics_registry import parse_prometheus_text
 
 
 # ---------------------------------------------------------------------------
@@ -707,7 +708,6 @@ class _DrillChaos:
 
 
 class TestFailoverProperty:
-    @pytest.mark.slow  # random replica-chaos property drill; the seeded kill+drain integration lock stays tier-1
     def test_random_replica_chaos_under_tight_pool(self, tiny_model, tmp_path):
         model, params = tiny_model
         n_req = 10
@@ -796,7 +796,7 @@ class TestRouterIntegration:
             ref.submit(p, 6)
         ref_outs = ref.run()
 
-        engines = [_engine(model, params) for _ in range(3)]
+        engines = [_engine(model, params, metrics=True) for _ in range(3)]
         router = Router(
             engines, heartbeat_timeout_s=1e9, max_retries=2,
             backoff_base_s=0.0, run_dir=tmp_path,
@@ -815,6 +815,13 @@ class TestRouterIntegration:
             assert np.array_equal(router.output(rid), ref_outs[i])
         assert router.replicas["r1"].removed
         assert read_requeue_verdict(tmp_path)["serve"]["replica"] == "r1"
+        # and the pool's metrics are ONE valid Prometheus page: the router's
+        # own series plus every replica's registry under a replica label
+        fams = parse_prometheus_text(router.metrics_text())
+        assert [float(v) for _, _, v in fams["dml_router_kills_total"]["samples"]] == [1.0]
+        tagged = {l["replica"] for f in fams.values() for _, l, _ in f["samples"] if "replica" in l}
+        assert tagged == {"r0", "r1", "r2"}
+        assert "dml_serve_tokens_total" in fams
 
 
 # ---------------------------------------------------------------------------
