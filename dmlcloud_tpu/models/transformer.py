@@ -740,32 +740,96 @@ def chunked_lm_loss(
         )(carry)
     m, s, tl = carry
     losses = (m + jnp.log(s)) - tl  # logsumexp - target logit
-    return _packed_mean(losses, segment_ids)
+    return (losses * _target_weights(tokens.shape, segment_ids)[:, :-1]).sum()
 
 
 @jax.named_scope("loss_head")
 def lm_loss(
     logits: jnp.ndarray, tokens: jnp.ndarray, segment_ids: jnp.ndarray | None = None
 ) -> jnp.ndarray:
-    """Next-token cross entropy over shifted targets.
+    """Next-token cross entropy, read off the logits where they lie.
 
-    With ``segment_ids`` (packed rows), a position only contributes when its
-    target is in the SAME segment (no predicting across a packing boundary)
-    and the segment is not padding (id 0 marks pad tokens)."""
-    import optax
+    Nothing of the ``[B, T, vocab]`` logits is sliced or copied: the TARGETS
+    are shifted (position ``t`` is held to ``tokens[t + 1]``) and every one of
+    the ``T`` positions gets a weight (``_target_weights``): 0 in the last
+    column, and with ``segment_ids`` (packed rows) 0 wherever the target is
+    not in the SAME segment (no predicting across a packing boundary) or the
+    segment is padding (id 0). The weights sum to 1 over the positions that
+    count, so the loss, the weighted sum of ``logsumexp(row) - row[target]``,
+    is their mean.
 
-    targets = tokens[:, 1:]
-    logits = logits[:, :-1]
-    losses = optax.softmax_cross_entropy_with_integer_labels(logits, targets)
-    return _packed_mean(losses, segment_ids)
+    The backward is written by hand (``jax.custom_vjp``): ``(softmax -
+    onehot) * weight`` in one expression over all ``T`` rows, computed in
+    float32 and returned in the logits' dtype; on the TPU it is written once
+    in bf16, which is what the head's two backward products round it to
+    anyway. Forward-mode derivatives (``jax.jvp``, ``jax.jacfwd``) of this
+    loss are therefore not defined; nothing in the tree takes one."""
+    targets = _next_in_row(tokens)  # any id does in the last column: its weight is 0
+    return _weighted_cross_entropy(logits, targets, _target_weights(tokens.shape, segment_ids))
 
 
-def _packed_mean(losses: jnp.ndarray, segment_ids: jnp.ndarray | None) -> jnp.ndarray:
-    """Mean of per-position losses; with packed ``segment_ids``, a position
-    only counts when its target is in the SAME non-pad segment. Shared by
-    both loss paths so the packing convention cannot diverge."""
+def _next_in_row(ids: jnp.ndarray) -> jnp.ndarray:
+    """``ids[:, t + 1]`` at column ``t``; 0 in the last column."""
+    return jnp.pad(ids[:, 1:], ((0, 0), (0, 1)))
+
+
+def _target_weights(shape: tuple[int, int], segment_ids: jnp.ndarray | None) -> jnp.ndarray:
+    """``[B, T]`` float32 weights of the next-token losses, summing to 1 (to 0
+    where no position counts): the last column has no target; with packed
+    ``segment_ids``, a position only counts when its target is in the SAME
+    non-pad segment. Shared by both loss paths so the packing convention
+    cannot diverge."""
     if segment_ids is None:
-        return losses.mean()
-    w = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, 1:] != 0)
-    w = w.astype(losses.dtype)
-    return (losses * w).sum() / jnp.maximum(w.sum(), 1)
+        counts = jnp.broadcast_to(jnp.arange(shape[1]) < shape[1] - 1, shape)
+    else:
+        target_segment = _next_in_row(segment_ids)  # the pad id in the last column: it counts for nothing
+        counts = (target_segment == segment_ids) & (target_segment != 0)
+    w = counts.astype(jnp.float32)
+    return w / jnp.maximum(w.sum(), 1)
+
+
+def _row_stats(logits: jnp.ndarray, targets: jnp.ndarray):
+    """float32 logits and, as a mask over the vocabulary, where each row's
+    target lies: a masked sum reads the target's logit in the pass that sums
+    the exponentials, and over a vocabulary sharded on ``model`` it is a
+    partial sum and an all-reduce of ``[B, T]``, where a gather would cross
+    shards."""
+    x = logits.astype(jnp.float32)
+    return x, jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) == targets[..., None]
+
+
+@jax.custom_vjp
+def _weighted_cross_entropy(logits: jnp.ndarray, targets: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+    """``sum(weights * (logsumexp(logits) - logits[targets]))`` over ``[B, T]``
+    rows; differentiable in ``logits`` only."""
+    return _weighted_cross_entropy_fwd(logits, targets, weights)[0]
+
+
+def _weighted_cross_entropy_fwd(logits, targets, weights):
+    x, hit = _row_stats(logits, targets)
+    m = x.max(-1)
+    lse = m + jnp.log(jnp.exp(x - m[..., None]).sum(-1))
+    picked = jnp.where(hit, x, 0).sum(-1)
+    # kept: the logits the model returned anyway and three [B, T] arrays
+    return ((lse - picked) * weights).sum(), (logits, lse, targets, weights)
+
+
+def _weighted_cross_entropy_bwd(kept, g):
+    logits, lse, targets, weights = kept
+    x, hit = _row_stats(logits, targets)
+    grad = (jnp.exp(x - lse[..., None]) - hit) * (weights * g)[..., None]
+    # On the TPU the head's two backward products round this operand to bf16
+    # themselves (default precision), and computing it as they read the logits
+    # costs them more than one pass that writes it in bf16 and two reads of
+    # that (m7b-train-8k: 27.8 against 25.5 ms a step; PERF.md section 6,
+    # PR 33): held once in bf16 there, the same numbers. Elsewhere it stays
+    # float32 and XLA's to place.
+    grad = jax.lax.platform_dependent(
+        grad,
+        tpu=lambda a: jax.lax.optimization_barrier(a.astype(jnp.bfloat16)).astype(a.dtype),
+        default=lambda a: a,
+    )
+    return grad.astype(logits.dtype), None, None
+
+
+_weighted_cross_entropy.defvjp(_weighted_cross_entropy_fwd, _weighted_cross_entropy_bwd)

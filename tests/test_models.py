@@ -69,6 +69,52 @@ def test_decoder_lm_forward_and_loss():
     assert float(loss) == pytest.approx(np.log(SMALL.vocab_size), rel=0.2)
 
 
+def _plain_lm_loss(logits, tokens, segment_ids=None):
+    """The form ``lm_loss`` had before it read the logits where they lie:
+    optax's loss on the shifted copy ``logits[:, :-1]``, the packed mean over
+    ``T - 1`` columns. Kept here as the reference."""
+    losses = optax.softmax_cross_entropy_with_integer_labels(logits[:, :-1], tokens[:, 1:])
+    if segment_ids is None:
+        return losses.mean()
+    w = (segment_ids[:, 1:] == segment_ids[:, :-1]) & (segment_ids[:, 1:] != 0)
+    w = w.astype(losses.dtype)
+    return (losses * w).sum() / jnp.maximum(w.sum(), 1)
+
+
+# (batch, logits dtype, segment ids a row or None)
+LM_LOSS_CASES = {
+    "plain": (1, jnp.float32, None),
+    "batch_of_3": (3, jnp.float32, None),
+    # a pad segment in the middle of nothing, a boundary at the last column
+    # (its target opens segment 4: not counted), pads at the end of a row
+    "packed": (2, jnp.float32, [[1, 1, 1, 2, 2, 2, 2, 3, 3, 3, 3, 4], [1, 1, 2, 2, 2, 3, 3, 3, 3, 0, 0, 0]]),
+    "no_position_counts": (2, jnp.float32, [[0] * 12, [1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12]]),
+    "bf16_logits": (2, jnp.bfloat16, [[1, 1, 1, 1, 2, 2, 2, 2, 2, 0, 0, 0], [1] * 12]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LM_LOSS_CASES))
+def test_lm_loss_equals_the_plain_form_it_replaced(case):
+    """Value and gradient with respect to the logits, against optax's loss on
+    the shifted copy. The arithmetic is float32 whatever the logits' dtype, and
+    the gradient comes back in the logits' dtype."""
+    batch, dtype, segs = LM_LOSS_CASES[case]
+    rng = np.random.RandomState(3)
+    logits = jnp.asarray(rng.randn(batch, 12, 50) * 3, dtype)
+    tokens = jnp.asarray(rng.randint(0, 50, (batch, 12)), jnp.int32)
+    segs = None if segs is None else jnp.asarray(segs, jnp.int32)
+    got, got_grad = jax.jit(jax.value_and_grad(lm_loss))(logits, tokens, segs)
+    want, want_grad = jax.value_and_grad(_plain_lm_loss)(logits.astype(jnp.float32), tokens, segs)
+    assert got.dtype == jnp.float32 and got_grad.dtype == dtype
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6, atol=1e-7)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(np.asarray(got_grad), np.asarray(want_grad), rtol=1e-5, atol=1e-8)
+    else:  # the float32 gradient, rounded once on the way out: within one bf16 step
+        np.testing.assert_allclose(np.asarray(got_grad, np.float32), np.asarray(want_grad), rtol=2.0**-7, atol=1e-8)
+    if case == "no_position_counts":
+        assert float(got) == 0.0 and not np.asarray(got_grad).any()
+
+
 def test_decoder_causality():
     """Changing a future token must not affect earlier logits."""
     model = DecoderLM(SMALL)

@@ -211,3 +211,42 @@ def test_a_bounded_expert_layer_writes_no_array_of_all_pairs_rows_on_its_usual_p
         full, usual = (first, second) if first else (false, true)  # branch 0 is the false one: the full path
         assert not [c for c in reach(usual, set()) if wide.search(computations[c])]
         assert [c for c in reach(full, set()) if wide.search(computations[c])]  # the pattern does see such arrays
+
+
+@pytest.mark.parametrize("layout", ["one_chip", "vocab_over_model"])
+def test_the_loss_reads_the_logits_where_they_lie(v5e, layout):
+    """The head and ``lm_loss`` of ``m7b-train-8k`` (``[1, 8192, 4096]`` bf16 into a float32 ``[4096, 32000]``
+    kernel), gradient to both and the kernel's squared norm as the clip takes it: the compiled program holds
+    no shifted copy of the logits (nothing ``T - 1`` long but the targets' own integers), no scatter for the
+    target logit's gradient, and on one chip under 2 GB of temporaries (3.15 GB with the shifted copy). The
+    logits' gradient is written once, in bf16, for the two backward products to read (the TPU's branch of
+    ``platform_dependent``, taken for a described chip too)."""
+    import re
+
+    from dmlcloud_tpu.models.transformer import lm_loss
+
+    hidden, vocab = 4096, 32000
+    if layout == "one_chip":
+        rows = cols = SingleDeviceSharding(v5e.devices[0])
+    else:
+        mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("fsdp", "model"))
+        rows, cols = NamedSharding(mesh, P()), NamedSharding(mesh, P(None, "model"))
+    x = jax.ShapeDtypeStruct((B, T, hidden), jnp.bfloat16, sharding=rows)
+    kernel = jax.ShapeDtypeStruct((hidden, vocab), jnp.float32, sharding=cols)
+    tokens = jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=rows)
+
+    def head_and_loss(x, kernel, tokens):
+        loss = lambda x, kernel: lm_loss(jnp.einsum("btd,dv->btv", x.astype(jnp.float32), kernel), tokens)
+        value, (dx, dkernel) = jax.value_and_grad(loss, argnums=(0, 1))(x, kernel)
+        return value, dx, dkernel, jnp.sum(dkernel**2)
+
+    compiled = jax.jit(head_and_loss).lower(x, kernel, tokens).compile()
+    text = compiled.as_text()
+    shifted = {shape for shape in re.findall(r"\w+\[[\d,]+\]", text) if str(T - 1) in re.findall(r"\d+", shape)}
+    assert shifted <= {f"s32[{B},{T - 1}]"}, shifted
+    assert "scatter" not in text
+    if layout == "one_chip":
+        assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+        assert f"bf16[{B},{T},{vocab}]" in text
+    else:  # the target's logit is a partial sum a shard and an all-reduce of [B, T], no gather across shards
+        assert " all-gather(" not in text and " all-to-all(" not in text
