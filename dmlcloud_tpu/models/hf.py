@@ -18,6 +18,7 @@ Two conversions happen beyond plain transposes:
 
 from __future__ import annotations
 
+import math
 from typing import Any, Mapping
 
 import jax.numpy as jnp
@@ -48,9 +49,9 @@ def transformer_config_from_hf(hf_config: Any, **overrides) -> TransformerConfig
     carries over into the model's windowed attention paths) or an
     ``Lfm2MoeConfig`` (``model_type`` ``lfm2_moe``: per-layer operators from
     ``layer_types``, RMSNorm over the head dimension of q and k, sigmoid-scored
-    experts with a selection bias after ``num_dense_layers`` dense layers).
-    ``experts_held`` is no published key: pass it as an override for one
-    chip's share of the experts."""
+    experts with a selection bias after ``num_dense_layers`` dense layers) or a
+    ``laguna`` config (:func:`_laguna_keys`). ``experts_held`` is no published
+    key: pass it as an override for one chip's share of the experts."""
     get = lambda key, default=None: getattr(hf_config, key, default)
     rope = get("rope_parameters") or {}
     scaling = get("rope_scaling") or (rope if rope.get("rope_type", "default") != "default" else None)
@@ -87,8 +88,53 @@ def transformer_config_from_hf(hf_config: Any, **overrides) -> TransformerConfig
             norm_topk_prob=bool(get("norm_topk_prob", True)),
             routed_scaling_factor=float(get("routed_scaling_factor", 1.0)),
         )
+    if get("model_type") == "laguna":
+        base.update(_laguna_keys(hf_config))
     base.update(overrides)
     return TransformerConfig(**base)
+
+
+def _laguna_keys(hf_config: Any) -> dict:
+    """The ``laguna`` family (poolside): ``full_attention`` and
+    ``sliding_attention`` layers (``layer_types``; ``sliding_window`` is the
+    latter's) with their own query-head counts
+    (``num_attention_heads_per_layer``) and rotary tables (``rope_parameters``
+    keyed by layer kind), a per-head sigmoid gate on attention's output
+    (``gating``), and after the leading dense layers (``mlp_layer_types``)
+    experts scored by a softmax over all of them beside one shared expert.
+    The config names neither the scoring nor how the shared expert is added:
+    softmax with no selection bias and an ungated sum are the convention of
+    the configs that publish ``norm_topk_prob`` with
+    ``shared_expert_intermediate_size``; a config that publishes
+    ``scoring_func`` is followed. What this model cannot honour is refused."""
+    get = lambda key, default=None: getattr(hf_config, key, default)
+    n = int(hf_config.num_hidden_layers)
+    kinds = list(get("mlp_layer_types") or ["dense" if i in (get("mlp_only_layers") or ()) else "sparse" for i in range(n)])
+    dense = kinds.index("sparse") if "sparse" in kinds else n
+    if kinds != ["dense"] * dense + ["sparse"] * (n - dense) or int(get("decoder_sparse_step", 1)) != 1:
+        raise ValueError("dense MLPs anywhere but in the leading layers are not supported (num_dense_layers)")
+    if get("attention_bias", False) or get("moe_apply_router_weight_on_input", False) or get("moe_router_logit_softcapping"):
+        raise ValueError("attention_bias, moe_apply_router_weight_on_input and moe_router_logit_softcapping are not supported")
+    gating = get("gating")
+    if set(get("gating_types") or ["per_head"]) != {"per_head"} or gating not in (None, False, True, "per-head"):
+        raise ValueError(f"the only gate on attention's output this model has is per head, got {gating!r} / {get('gating_types')!r}")
+    return dict(
+        layer_types=tuple(hf_config.layer_types),
+        num_heads_per_layer=tuple(int(h) for h in get("num_attention_heads_per_layer") or ()) or None,
+        rope_parameters=tuple(
+            (kind, float(rope["rope_theta"]), _rope_scaling_from_hf(rope), float(rope.get("partial_rotary_factor", 1.0)))
+            for kind, rope in sorted((get("rope_parameters") or {}).items()) if isinstance(rope, dict)
+        ) or None,
+        gating="per-head" if gating else None,
+        num_experts=int(hf_config.num_experts),
+        num_dense_layers=dense,
+        num_experts_per_tok=int(hf_config.num_experts_per_tok),
+        moe_intermediate_size=int(hf_config.moe_intermediate_size),
+        shared_expert_intermediate_size=int(get("shared_expert_intermediate_size") or 0),
+        scoring_func=get("scoring_func", "softmax"),
+        norm_topk_prob=bool(get("norm_topk_prob", True)),
+        routed_scaling_factor=float(get("moe_routed_scaling_factor", 1.0)),
+    )
 
 
 def _rope_scaling_from_hf(rs: Any) -> tuple | None:
@@ -114,7 +160,19 @@ def _rope_scaling_from_hf(rs: Any) -> tuple | None:
             float(rs["high_freq_factor"]),
             int(rs["original_max_position_embeddings"]),
         )
-    raise ValueError(f"unsupported HF rope_scaling type {kind!r} (supported: linear, llama3)")
+    if kind == "yarn":
+        if not rs.get("original_max_position_embeddings"):
+            raise ValueError(f"yarn rope_scaling needs original_max_position_embeddings: {rs!r}")
+        factor = float(rs["factor"])
+        return (
+            "yarn",
+            factor,
+            float(rs.get("beta_fast") or 32.0),
+            float(rs.get("beta_slow") or 1.0),
+            int(rs["original_max_position_embeddings"]),
+            float(rs.get("attention_factor") or 0.1 * math.log(factor) + 1.0),  # YaRN's own default
+        )
+    raise ValueError(f"unsupported HF rope_scaling type {kind!r} (supported: linear, llama3, yarn)")
 
 
 def llama_params_from_hf(state_dict: Mapping[str, Any], cfg: TransformerConfig, dtype=jnp.float32):

@@ -1,14 +1,18 @@
 """Mixture-of-Experts, dropless: every (token, expert) pair the router picks
 is computed, whatever the routing.
 
-The layer routes over ALL ``num_experts`` (sigmoid scores, an optional
-selection bias and normalised top-k weights, as the DeepSeek-V3 / LFM2 family
-publishes them), and computes the part of the result that the
-experts it HOLDS give: ``experts_held = (a, b)`` keeps the three expert
+The layer routes over ALL ``num_experts`` (``scoring_func``: sigmoid scores,
+an optional selection bias and normalised top-k weights, as the DeepSeek-V3 /
+LFM2 family publishes them, or a softmax over all the experts, as the families
+that publish ``norm_topk_prob`` beside a shared expert do), and computes the
+part of the result that the experts it HOLDS give: ``experts_held = (a, b)`` keeps the three expert
 matrices of experts ``[a, b)`` only, as one chip of an expert-parallel
 deployment would, and returns ``sum over sel ∩ [a, b)`` of ``g_e * FFN_e(x)``.
 The shares of all holders of one layer add up to the whole layer's output;
-nothing stands in for the absent experts. ``None`` holds them all.
+nothing stands in for the absent experts. ``None`` holds them all. With
+``shared_expert_intermediate_size`` > 0 one more SwiGLU of that width runs on
+every token, ungated, and is added to the held experts' share: what every
+holder of a layer computes alike (phase ``moe_shared``).
 
 How: the ``N * k`` pairs are sorted by expert (pairs of experts not held sort
 to the end, so the live rows are a prefix of the sorted order), the tokens of
@@ -66,6 +70,8 @@ def moe_partition_rules() -> list[tuple[str, P]]:
         ("moe/(gate|up)_proj", P("expert", "fsdp", "model")),
         ("moe/down_proj", P("expert", "model", "fsdp")),
         ("moe/router/kernel", P()),
+        ("shared_expert/(gate|up)_proj/kernel", P("fsdp", "model")),
+        ("shared_expert/down_proj/kernel", P("model", "fsdp")),
     ]
 
 
@@ -76,8 +82,10 @@ class MoEConfig:
     hidden_dim: int = 512
     mlp_dim: int = 1408  # one expert's width
     use_expert_bias: bool = False
+    scoring_func: str = "sigmoid"  # "sigmoid" | "softmax" over all the experts
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    shared_expert_intermediate_size: int = 0  # 0 = no shared expert
     experts_held: tuple[int, int] | None = None  # [a, b) of the experts; None = all
     dtype: Any = jnp.bfloat16
     router_z_coef: float = 1e-3
@@ -87,6 +95,8 @@ class MoEConfig:
         a, b = self.held
         if not 0 <= a < b <= self.num_experts:
             raise ValueError(f"experts_held {self.experts_held!r} is no range of the {self.num_experts} experts")
+        if self.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func must be 'sigmoid' or 'softmax', got {self.scoring_func!r}")
 
     @property
     def held(self) -> tuple[int, int]:
@@ -96,13 +106,15 @@ class MoEConfig:
 def route(cfg: MoEConfig, logits, bias=None):
     """``(scores [N, E], chosen experts [N, k], their weights [N, k])`` from the
     router's float32 logits. ``bias`` [E] shifts the choice and nothing else."""
-    scores = jax.nn.sigmoid(logits)
+    softmax = cfg.scoring_func == "softmax"
+    scores = jax.nn.softmax(logits, axis=-1) if softmax else jax.nn.sigmoid(logits)
     choice = scores if bias is None else scores + jax.lax.stop_gradient(bias)
     _, chosen = jax.lax.top_k(choice, min(cfg.top_k, cfg.num_experts))
     # the chosen scores by a one-hot product: elementwise forward and backward, where a gather's transpose is a scatter
     gates = jnp.sum(jax.nn.one_hot(chosen, cfg.num_experts, dtype=scores.dtype) * scores[:, None, :], axis=-1)
     if cfg.norm_topk_prob:
-        gates = gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
+        # sigmoid scores can all lie near 0, and their family publishes the guard; a softmax's k largest sum to k / E at least
+        gates = gates / (jnp.sum(gates, -1, keepdims=True) + (0.0 if softmax else 1e-6))
     return scores, chosen, gates * cfg.routed_scaling_factor
 
 
@@ -244,9 +256,23 @@ def _bounded_bwd(k, bound, residuals, d_out):
 _bounded_path.defvjp(_bounded_fwd, _bounded_bwd)
 
 
+class SwiGLU(nn.Module):
+    """``down(silu(gate(x)) * up(x))``, ``width`` wide: the shared expert."""
+
+    width: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from .quant import QuantDense
+
+        dense = lambda feats, name: QuantDense(feats, use_bias=False, dtype=self.dtype, param_dtype=jnp.float32, name=name)
+        return dense(x.shape[-1], "down_proj")(nn.silu(dense(self.width, "gate_proj")(x)) * dense(self.width, "up_proj")(x))
+
+
 class MoEMLP(nn.Module):
     """Dropless expert SwiGLU block: ``[B, T, D] -> [B, T, D]``, the share of
-    the experts held (module docstring)."""
+    the experts held, and the shared expert where the layer has one (module docstring)."""
 
     cfg: MoEConfig
 
@@ -299,7 +325,11 @@ class MoEMLP(nn.Module):
             z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
             self.sow("losses", "moe_aux", cfg.balance_coef * balance + cfg.router_z_coef * z_loss,
                      init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.add)
-        return out.reshape(b, t, d).astype(x.dtype)
+        out = out.reshape(b, t, d).astype(x.dtype)
+        if cfg.shared_expert_intermediate_size:
+            with jax.named_scope("moe_shared"):
+                out = out + SwiGLU(cfg.shared_expert_intermediate_size, cfg.dtype, name="shared_expert")(x)
+        return out
 
 
 def moe_counters(variables: Any) -> dict:

@@ -20,7 +20,7 @@ from __future__ import annotations
 import math
 
 from dataclasses import dataclass, field
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -29,7 +29,16 @@ from jax.sharding import PartitionSpec as P
 
 
 #: the operators a block can have, by the names published configs give them
-LAYER_KINDS = ("full_attention", "conv")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
+
+
+class LayerAttention(NamedTuple):
+    """What one attention layer is told of itself (``TransformerConfig.attention_layer``)."""
+
+    kind: str  # "full_attention" | "sliding_attention"
+    num_heads: int  # query heads; the KV heads are the model's
+    window: int | None
+    rope: tuple  # (theta, scaling, partial_rotary_factor): the key of its rotary table
 
 
 @dataclass(frozen=True)
@@ -47,6 +56,12 @@ class TransformerConfig:
     # jit-static aux of the model): ("linear", factor) or
     # ("llama3", factor, low_freq_factor, high_freq_factor, original_len).
     rope_scaling: tuple | None = None
+    # A rotary table per layer kind, where a config publishes ``rope_parameters``
+    # keyed by ``layer_types``' names: ``((kind, theta, scaling, partial_rotary_factor),
+    # ...)``, hashable; ``partial_rotary_factor`` is the share of the head that
+    # rotates, from its first element on. A kind it does not name takes the two
+    # fields above and rotates the whole head.
+    rope_parameters: tuple | None = None
     dtype: Any = jnp.bfloat16
     tie_embeddings: bool = False
     attn_impl: str = "dot"  # 'dot' | 'flash' | 'ring'
@@ -54,15 +69,23 @@ class TransformerConfig:
     # itself + the previous W-1. Supported by every impl: 'dot'/'flash'
     # (stale K/V blocks skipped — O(T*W) compute), 'ring' (the ring visits
     # only 1 + ceil((W-1)/Tl) blocks — O(W) communication), and the decode
-    # cache.
+    # cache. With ``layer_types`` None it is the whole model's; with
+    # ``layer_types`` it is the window of the ``sliding_attention`` layers, and
+    # the ``full_attention`` layers have none (``attention_layer``).
     sliding_window: int | None = None
+    # query heads of each layer (published as ``num_attention_heads_per_layer``); None = ``num_heads`` everywhere
+    num_heads_per_layer: tuple[int, ...] | None = None
+    # "per-head": each head's attention output is multiplied by ``sigmoid(x @ W_g)``, one
+    # scalar a head from the block's normed input, before ``o_proj``. None = no gate.
+    gating: str | None = None
     # RMSNorm's epsilon, every norm of the model (published as rms_norm_eps / norm_eps)
     norm_eps: float = 1e-6
     # RMSNorm with a learned scale over the head dimension of q and of k, before RoPE
     qk_norm: bool = False
-    # The operator of each block, by the published names: "full_attention"
-    # (Attention) or "conv" (ShortConv, the gated short convolution of the
-    # LFM2 family, kernel length ``conv_L_cache``). None = attention everywhere.
+    # The operator of each block, by the published names: "full_attention" and
+    # "sliding_attention" (Attention, without and with the window) or "conv"
+    # (ShortConv, the gated short convolution of the LFM2 family, kernel length
+    # ``conv_L_cache``). None = attention everywhere.
     layer_types: tuple[str, ...] | None = None
     conv_L_cache: int = 3
     # MoE (models/moe.py): with ``num_experts`` > 0 every block after the first
@@ -76,8 +99,10 @@ class TransformerConfig:
     num_experts_per_tok: int = 2
     moe_intermediate_size: int | None = None  # one expert's width; None = mlp_dim
     use_expert_bias: bool = False
+    scoring_func: str = "sigmoid"  # how the router's logits become scores: "sigmoid" | "softmax" (over all experts)
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    shared_expert_intermediate_size: int = 0  # > 0: one SwiGLU of this width on every token, added to the routed experts'
     experts_held: tuple[int, int] | None = None
     seq_axis: str = "seq"  # mesh axis used when attn_impl == 'ring'
     # Mesh for attn_impl='ring' and 'flash' under plain jit: both wrap
@@ -111,6 +136,16 @@ class TransformerConfig:
             unknown = sorted(set(self.layer_types) - set(LAYER_KINDS))
             if unknown:
                 raise ValueError(f"layer_types holds {unknown}; the kinds this model has are {LAYER_KINDS}")
+            if "sliding_attention" in self.layer_types and self.sliding_window is None:
+                raise ValueError("layer_types names sliding_attention layers and sliding_window is None")
+        if self.num_heads_per_layer is not None:
+            if len(self.num_heads_per_layer) != self.num_layers:
+                raise ValueError(
+                    f"num_heads_per_layer names {len(self.num_heads_per_layer)} layers, num_layers is {self.num_layers}")
+            if any(h % self.kv_heads for h in self.num_heads_per_layer):
+                raise ValueError(f"num_heads_per_layer {self.num_heads_per_layer} are not all multiples of {self.kv_heads} KV heads")
+        if self.gating not in (None, "per-head"):
+            raise ValueError(f"gating must be None or 'per-head', got {self.gating!r}")
 
     @property
     def kv_heads(self) -> int:
@@ -119,17 +154,30 @@ class TransformerConfig:
     def layer_kind(self, i: int) -> str:
         return "full_attention" if self.layer_types is None else self.layer_types[i]
 
+    def attention_layer(self, i: int | None = None) -> LayerAttention:
+        """The one place that decides an attention layer's query heads, window
+        and rotary table. ``i`` None: the values of a model that names no layers."""
+        kind = "full_attention" if i is None else self.layer_kind(i)
+        heads = self.num_heads if i is None or self.num_heads_per_layer is None else self.num_heads_per_layer[i]
+        windowed = self.layer_types is None or i is None or kind == "sliding_attention"
+        rope = (self.rope_theta, self.rope_scaling, 1.0)
+        rope = next((tuple(params) for k, *params in self.rope_parameters or () if k == kind), rope)
+        return LayerAttention(kind, heads, self.sliding_window if windowed else None, rope)
+
     def is_expert_layer(self, i: int) -> bool:
         return self.num_experts > 0 and i >= self.num_dense_layers
 
     def require_attention_only(self, what: str) -> None:
-        """Decoding keeps keys and values per sequence and nothing else: a
-        layer kind with state of another sort cannot be served yet (ROADMAP M5)."""
+        """Decoding keeps keys and values per sequence and nothing else, in one
+        page shape and under one window for the whole model: a layer kind with
+        state of another sort (ROADMAP M5), or with a window of its own beside
+        layers without (ROADMAP M2), cannot be served yet."""
         other = sorted({k for k in self.layer_types or () if k != "full_attention"})
         if other:
+            why = ("a KV cache or pool holds one window and one page shape for the whole model, not a layer kind's own (ROADMAP M2)"
+                   if other[0] == "sliding_attention" else "their per-sequence state is not a KV cache (ROADMAP M5)")
             raise NotImplementedError(
-                f"{what} cannot run a model with layers of kind {other[0]!r}: their per-sequence state "
-                "is not a KV cache (ROADMAP M5); only the training path runs them"
+                f"{what} cannot run a model with layers of kind {other[0]!r}: {why}; only the training path runs them"
             )
 
 
@@ -145,7 +193,7 @@ def llama_partition_rules() -> list[tuple[str, P]]:
         # vocab over fsdp, features over model: the token gather then never
         # crosses the model axis (each TP shard gathers its feature slice)
         ("embed/embedding", P("fsdp", "model")),
-        ("attn/(q|k|v)_proj/kernel", P("fsdp", "model")),
+        ("attn/(q|k|v|g)_proj/kernel", P("fsdp", "model")),
         ("attn/o_proj/kernel", P("model", "fsdp")),
         ("mlp/(gate|up)_proj/kernel", P("fsdp", "model")),
         ("mlp/down_proj/kernel", P("model", "fsdp")),
@@ -169,17 +217,25 @@ class RMSNorm(nn.Module):
 
 
 def rope_frequencies(
-    head_dim: int, max_len: int, theta: float, scaling: tuple | None = None
+    head_dim: int, max_len: int, theta: float, scaling: tuple | None = None, partial_rotary_factor: float = 1.0
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Rotary cos/sin tables; ``scaling`` applies a context-extension
-    transform to the base frequencies:
+    """Rotary cos/sin tables ``[max_len, rot / 2]`` over the ``rot = head_dim *
+    partial_rotary_factor`` dimensions that rotate; ``scaling`` applies a
+    context-extension transform to the base frequencies:
 
     - ``("linear", factor)`` — positions interpolated by 1/factor;
     - ``("llama3", factor, low_freq_factor, high_freq_factor, orig_len)`` —
       Llama-3's wavelength-banded scheme: high-frequency components kept,
-      low-frequency ones divided by ``factor``, a smooth ramp between.
+      low-frequency ones divided by ``factor``, a smooth ramp between;
+    - ``("yarn", factor, beta_fast, beta_slow, orig_len, attention_factor)`` —
+      YaRN (arXiv:2309.00071) as published configs state it: pairs that turn
+      more than ``beta_fast`` times over ``orig_len`` positions kept, those that
+      turn fewer than ``beta_slow`` times divided by ``factor``, a linear ramp
+      over the pairs between; cos and sin times ``attention_factor``.
     """
-    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    rot = int(head_dim * partial_rotary_factor)
+    freqs = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    amplitude = None
     if scaling is not None:
         kind = scaling[0]
         if kind == "linear":
@@ -200,19 +256,33 @@ def rope_frequencies(
                 ),
             )
             freqs = scaled
+        elif kind == "yarn":
+            _, factor, beta_fast, beta_slow, orig_len, amplitude = scaling
+            # the (fractional) pair that turns ``rotations`` times over the original context
+            pair = lambda rotations: rot * math.log(orig_len / (rotations * 2.0 * math.pi)) / (2.0 * math.log(theta))
+            low, high = max(math.floor(pair(beta_fast)), 0), min(math.ceil(pair(beta_slow)), rot - 1)
+            ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+            freqs = freqs * (1.0 - ramp) + freqs / factor * ramp
         else:
             raise ValueError(f"unsupported rope scaling kind {kind!r}")
     t = jnp.arange(max_len, dtype=jnp.float32)
-    angles = jnp.outer(t, freqs)  # [T, head_dim/2]
+    angles = jnp.outer(t, freqs)  # [T, rot/2]
+    if amplitude is not None:
+        return jnp.cos(angles) * amplitude, jnp.sin(angles) * amplitude
     return jnp.cos(angles), jnp.sin(angles)
 
 
 def apply_rope(
     x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray, offset: int = 0, positions: jnp.ndarray | None = None
 ) -> jnp.ndarray:
-    """x: [B, T, H, D]. Rotates pairs (even, odd) of the head dim.
+    """x: [B, T, H, D]. Rotates pairs (even, odd) of the head dim, of its
+    first ``2 * cos.shape[-1]`` elements where the table is narrower than the
+    head (``partial_rotary_factor``); the rest passes as it is.
     ``positions`` [B, T] overrides the contiguous ``offset`` window —
     packed rows use it to restart positions at each segment boundary."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :rot], cos, sin, offset, positions), x[..., rot:]], axis=-1)
     if positions is not None:
         cos = cos[positions][:, :, None, :]  # [B, T, 1, D/2]
         sin = sin[positions][:, :, None, :]
@@ -255,16 +325,17 @@ def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None
     return out.reshape(b, t, h, d)
 
 
-@jax.named_scope("attn_kernel")
-def _flash_attention(cfg: TransformerConfig, q, k, v, segment_ids=None):
+def _flash_attention(cfg: TransformerConfig, layer: LayerAttention, q, k, v, segment_ids=None):
     """The flash path of every training branch: the kernel as it is on one
-    device, shard_mapped over ``cfg.mesh`` on several."""
+    device, shard_mapped over ``cfg.mesh`` on several. The kernels of a
+    ``sliding_attention`` layer run under a phase of their own."""
     from ..ops.flash_attention import flash_attention, flash_attention_sharded
 
-    kwargs = dict(causal=True, window=cfg.sliding_window, segment_ids=segment_ids)
-    if cfg.mesh is not None and cfg.mesh.size > 1:
-        return flash_attention_sharded(q, k, v, cfg.mesh, **kwargs)
-    return flash_attention(q, k, v, **kwargs)
+    kwargs = dict(causal=True, window=layer.window, segment_ids=segment_ids)
+    with jax.named_scope("attn_window_kernel" if layer.kind == "sliding_attention" else "attn_kernel"):
+        if cfg.mesh is not None and cfg.mesh.size > 1:
+            return flash_attention_sharded(q, k, v, cfg.mesh, **kwargs)
+        return flash_attention(q, k, v, **kwargs)
 
 
 def _adapter_add(y, inp, name, adapters):
@@ -291,6 +362,7 @@ def _adapter_add(y, inp, name, adapters):
 
 class Attention(nn.Module):
     cfg: TransformerConfig
+    layer: LayerAttention | None = None  # None: the model-wide values (``cfg.attention_layer()``)
 
     @nn.compact
     def __call__(
@@ -300,13 +372,15 @@ class Attention(nn.Module):
         from .quant import QuantDenseGeneral
 
         cfg = self.cfg
+        layer = self.layer or cfg.attention_layer()
+        num_heads, window = layer.num_heads, layer.window
         # quant-aware: int8 weight-only trees (models/quant.py) feed the
         # matmuls directly, scales applied to the fp32 accumulator
         dense = lambda feats, name: QuantDenseGeneral(
             feats, axis=-1, use_bias=False, dtype=cfg.dtype, param_dtype=jnp.float32, name=name
         )
         b, t, _ = x.shape
-        q = _adapter_add(dense((cfg.num_heads, cfg.head_dim), "q_proj")(x), x, "q_proj", adapters)
+        q = _adapter_add(dense((num_heads, cfg.head_dim), "q_proj")(x), x, "q_proj", adapters)
         k = _adapter_add(dense((cfg.kv_heads, cfg.head_dim), "k_proj")(x), x, "k_proj", adapters)
         v = _adapter_add(dense((cfg.kv_heads, cfg.head_dim), "v_proj")(x), x, "v_proj", adapters)
         if cfg.qk_norm:
@@ -339,7 +413,7 @@ class Attention(nn.Module):
             q = apply_rope(q, cos, sin, positions=positions)
             k = apply_rope(k, cos, sin, positions=positions)
             if cfg.attn_impl == "flash":
-                out = _flash_attention(cfg, q, k, v, segment_ids=seg_ids)
+                out = _flash_attention(cfg, layer, q, k, v, segment_ids=seg_ids)
             else:
                 out = _dot_attention(q, k, v, mask=mask)
         elif paged is not None:
@@ -362,8 +436,8 @@ class Attention(nn.Module):
             kv_pos = jnp.arange(gk.shape[1])[None, None, :]  # [1, 1, L]
             q_pos = positions[:, :, None]  # [B, t, 1] absolute positions
             mask = kv_pos <= q_pos  # causal AND only this row's filled slots
-            if cfg.sliding_window is not None:
-                mask = mask & _window_keep(q_pos, kv_pos, cfg.sliding_window)
+            if window is not None:
+                mask = mask & _window_keep(q_pos, kv_pos, window)
             out = _dot_attention(q, gk, gv, mask=mask)
         elif cache is not None:
             # Autoregressive decode: write this call's K/V into the static-
@@ -384,39 +458,43 @@ class Attention(nn.Module):
             q_pos = offset + jnp.arange(t)[:, None]  # [t, 1]
             kv_pos = jnp.arange(s)[None, :]  # [1, s]
             mask = kv_pos <= q_pos  # causal AND only written slots
-            if cfg.sliding_window is not None:
-                mask = mask & _window_keep(q_pos, kv_pos, cfg.sliding_window)
+            if window is not None:
+                mask = mask & _window_keep(q_pos, kv_pos, window)
             if decode_pad is not None:
                 # left-pad slots hold garbage K/V — mask them per row
                 pad_len, _ = decode_pad
                 mask = mask[None] & (kv_pos[None] >= pad_len[:, None, None])
             out = _dot_attention(q, k, v, mask=mask)
         elif cfg.attn_impl == "flash":
-            out = _flash_attention(cfg, q, k, v)
+            out = _flash_attention(cfg, layer, q, k, v)
         elif cfg.attn_impl == "ring":
             with jax.named_scope("attn_kernel"):
                 if cfg.mesh is not None:
                     from ..ops.ring_attention import ring_attention_sharded
 
                     out = ring_attention_sharded(
-                        q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
+                        q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=window
                     )
                 else:
                     from ..ops.ring_attention import ring_attention
 
                     out = ring_attention(
-                        q, k, v, axis_name=cfg.seq_axis, causal=True, window=cfg.sliding_window
+                        q, k, v, axis_name=cfg.seq_axis, causal=True, window=window
                     )
-        elif cfg.sliding_window is not None:
+        elif window is not None:
             pos = jnp.arange(t)
             q_pos, k_pos = pos[:, None], pos[None, :]
             out = _dot_attention(
-                q, k, v, mask=(q_pos >= k_pos) & _window_keep(q_pos, k_pos, cfg.sliding_window)
+                q, k, v, mask=(q_pos >= k_pos) & _window_keep(q_pos, k_pos, window)
             )
         else:
             out = _dot_attention(q, k, v, causal=True)
 
-        out = out.reshape(b, t, cfg.num_heads * cfg.head_dim)
+        if cfg.gating == "per-head":
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(dense((num_heads,), "g_proj")(x).astype(jnp.float32))  # [B, T, H]
+                out = out * gate[..., None].astype(out.dtype)
+        out = out.reshape(b, t, num_heads * cfg.head_dim)
         from .quant import QuantDenseGeneral
 
         proj = QuantDenseGeneral(
@@ -473,11 +551,13 @@ class ShortConv(nn.Module):
 class DecoderBlock(nn.Module):
     """``h = x + Op(norm(x))``, ``y = h + FFN(norm(h))``. ``kind`` names the
     operator (``LAYER_KINDS``), which owns its projections and its call;
-    ``use_moe`` puts the expert layer in the dense MLP's place."""
+    ``use_moe`` puts the expert layer in the dense MLP's place; ``attention``
+    is what an attention operator is told of itself (None: the model-wide values)."""
 
     cfg: TransformerConfig
     use_moe: bool = False
     kind: str = "full_attention"
+    attention: LayerAttention | None = None
 
     @nn.compact
     def __call__(
@@ -499,13 +579,13 @@ class DecoderBlock(nn.Module):
                 raise NotImplementedError("a 'conv' layer takes no cache, packed rows, pages or attention adapters")
             x = x + ShortConv(cfg, name="conv")(norm("conv_norm")(x))
         elif cache is not None:
-            attn_out, new_cache = Attention(cfg, name="attn")(
+            attn_out, new_cache = Attention(cfg, self.attention, name="attn")(
                 norm("attn_norm")(x), cos, sin, cache=cache, offset=offset,
                 decode_pad=decode_pad, attend_len=attend_len, paged=paged, adapters=attn_ad,
             )
             x = x + attn_out
         else:
-            x = x + Attention(cfg, name="attn")(
+            x = x + Attention(cfg, self.attention, name="attn")(
                 norm("attn_norm")(x), cos, sin, seg_info=seg_info, adapters=attn_ad
             )
         if self.use_moe:
@@ -517,8 +597,10 @@ class DecoderBlock(nn.Module):
                 hidden_dim=cfg.hidden_dim,
                 mlp_dim=cfg.moe_intermediate_size or cfg.mlp_dim,
                 use_expert_bias=cfg.use_expert_bias,
+                scoring_func=cfg.scoring_func,
                 norm_topk_prob=cfg.norm_topk_prob,
                 routed_scaling_factor=cfg.routed_scaling_factor,
+                shared_expert_intermediate_size=cfg.shared_expert_intermediate_size,
                 experts_held=cfg.experts_held,
                 dtype=cfg.dtype,
             )
@@ -600,14 +682,17 @@ class DecoderLM(nn.Module):
                 mask = None  # the flash kernels mask from the raw ids
             else:
                 mask = jnp.tril(jnp.ones((t, t), dtype=bool))[None] & same
-                if cfg.sliding_window is not None:
+                window = cfg.attention_layer(0).window  # every layer's: a packed row runs full_attention layers only
+                if window is not None:
                     pos = jnp.arange(t)
-                    mask = mask & _window_keep(pos[:, None], pos[None, :], cfg.sliding_window)[None]
+                    mask = mask & _window_keep(pos[:, None], pos[None, :], window)[None]
             seg_info = (positions, mask, segment_ids)
         x = nn.Embed(
             cfg.vocab_size, cfg.hidden_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="embed"
         )(tokens)
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta, cfg.rope_scaling)
+        # one rotary table, unless ``rope_parameters`` gives the layer kinds their own
+        layers = [cfg.attention_layer(i) for i in range(cfg.num_layers)]
+        tables = {rope: rope_frequencies(cfg.head_dim, cfg.max_seq_len, *rope) for rope in dict.fromkeys(a.rope for a in layers)}
 
         def constrain(x):
             if cfg.act_sharding is None:
@@ -625,19 +710,20 @@ class DecoderLM(nn.Module):
         adapter_tree, adapter_ids = adapters if adapters is not None else (None, None)
         for i in range(cfg.num_layers):
             use_moe, kind = cfg.is_expert_layer(i), cfg.layer_kind(i)
+            cos, sin = tables[layers[i].rope]
             name = f"layer_{i}"
             layer_ad = None
             if adapter_tree is not None and adapter_tree.get(name) is not None:
                 layer_ad = (adapter_tree[name], adapter_ids)
             if cache is not None:
-                x, new_cache[name] = DecoderBlock(cfg, use_moe=use_moe, kind=kind, name=name)(
+                x, new_cache[name] = DecoderBlock(cfg, use_moe=use_moe, kind=kind, attention=layers[i], name=name)(
                     x, cos, sin, cache=cache[name], offset=offset, decode_pad=decode_pad,
                     attend_len=attend_len, paged=paged, adapters=layer_ad,
                 )
                 x = constrain(x)
             else:
                 x = constrain(
-                    block_cls(cfg, use_moe=use_moe, kind=kind, name=name)(
+                    block_cls(cfg, use_moe=use_moe, kind=kind, attention=layers[i], name=name)(
                         x, cos, sin, seg_info=seg_info, adapters=layer_ad
                     )
                 )

@@ -205,10 +205,12 @@ def chip_peak_flops(device=None) -> float:
 #: name (``embed``, ``mlp``; ``attn`` and ``*_norm`` through the aliases below).
 #: The expert layer and the short convolution open scopes of their own
 #: (``moe_route``: scores, top-k, sort and the two moves of rows; ``moe_experts``:
-#: the grouped products; ``conv_op``: the whole operator).
+#: the grouped products; ``moe_shared``: the shared expert; ``conv_op``: the whole
+#: operator). ``attn_window_kernel`` is ``attn_kernel`` for the kernels of a
+#: ``sliding_attention`` layer, ``attn_gate`` the per-head gate on attention's output.
 PHASES = (
-    "embed", "norm", "attn_proj", "attn_kernel", "kv_write", "kv_gather", "attention",
-    "mlp", "conv_op", "moe_route", "moe_experts", "loss_head", "head", "sampling", "grad_clip", "optimizer",
+    "embed", "norm", "attn_proj", "attn_kernel", "attn_window_kernel", "attn_gate", "kv_write", "kv_gather", "attention",
+    "mlp", "conv_op", "moe_route", "moe_experts", "moe_shared", "loss_head", "head", "sampling", "grad_clip", "optimizer",
 )
 #: Flax module names that are not themselves phase names.
 _PHASE_ALIASES = {"attn": "attn_proj"}

@@ -206,10 +206,14 @@ def test_beam_search_matches_hf():
             "high_freq_factor": 4.0,
             "original_max_position_embeddings": 32,
         },
+        {"rope_type": "yarn", "factor": 4.0, "original_max_position_embeddings": 16, "beta_fast": 4.0, "beta_slow": 1.0},
+        {"rope_type": "yarn", "factor": 8.0, "original_max_position_embeddings": 16, "beta_fast": 2.0, "beta_slow": 1.0,
+         "attention_factor": 1.3},
     ],
+    ids=["linear", "llama3", "yarn", "yarn-with-attention-factor"],
 )
 def test_rope_scaled_logits_match_hf(rope_scaling):
-    """Llama-3 / linear rope scaling must reproduce HF's scaled rotary
+    """Llama-3 / linear / YaRN rope scaling must reproduce HF's scaled rotary
     geometry, not silently fall back to plain RoPE."""
     hf_cfg = transformers.LlamaConfig(
         vocab_size=61, hidden_size=32, intermediate_size=64, num_hidden_layers=2,
@@ -235,9 +239,12 @@ def test_unsupported_rope_scaling_raises():
         max_position_embeddings, rope_theta = 64, 10000.0
         tie_word_embeddings, sliding_window = False, None
         head_dim = 8
-        rope_scaling = {"rope_type": "yarn", "factor": 2.0}
+        rope_scaling = {"rope_type": "longrope", "factor": 2.0}
 
-    with pytest.raises(ValueError, match="yarn"):
+    with pytest.raises(ValueError, match="longrope"):
+        transformer_config_from_hf(FakeCfg())
+    FakeCfg.rope_scaling = {"rope_type": "yarn", "factor": 2.0}  # YaRN without the context it was stretched from
+    with pytest.raises(ValueError, match="original_max_position_embeddings"):
         transformer_config_from_hf(FakeCfg())
 
 

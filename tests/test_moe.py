@@ -1,6 +1,6 @@
-"""The dropless MoE layer: routing (sigmoid scores, with and without the selection bias), every pair
-computed whatever the routing, one chip's share of the experts, counters, aux
-losses, expert parallelism over the mesh."""
+"""The dropless MoE layer: routing (sigmoid scores, with and without the selection bias, or a softmax over
+all the experts), every pair computed whatever the routing, one chip's share of the experts, the shared
+expert beside them, counters, aux losses, expert parallelism over the mesh."""
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +41,9 @@ def by_hand(cfg, variables, x):
         weight = jnp.sum(jnp.where(chosen == e, gates, 0.0), axis=-1)
         h = jax.nn.silu(tokens @ p["moe/gate_proj"][n]) * (tokens @ p["moe/up_proj"][n])
         out = out + weight[:, None] * (h @ p["moe/down_proj"][n])
+    if "shared_expert" in p:  # on every token, ungated
+        kernel = lambda name: p["shared_expert"][name]["kernel"]
+        out = out + (jax.nn.silu(tokens @ kernel("gate_proj")) * (tokens @ kernel("up_proj"))) @ kernel("down_proj")
     return out.reshape(x.shape)
 
 
@@ -54,7 +57,11 @@ class TestMoEMLP:
     @pytest.mark.parametrize("overrides", [
         dict(), dict(top_k=1), dict(top_k=4), dict(use_expert_bias=True),
         dict(norm_topk_prob=False, routed_scaling_factor=2.5), dict(num_experts=8, experts_held=(2, 5)),
-    ], ids=["top2", "top1", "top4-of-4", "bias", "unnormalised-scaled", "share"])
+        dict(scoring_func="softmax"), dict(scoring_func="softmax", num_experts=8, top_k=3, routed_scaling_factor=2.5),
+        dict(scoring_func="softmax", norm_topk_prob=False), dict(shared_expert_intermediate_size=12),
+        dict(scoring_func="softmax", shared_expert_intermediate_size=12, num_experts=8, experts_held=(2, 5)),
+    ], ids=["top2", "top1", "top4-of-4", "bias", "unnormalised-scaled", "share", "softmax", "softmax-top3-of-8-scaled",
+            "softmax-unnormalised", "shared-expert", "softmax-shared-share"])
     def test_output_is_the_loop_over_experts(self, overrides):
         model, variables, x = make_layer(**overrides)
         if "buffers" in variables:
@@ -80,6 +87,32 @@ class TestMoEMLP:
         want = {0: s[0] / (s[0] + s[3] + 1e-6), 3: s[3] / (s[0] + s[3] + 1e-6)}
         for e, g in zip(np.asarray(chosen)[0], np.asarray(gates)[0]):
             assert g == pytest.approx(float(want[int(e)]), rel=1e-6)
+
+    @pytest.mark.parametrize("norm, scaling", [(True, 1.0), (True, 2.5), (False, 1.0)])
+    def test_softmax_scores_are_over_all_the_experts_and_the_weights_over_the_chosen(self, norm, scaling):
+        cfg = MoEConfig(num_experts=6, top_k=2, scoring_func="softmax", norm_topk_prob=norm, routed_scaling_factor=scaling)
+        logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0, 0.5, -3.0], [0.0, 0.0, 4.0, 0.0, 3.0, 0.0]])
+        scores, chosen, gates = route(cfg, logits)
+        p = np.exp(np.asarray(logits)) / np.exp(np.asarray(logits)).sum(-1, keepdims=True)
+        np.testing.assert_allclose(np.asarray(scores), p, rtol=1e-6)
+        assert [sorted(row) for row in np.asarray(chosen).tolist()] == [[0, 1], [2, 4]]
+        for row, (e, g) in enumerate(zip(np.asarray(chosen), np.asarray(gates))):
+            want = p[row, e] / (p[row, e].sum() if norm else 1.0) * scaling  # no guard in the sum: it is k / E at least
+            np.testing.assert_allclose(g, want, rtol=1e-6)
+
+    def test_an_unknown_scoring_is_refused(self):
+        with pytest.raises(ValueError, match="scoring_func"):
+            MoEConfig(scoring_func="tanh")
+
+    @pytest.mark.parametrize("held", [None, (0, 2), (2, 4)], ids=["all", "first-half", "second-half"])
+    def test_the_shared_expert_is_added_to_every_token_whatever_the_share(self, held):
+        model, variables, x = make_layer(shared_expert_intermediate_size=12, experts_held=held)
+        without = MoEMLP(MoEConfig(**{**model.cfg.__dict__, "shared_expert_intermediate_size": 0}))
+        routed = {"params": {k: v for k, v in variables["params"].items() if k != "shared_expert"}}
+        kernel = lambda name: variables["params"]["shared_expert"][name]["kernel"]
+        assert kernel("gate_proj").shape == (D, 12) and kernel("down_proj").shape == (12, D)
+        shared = (jax.nn.silu(x @ kernel("gate_proj")) * (x @ kernel("up_proj"))) @ kernel("down_proj")
+        np.testing.assert_allclose(np.asarray(model.apply(variables, x)), np.asarray(without.apply(routed, x) + shared), atol=1e-5)
 
     def test_aux_losses_sown(self):
         model, params, x = make_layer()
@@ -173,8 +206,10 @@ class TestRowBound:
     def test_the_bound_is_twice_the_even_share_in_whole_tiles_and_never_over_all_pairs(self, pairs, held, experts, want):
         assert row_bound(pairs, held, experts) == want
 
-    def test_random_routing_takes_the_usual_path_and_is_the_loop_over_experts(self):
-        model, variables, x = make_layer(**BOUNDED)
+    @pytest.mark.parametrize("overrides", [dict(), dict(scoring_func="softmax", shared_expert_intermediate_size=12)],
+                             ids=["sigmoid", "softmax-shared"])
+    def test_random_routing_takes_the_usual_path_and_is_the_loop_over_experts(self, overrides):
+        model, variables, x = make_layer(**BOUNDED, **overrides)
         counters = assert_same_as_the_loop(model, variables, x)
         assert 0 < counters["moe/pairs_held"] <= BOUND and counters["moe/overflow_layers"] == 0
 
@@ -341,6 +376,13 @@ class TestExpertParallel:
         assert len(expert_sharded) == 3
         for s in expert_sharded:
             assert s.spec[0] == "expert"
+
+    def test_partition_rules_shard_the_shared_expert_as_a_dense_mlp(self):
+        _, params, _ = make_layer(num_experts=8, shared_expert_intermediate_size=16)
+        mesh = mesh_lib.create_mesh({"fsdp": 2, "model": 4})
+        shardings = mesh_lib.sharding_for(params, mesh, moe_partition_rules())["params"]["shared_expert"]
+        assert tuple(shardings["gate_proj"]["kernel"].spec) == ("fsdp", "model") == tuple(shardings["up_proj"]["kernel"].spec)
+        assert tuple(shardings["down_proj"]["kernel"].spec) == ("model", "fsdp")
 
 
 class TestMoETransformer:

@@ -87,6 +87,27 @@ def test_flash_kernel_compiles_for_v5e(v5e, case):
     assert "tpu_custom_call" in _compile(fn, *specs)
 
 
+# laguna-train-8k's calls: one KV head and its query group (9 heads on a window layer, 6 on a full one), a window of
+# 512 where no sweep chose blocks, so the kernels run on the default 512 x 1024 and an edge pair is mostly masked
+@pytest.mark.parametrize("heads, window", [(9, 512), (6, None)], ids=["window-512-group-9", "full-group-6"])
+def test_flash_kernels_compile_for_one_kv_head_and_its_query_group(v5e, heads, window):
+    from dmlcloud_tpu.ops.flash_attention import _plan_for
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    sds = lambda h: jax.ShapeDtypeStruct((B, T, h, D), jnp.bfloat16, sharding=one_chip)
+    hlo = _compile(_sum_grad(_pallas(window=window)), sds(heads), sds(1), sds(1))
+    assert hlo.count("tpu_custom_call") >= 3
+    plan = _plan_for(None, None, D, T, T, True, window)
+    assert (plan.block_q, plan.block_k) == (512, 1024)
+    # a window layer's query block holds a pair with two key blocks at most, a key block with three query blocks:
+    # 15 of 16 key blocks' worth of the rectangle is never a grid step; a full layer's band is the whole triangle
+    assert (plan.kv_width, plan.q_width) == ((2, 3) if window else (8, 16))
+    for qi in range(plan.num_qb):
+        first, last = plan.kv_band(qi)
+        kept = [kb for kb in range(plan.num_kb) if kb * 1024 <= qi * 512 + 511 and (window is None or qi * 512 - (kb * 1024 + 1023) < window)]
+        assert list(range(first, last + 1)) == kept
+
+
 def test_flash_kernel_compiles_on_a_four_chip_mesh(v5e):
     """XLA refuses to partition a Mosaic kernel; ``flash_attention_sharded``
     is what lets ``attn_impl="flash"`` compile on an fsdp x model mesh."""
