@@ -14,23 +14,32 @@ nothing stands in for the absent experts. ``None`` holds them all. With
 every token, ungated, and is added to the held experts' share: what every
 holder of a layer computes alike (phase ``moe_shared``).
 
-How: the ``N * k`` pairs are sorted by expert (pairs of experts not held sort
-to the end, so the live rows are a prefix of the sorted order), the tokens of
-the sorted pairs gathered, the gate/up and down products run as grouped
-products over the ragged groups (``ops/grouped_matmul.py``), the gate weights
-applied and the rows added back to their tokens (gathers both ways, forward
-and backward: no scatter-add runs). No pair may be dropped and the worst
-routing sends all ``N * k`` to held experts, but a layer that holds ``h`` of
-``E`` experts expects ``N * k * h / E``. So the layer works in a buffer of
+How: the tokens of the pairs are gathered into rows that lie sorted by expert
+(pairs of experts not held sort to the end, so the live rows are a prefix of
+the sorted order), the gate/up and down products run as grouped products over
+the ragged groups (``ops/grouped_matmul.py``), the gate weights applied and the
+rows added back to their tokens (gathers both ways, forward and backward: no
+scatter-add of rows runs). No pair may be dropped and the worst routing sends
+all ``N * k`` to held experts, but a layer that holds ``h`` of ``E`` experts
+expects ``N * k * h / E``. So the layer works in a buffer of
 ``R = row_bound(N * k, h, E)`` rows, twice that even share, whenever a step's
 live rows fit it, and in the full ``N * k`` buffer when they do not: both
 compiled, chosen on the device by a ``jax.lax.cond`` on the step's own
-``group_sizes``, the same output and gradients wherever both apply. The usual
-path reads and writes ``R``-row arrays only (beside the index vectors), keeps
-``R``-row residuals for its hand-written backward, and the rare path recomputes
-its forward in the backward, so a step that fits pays nothing for the buffer
-behind it. Where the layer holds every expert (or half of them) ``R`` is
-``N * k``: one path, no ``cond``.
+``group_sizes``, the same output and gradients wherever both apply. On the
+usual path everything after the choice of experts costs what the buffer holds
+(``R`` rows and one pass over the ``N`` tokens), not what the router chose
+from: :func:`sort_pairs` finds the first ``R`` sorted pairs by counting the live
+pairs into a token-order buffer and sorting that many places (nothing as long
+as ``N * k`` is sorted or fetched by index), the way out is one ``R``-row
+gather, the way back ``R + N`` row fetches whatever ``k`` is
+(``collect_rows``), the weights and their gradient an ``R``-sized gather and
+its transpose; it keeps ``R``-row residuals for its hand-written backward. No
+shape, trip count or branch follows the number of live pairs: a step that
+fits pays the buffer's cost whatever its routing. The rare path sorts all
+``N * k`` pairs for itself inside its branch and recomputes its forward in
+the backward, so a step that fits pays nothing for the buffer behind it. Where
+the layer holds every expert (or half of them) ``R`` is ``N * k``: one path,
+no ``cond``, the ``N * k`` sort.
 
 - ``expert_bias`` (``use_expert_bias``) takes part in the choice of experts
   only. It is a buffer, not a parameter (collection ``buffers``, as the
@@ -59,7 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.grouped_matmul import collect, collect_rows, grouped_matmul, spread, spread_rows
+from ..ops.grouped_matmul import Runs, collect, collect_rows, grouped_matmul, run_layout, spread, spread_rows
 
 
 def moe_partition_rules() -> list[tuple[str, P]]:
@@ -118,20 +127,71 @@ def route(cfg: MoEConfig, logits, bias=None):
     return scores, chosen, gates * cfg.routed_scaling_factor
 
 
-def sort_pairs(chosen, gates, held: tuple[int, int]):
-    """The ``N * k`` pairs in the order of their expert, the pairs of experts
-    outside ``held`` last: ``(order, its inverse, the sorted pairs' weights (0
-    where not held), group_sizes [held experts])``; sorted pair ``i`` is pair
-    ``order[i]`` of token ``order[i] // k``."""
-    a, b = held
+def _tokens_of(ends, slots):
+    """For sorted ``ends [N]``, how many lie at or below each of ``slots``: the
+    token whose live pairs a slot falls among. A search in two levels of
+    comparisons (blocks of 128 ends, then one block's), no loop and no sort:
+    0.014 ms on a v5e for 5,504 slots among 8,192 ends, where
+    ``jnp.searchsorted`` takes 0.52 by its loop and 0.13 by its sort
+    (PERF.md section 6, PR 36)."""
+    table = jnp.pad(ends, (0, -ends.shape[0] % 128), constant_values=jnp.iinfo(ends.dtype).max).reshape(-1, 128)
+    block = jnp.sum(table[:, -1][None, :] <= slots[:, None], axis=1, dtype=jnp.int32)
+    block = jnp.minimum(block, table.shape[0] - 1)
+    return block * 128 + jnp.sum(table[block] <= slots[:, None], axis=1, dtype=jnp.int32)
+
+
+def sort_pairs(chosen, gates, held: tuple[int, ...]):
+    """The ``N * k`` pairs in the order of their expert, stably, the pairs of
+    experts outside ``[a, b)`` last; sorted pair ``i`` is pair ``order[i]`` of
+    token ``order[i] // k``.
+
+    ``held = (a, b)``: all of them, by a stable sort of the ``N * k`` keys and a
+    second one for its inverse: ``(order, its inverse, the sorted pairs' weights
+    (0 where not held), group_sizes [held experts])``.
+
+    ``held = (a, b, rows)``, for a layer that works in ``rows`` rows: the first
+    ``rows`` sorted pairs alone, right wherever the live pairs fit them, and
+    nothing sorted or fetched that is longer than the buffer: ``(order [rows],
+    Runs, weights [rows], group_sizes)``. Over the ``N * k`` pairs run comparisons
+    and cumulative sums of integers only: a token's live pairs are counted, its
+    first slot in token order is the live pairs before it, a search gives every
+    place of the token-order buffer (``ops.grouped_matmul.run_layout``) its
+    token and pair, and two sorts of that many places, by expert and back, give
+    the expert order and each place's row in it. The ``Runs`` are what
+    :func:`~dmlcloud_tpu.ops.grouped_matmul.collect_rows` goes back by."""
+    a, b, *bound = held
     n, k = chosen.shape
-    expert = chosen.reshape(n * k)
-    live = (expert >= a) & (expert < b)
-    key = jnp.where(live, expert - a, b - a)
-    order = jnp.argsort(key, stable=True)
-    group_sizes = jnp.sum(key[:, None] == jnp.arange(b - a)[None, :], axis=0, dtype=jnp.int32)
-    weight = jnp.where(live, gates.reshape(n * k), 0.0)[order]
-    return order, jnp.argsort(order), weight, group_sizes
+    live = (chosen >= a) & (chosen < b)
+    key = jnp.where(live, chosen - a, b - a)
+    group_sizes = jnp.sum(key.reshape(n * k, 1) == jnp.arange(b - a)[None, :], axis=0, dtype=jnp.int32)
+    if not bound:
+        order = jnp.argsort(key.reshape(n * k), stable=True)
+        weight = jnp.where(live, gates, 0.0).reshape(n * k)[order]
+        return order, jnp.argsort(order), weight, group_sizes
+    (rows,) = bound
+    chunks, tile, heads = run_layout(rows, k)  # a token's live pairs are k at most
+    count = jnp.sum(live, axis=1, dtype=jnp.int32)
+    ends = jnp.cumsum(count)
+    head = ends - count  # a token's first slot: the live pairs before it
+    slot = jnp.where(live, head[:, None] + jnp.cumsum(live, axis=1, dtype=jnp.int32) - 1, -1)  # [N, k]
+    # chunk c of the buffer holds, whole, the runs whose heads are slots [c * heads, (c + 1) * heads)
+    chunk = jnp.repeat(jnp.arange(chunks, dtype=jnp.int32), tile)
+    at = chunk * heads + jnp.tile(jnp.arange(tile, dtype=jnp.int32), chunks)  # the slot a place stands for
+    token = jnp.minimum(_tokens_of(ends, at), n - 1)
+    mine = jnp.concatenate([slot, head[:, None]], axis=1)[token]  # one fetch a place: its token's slots, and the first of them
+    match = mine[:, :k] == at[:, None]  # [places, k]: which of its token's pairs a place holds
+    taken = jnp.any(match, axis=1) & (mine[:, k] // heads == chunk)
+    pair = token * k + jnp.argmax(match, axis=1).astype(jnp.int32)
+    expert = jnp.where(taken, jnp.sum(jnp.where(match, key[token], 0), axis=1), b - a)
+    # the places lie in token order: sorted stably by expert they are the expert order, empty places last
+    place = jnp.arange(chunks * tile, dtype=jnp.int32)
+    _, order, came_from = jax.lax.sort((expert, pair, place), num_keys=1, is_stable=True)
+    _, row = jax.lax.sort((came_from, place), num_keys=1)
+    order = order[:rows]
+    weight = jnp.where(jnp.arange(rows) < ends[-1], gates.reshape(n * k)[order], 0.0)
+    runs = Runs(row, jnp.where(taken, token, n).reshape(chunks, tile),
+                jnp.where(count > 0, head // heads * tile + head % heads, chunks * tile - 1))
+    return order, runs, weight, group_sizes
 
 
 #: The bounded buffer holds this many times the even share of the held experts.
@@ -178,40 +238,54 @@ def _full_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes
         return collect(out_rows.astype(tokens.dtype), order, inverse, k)
 
 
+def _sorted_full_path(tokens, gates, gate_w, up_w, down_w, chosen, group_sizes, held):
+    """:func:`_full_path` for a bounded layer: the rare path sorts all the pairs for itself."""
+    with jax.named_scope("moe_route"):
+        order, inverse, weight, _ = sort_pairs(chosen, gates, held)
+    return _full_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, chosen.shape[1])
+
+
 # The two paths of a bounded layer, forward and backward: each body is traced once
 # a process (an inner jit, inlined under the step's) and shared by the expert
-# layers and by the traces a step goes through before it runs.
+# layers and by the traces a step goes through before it runs. The usual path reads
+# the weights ``sort_pairs`` gave it and gives them their gradient; the rare path
+# reads the gates they were taken from and gives those theirs.
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bound"))
-def _usual_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, *, k, bound):
+@functools.partial(jax.jit, static_argnames=("bound",))
+def _usual_fwd(tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, *, bound):
+    del gates
+    k = chosen.shape[1]
     with jax.named_scope("moe_route"):
         live = _live_rows(group_sizes, bound)
         rows = jnp.where(live, spread_rows(tokens, order, k, bound), 0)  # [R, D]
     gate, up, out_rows = _ffn(rows, gate_w, up_w, down_w, group_sizes)
     with jax.named_scope("moe_route"):
-        weighted = jnp.where(live, out_rows.astype(jnp.float32) * weight[:bound, None], 0.0)
-        return collect_rows(weighted.astype(tokens.dtype), inverse, k), (rows, gate, up, out_rows)
+        weighted = jnp.where(live, out_rows.astype(jnp.float32) * weight[:, None], 0.0)
+        out = collect_rows(weighted.astype(tokens.dtype), runs)
+        return out, (rows, gate, up, out_rows)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bound"))
-def _full_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, *, k, bound):
-    out = _full_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k)
+@functools.partial(jax.jit, static_argnames=("bound", "held"))
+def _full_fwd(tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, *, bound, held):
+    del weight, order, runs
+    out = _sorted_full_path(tokens, gates, gate_w, up_w, down_w, chosen, group_sizes, held)
     rows = jnp.zeros((bound, tokens.shape[1]), tokens.dtype)  # nothing is kept: the backward computes this path again
     wide = jnp.zeros((bound, gate_w.shape[2]), tokens.dtype)
     return out, (rows, wide, wide, rows)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bound"))
-def _usual_bwd(saved, tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, d_out, *, k, bound):
+@functools.partial(jax.jit, static_argnames=("bound",))
+def _usual_bwd(saved, tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, d_out, *, bound):
     del tokens
     rows, gate, up, out_rows = saved
+    k = chosen.shape[1]
     product = lambda lhs, rhs: grouped_matmul(lhs, rhs, group_sizes)
     with jax.named_scope("moe_route"):
         live = _live_rows(group_sizes, bound)
         d_weighted = jnp.where(live, spread_rows(d_out.astype(rows.dtype), order, k, bound).astype(jnp.float32), 0.0)
         d_weight = jnp.sum(d_weighted * out_rows.astype(jnp.float32), axis=-1)
-        d_out_rows = (d_weighted * weight[:bound, None]).astype(rows.dtype)
+        d_out_rows = (d_weighted * weight[:, None]).astype(rows.dtype)
     with jax.named_scope("moe_experts"):
         hidden, swiglu_vjp = jax.vjp(lambda g, u: nn.silu(g) * u, gate, up)
         d_hidden, d_down = jax.vjp(product, hidden, down_w)[1](d_out_rows)  # a product's own result is not needed
@@ -220,37 +294,39 @@ def _usual_bwd(saved, tokens, weight, gate_w, up_w, down_w, order, inverse, grou
         d_rows_up, d_up_w = jax.vjp(product, rows, up_w)[1](d_up)
     with jax.named_scope("moe_route"):
         d_rows = jnp.where(live, d_rows_gate + d_rows_up, 0)
-        d_tokens = collect_rows(d_rows, inverse, k).astype(rows.dtype)
-        return d_tokens, jnp.pad(d_weight, (0, weight.shape[0] - bound)), d_gate_w, d_up_w, d_down
+        d_tokens = collect_rows(d_rows, runs).astype(rows.dtype)
+        return d_tokens, d_weight, jnp.zeros_like(gates), d_gate_w, d_up_w, d_down
 
 
-@functools.partial(jax.jit, static_argnames=("k", "bound"))
-def _full_bwd(saved, tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, d_out, *, k, bound):
-    del saved, bound
-    path = lambda *diff: _full_path(*diff, order, inverse, group_sizes, k)
-    return jax.vjp(path, tokens, weight, gate_w, up_w, down_w)[1](d_out)
+@functools.partial(jax.jit, static_argnames=("bound", "held"))
+def _full_bwd(saved, tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, d_out, *, bound, held):
+    del saved, order, runs, bound
+    path = lambda *diff: _sorted_full_path(*diff, chosen, group_sizes, held)
+    d_tokens, d_gates, *d_matrices = jax.vjp(path, tokens, gates, gate_w, up_w, down_w)[1](d_out)
+    return d_tokens, jnp.zeros_like(weight), d_gates, *d_matrices
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _bounded_path(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound):
-    """:func:`_full_path`'s result, computed in ``bound`` rows when the live rows fit them."""
-    return _bounded_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(10, 11))
+def _bounded_path(tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, bound, held):
+    """:func:`_full_path`'s result, computed in ``bound`` rows when the live rows fit them:
+    ``order``, ``runs`` and ``weight`` are ``sort_pairs``' for ``(*held, bound)``."""
+    return _bounded_fwd(tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, bound, held)[0]
 
 
-def _bounded_fwd(tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes, k, bound):
-    args = (tokens, weight, gate_w, up_w, down_w, order, inverse, group_sizes)
+def _bounded_fwd(tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes, bound, held):
+    args = (tokens, weight, gates, gate_w, up_w, down_w, order, runs, chosen, group_sizes)
     # no scope of the layer's own round a cond: XLA's grouped kernel takes the path of names round it for its
     # ``op_name``, and is given its phase by its instruction's name only where that path holds none
-    out, saved = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_fwd, k=k, bound=bound),
-                              functools.partial(_full_fwd, k=k, bound=bound), *args)
+    out, saved = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_fwd, bound=bound),
+                              functools.partial(_full_fwd, bound=bound, held=held), *args)
     return out, (saved, *args)
 
 
-def _bounded_bwd(k, bound, residuals, d_out):
+def _bounded_bwd(bound, held, residuals, d_out):
     group_sizes = residuals[-1]
-    grads = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_bwd, k=k, bound=bound),
-                         functools.partial(_full_bwd, k=k, bound=bound), *residuals, d_out)
-    return (*grads, None, None, None)
+    grads = jax.lax.cond(jnp.sum(group_sizes) <= bound, functools.partial(_usual_bwd, bound=bound),
+                         functools.partial(_full_bwd, bound=bound, held=held), *residuals, d_out)
+    return (*grads, None, None, None, None)
 
 
 _bounded_path.defvjp(_bounded_fwd, _bounded_bwd)
@@ -297,7 +373,9 @@ class MoEMLP(nn.Module):
                                 name="router")(tokens.astype(jnp.float32))  # [N, E]
             scores, chosen, gates = route(cfg, logits, bias)
             k = chosen.shape[1]
-            order, inverse, weight, group_sizes = sort_pairs(chosen, gates, (lo, hi))
+            bound = row_bound(n_tok * k, held, e)
+            bounded = bound < n_tok * k
+            order, inverse, weight, group_sizes = sort_pairs(chosen, gates, (lo, hi, bound) if bounded else (lo, hi))
 
         wi_init = nn.initializers.variance_scaling(1.0, "fan_in", "truncated_normal", in_axis=-2, out_axis=-1, batch_axis=0)
         gate_w = self.param("moe/gate_proj", wi_init, (held, d, cfg.mlp_dim), jnp.float32)
@@ -305,11 +383,10 @@ class MoEMLP(nn.Module):
         down_w = self.param("moe/down_proj", wi_init, (held, cfg.mlp_dim, d), jnp.float32)
         with jax.named_scope("moe_experts"):  # cast once a step, for either path
             matrices = gate_w.astype(cfg.dtype), up_w.astype(cfg.dtype), down_w.astype(cfg.dtype)
-        bound = row_bound(n_tok * k, held, e)
-        if bound == n_tok * k:
-            out = _full_path(tokens.astype(cfg.dtype), weight, *matrices, order, inverse, group_sizes, k)
+        if bounded:  # ``inverse`` is the rows' runs in token order
+            out = _bounded_path(tokens.astype(cfg.dtype), weight, gates, *matrices, order, inverse, chosen, group_sizes, bound, (lo, hi))
         else:
-            out = _bounded_path(tokens.astype(cfg.dtype), weight, *matrices, order, inverse, group_sizes, k, bound)
+            out = _full_path(tokens.astype(cfg.dtype), weight, *matrices, order, inverse, group_sizes, k)
 
         with jax.named_scope("moe_route"):
             load = group_sizes.astype(jnp.float32)

@@ -11,7 +11,7 @@ from dmlcloud_tpu.models import moe
 from dmlcloud_tpu.models.moe import (
     MoEConfig, MoEMLP, moe_counters, moe_partition_rules, route, row_bound, sort_pairs, total_aux_loss,
 )
-from dmlcloud_tpu.ops.grouped_matmul import collect, collect_rows, grouped_matmul, spread, spread_rows
+from dmlcloud_tpu.ops.grouped_matmul import collect, collect_rows, grouped_matmul, run_layout, spread, spread_rows
 from dmlcloud_tpu.parallel import mesh as mesh_lib
 from dmlcloud_tpu.utils.profiling import phase_of
 
@@ -153,33 +153,51 @@ BOUNDED = dict(tokens=256, num_experts=8, experts_held=(0, 2))
 PAIRS, BOUND = 2 * B * 256, 512
 
 
-def routed_to(live_pairs):
-    """``route`` with ``live_pairs`` of the 1,024 pairs sent to the held experts 0 and 1, whatever the scores."""
+def routed_as(chosen):
+    """``route`` with the experts ``chosen [N, k]`` whatever the scores, the weights the chosen experts' scores normalised."""
     def fixed(cfg, logits, bias=None):
         scores, _, _ = TRUE_ROUTE(cfg, logits, bias)
-        n = logits.shape[0]
-        first = np.where(np.arange(n) < min(live_pairs, n), np.arange(n) % 2, 2 + np.arange(n) % 6)
-        second = np.where(np.arange(n) < live_pairs - n, 1 - np.arange(n) % 2, 2 + (np.arange(n) + 1) % 6)
-        chosen = jnp.asarray(np.stack([first, second], axis=1), jnp.int32)
         gates = jnp.sum(jax.nn.one_hot(chosen, cfg.num_experts) * scores[:, None, :], axis=-1)
-        return scores, chosen, gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
+        return scores, jnp.asarray(chosen, jnp.int32), gates / (jnp.sum(gates, -1, keepdims=True) + 1e-6)
     return fixed
+
+
+def experts_with(n, k, experts, held, live):
+    """``[n, k]`` experts, ``k`` different ones a token, with ``live`` of the pairs sent to experts in ``held``, spread
+    over tokens drawn at random; ``"one token"``: one token alone sends the held experts all the pairs it can."""
+    rng = np.random.default_rng(0)
+    inside, most = np.arange(*held), min(k, held[1] - held[0])
+    outside = np.setdiff1d(np.arange(experts), inside)
+    if live == "one token":
+        counts = np.where(np.arange(n) == n // 3, most, 0)
+    else:
+        counts = np.bincount(rng.permutation(np.repeat(np.arange(n), most))[:live], minlength=n)
+    return np.stack([rng.permutation(np.concatenate([rng.choice(inside, c, replace=False), rng.choice(outside, k - c, replace=False)]))
+                     for c in counts]).astype(np.int32)
+
+
+def routed_to(live_pairs):
+    """``route`` with ``live_pairs`` of the 1,024 pairs sent to the held experts 0 and 1, whatever the scores."""
+    n = PAIRS // 2
+    first = np.where(np.arange(n) < min(live_pairs, n), np.arange(n) % 2, 2 + np.arange(n) % 6)
+    second = np.where(np.arange(n) < live_pairs - n, 1 - np.arange(n) % 2, 2 + (np.arange(n) + 1) % 6)
+    return routed_as(np.stack([first, second], axis=1))
 
 
 def value_and_grads(fn, variables, x):
     return jax.value_and_grad(lambda v, x: jnp.sum(fn(v, x) ** 2), argnums=(0, 1))(variables, x)
 
 
-def assert_same_as_the_loop(model, variables, x):
-    """Output, and the gradients of every parameter and of the input, against the loop over experts;
-    returns the layer's counters."""
+def assert_same_as_the_loop(model, variables, x, some=True):
+    """Output, and the gradients of every parameter and of the input (none of them all zero, where ``some`` pairs
+    are live), against the loop over experts; returns the layer's counters."""
     (y, stats) = model.apply(variables, x, mutable=["moe_stats"])
     np.testing.assert_allclose(np.asarray(y), np.asarray(by_hand(model.cfg, variables, x)), atol=1e-5)
     _, got = value_and_grads(model.apply, variables, x)
     _, want = value_and_grads(lambda v, x: by_hand(model.cfg, v, x), variables, x)
     for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-4, err_msg=str(path))
-        assert np.abs(np.asarray(w)).sum() > 0, path
+        assert not some or np.abs(np.asarray(w)).sum() > 0, path
     return {name: float(v) for name, v in moe_counters(stats).items()}
 
 
@@ -251,22 +269,44 @@ class TestRowBound:
     @pytest.mark.parametrize("body", ["forward", "backward"])
     def test_the_usual_path_holds_no_array_of_all_pairs_rows_with_a_feature_axis(self, body):
         n, k, f = B * 256, 2, 16
-        group_sizes = jnp.asarray([100, 120], jnp.int32)
-        order = jax.random.permutation(jax.random.PRNGKey(0), n * k).astype(jnp.int32)
-        args = (jnp.ones((n, D)), jnp.ones((n * k,)), jnp.ones((2, D, f)), jnp.ones((2, D, f)), jnp.ones((2, f, D)),
-                order, jnp.argsort(order).astype(jnp.int32), group_sizes)
+        chosen = jnp.asarray(experts_with(n, k, 8, (0, 2), 220))
+        gates = jnp.ones((n, k)) / k
+        order, runs, weight, group_sizes = sort_pairs(chosen, gates, (0, 2, BOUND))
+        args = (jnp.ones((n, D)), weight, gates, jnp.ones((2, D, f)), jnp.ones((2, D, f)), jnp.ones((2, f, D)), order, runs, chosen, group_sizes)
         if body == "forward":
-            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_fwd(*a, k=k, bound=BOUND))(*args)
+            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_fwd(*a, bound=BOUND))(*args)
         else:
             saved = (jnp.ones((BOUND, D)), jnp.ones((BOUND, f)), jnp.ones((BOUND, f)), jnp.ones((BOUND, D)))
-            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_bwd(*a, k=k, bound=BOUND))(saved, *args, jnp.ones((n, D)))
+            jaxpr = jax.make_jaxpr(lambda *a: moe._usual_bwd(*a, bound=BOUND))(saved, *args, jnp.ones((n, D)))
         shapes = shapes_in(jaxpr.jaxpr)
         # index vectors and [N, k] arrays may have N * k entries; nothing may have N * k rows of features
-        assert max(int(np.prod(shape)) for shape in shapes) <= max(n * k, BOUND * f, n * D)
+        assert max(int(np.prod(shape)) for shape in shapes) <= max(n * k, BOUND * f, n * D, int(np.prod(runs.token.shape)) * 128)
         assert not [shape for shape in shapes if len(shape) > 1 and shape[0] == n * k]
-        # and the same check does see the full path's buffers
-        full = jax.make_jaxpr(lambda *a: moe._full_fwd(*a, k=k, bound=BOUND))(*args)
+        # nothing is sorted, and rows are fetched once on the way out and twice on the way back, whatever k is
+        kinds = [eqn.primitive.name for eqn in eqns_in(jaxpr.jaxpr)]
+        assert "sort" not in kinds and "scatter-add" not in kinds and "scatter_add" not in kinds
+        fetches = [eqn for eqn in eqns_in(jaxpr.jaxpr) if eqn.primitive.name == "gather" and eqn.outvars[0].aval.shape[-1] == D]
+        assert len(fetches) == 3 and sorted(eqn.outvars[0].aval.shape[0] for eqn in fetches)[0] == BOUND
+        # and the same checks do see the full path's buffers and its sorts
+        full = jax.make_jaxpr(lambda *a: moe._full_fwd(*a, bound=BOUND, held=(0, 2)))(*args)
         assert (n * k, D) in shapes_in(full.jaxpr)
+        assert [eqn for eqn in eqns_in(full.jaxpr) if eqn.primitive.name == "sort" and eqn.outvars[0].aval.shape == (n * k,)]
+
+    def test_outside_the_rare_path_no_sort_is_as_long_as_the_pairs(self):
+        model, variables, x = make_layer(**BOUNDED)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(model.apply(v, x) ** 2)))(variables)
+
+        def sorts(jaxpr, inside_a_cond=False):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "sort":
+                    yield eqn.outvars[0].aval.shape[0], inside_a_cond
+                for inner in jax.core.jaxprs_in_params(eqn.params):
+                    yield from sorts(inner, inside_a_cond or eqn.primitive.name == "cond")
+
+        found = list(sorts(jaxpr.jaxpr))
+        places = int(np.prod(run_layout(BOUND, 2)[:2]))
+        assert sorted({length for length, inside in found if not inside}) == [places] and places < PAIRS
+        assert {length for length, inside in found if inside} == {PAIRS}  # the full branches sort all the pairs for themselves
 
     @pytest.mark.parametrize("live_pairs", [200, PAIRS - 24], ids=["R-row-buffer", "N*k-row-buffer"])
     def test_rows_past_the_live_ones_are_not_read(self, monkeypatch, live_pairs):
@@ -303,18 +343,69 @@ class TestRowBound:
         for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5, atol=1e-6)
 
-    def test_the_moves_of_the_first_rows_are_each_others_transposes(self):
-        n, k, d, bound = 12, 2, 4, 8
-        order = jax.random.permutation(jax.random.PRNGKey(0), n * k)
-        inverse = jnp.argsort(order)
-        tokens = jax.random.normal(jax.random.PRNGKey(1), (n, d))
-        rows = jax.random.normal(jax.random.PRNGKey(2), (bound, d))
-        np.testing.assert_array_equal(np.asarray(spread_rows(tokens, order, k, bound)),
-                                      np.asarray(spread(tokens, order, inverse, k))[:bound])
-        padded = jnp.concatenate([rows, jnp.zeros((n * k - bound, d))])
-        np.testing.assert_allclose(np.asarray(collect_rows(rows, inverse, k)), np.asarray(collect(padded, order, inverse, k)), rtol=1e-6)
-        np.testing.assert_allclose(float(jnp.vdot(spread_rows(tokens, order, k, bound), rows)),
-                                   float(jnp.vdot(tokens, collect_rows(rows, inverse, k))), rtol=1e-5)
+    @pytest.mark.parametrize("live", [0, "one token", "random", "R", "R + 1"])
+    @pytest.mark.parametrize("length, routing", [(256, dict(top_k=2, num_experts=8, experts_held=(0, 2))),
+                                                 (256, dict(top_k=10, num_experts=256, experts_held=(16, 24))),
+                                                 (1024, dict(top_k=1, num_experts=16, experts_held=(4, 6)))],
+                             ids=["top-2-of-8-a-quarter-held", "top-10-of-256-eight-held", "top-1-of-16-two-held"])
+    def test_the_moves_of_the_first_rows_are_each_others_transposes(self, monkeypatch, length, routing, live):
+        """The index of the first ``R`` rows against the stable sort of all the pairs, the way back against ``collect``
+        on the padded buffer and as the transpose of ``spread_rows``, and the layer round them against the loop over
+        experts: for no live pair, one token's run alone, a random routing, a full buffer, and one pair more (the full path)."""
+        n, k, experts, held, d = B * length, routing["top_k"], routing["num_experts"], routing["experts_held"], 4
+        rows = row_bound(n * k, held[1] - held[0], experts)
+        assert rows == BOUND < n * k
+        if live == "random":
+            chosen = np.asarray(TRUE_ROUTE(MoEConfig(**routing), jax.random.normal(jax.random.PRNGKey(3), (n, experts)))[1])
+            live = int(((chosen >= held[0]) & (chosen < held[1])).sum())
+            assert 0 < live < rows
+        else:
+            chosen = experts_with(n, k, experts, held, {"R": rows, "R + 1": rows + 1}.get(live, live))
+            live = {"one token": min(k, held[1] - held[0]), "R": rows, "R + 1": rows + 1}.get(live, live)
+        gates = jax.random.uniform(jax.random.PRNGKey(4), (n, k), minval=0.1)
+        order_all, inverse_all, weight_all, sizes_all = sort_pairs(jnp.asarray(chosen), gates, held)
+        assert int(sizes_all.sum()) == live
+        if live <= rows:
+            order, runs, weight, sizes = sort_pairs(jnp.asarray(chosen), gates, (*held, rows))
+            np.testing.assert_array_equal(np.asarray(sizes), np.asarray(sizes_all))
+            assert order.shape == weight.shape == (rows,)
+            np.testing.assert_array_equal(np.asarray(order)[:live], np.asarray(order_all)[:live])  # today's stable order
+            np.testing.assert_array_equal(np.asarray(weight)[:live], np.asarray(weight_all)[:live])
+            assert not np.asarray(weight)[live:].any()
+            # a token's rows are neighbours in one chunk of the token-order buffer, and the places name each live row once
+            chunks, tile, heads = run_layout(rows, k)
+            head, token = np.asarray(runs.head), np.asarray(runs.token).reshape(-1)
+            count = ((chosen >= held[0]) & (chosen < held[1])).sum(axis=1)
+            assert runs.token.shape == (chunks, tile) and token[-1] == n  # the last place is empty: what a token with no row reads
+            for t in range(n):
+                if count[t]:
+                    assert head[t] // tile == (head[t] + count[t] - 1) // tile and head[t] % tile < heads
+                    assert (token[head[t]:head[t] + count[t]] == t).all()
+                else:
+                    assert head[t] == chunks * tile - 1
+            assert sorted(np.asarray(runs.row)[token < n].tolist()) == list(range(live)) and (token < n).sum() == live
+            tokens = jax.random.normal(jax.random.PRNGKey(1), (n, d))
+            buffer = jax.random.normal(jax.random.PRNGKey(2), (rows, d))
+            alive = (jnp.arange(rows) < live)[:, None]
+            np.testing.assert_array_equal(np.asarray(spread_rows(tokens, order, k, rows))[:live],
+                                          np.asarray(spread(tokens, order_all, inverse_all, k))[:live])
+            padded = jnp.concatenate([jnp.where(alive, buffer, 0), jnp.zeros((n * k - rows, d))])
+            back = collect_rows(jnp.where(alive, buffer, jnp.nan), runs)  # what lies past the live rows is not read
+            np.testing.assert_allclose(np.asarray(back), np.asarray(collect(padded, order_all, inverse_all, k)), rtol=1e-6, atol=1e-6)
+            np.testing.assert_allclose(float(jnp.vdot(jnp.where(alive, spread_rows(tokens, order, k, rows), 0), jnp.where(alive, buffer, 0))),
+                                       float(jnp.vdot(tokens, back)), rtol=1e-5, atol=1e-5)
+        model, variables, x = make_layer(tokens=length, **routing)
+        monkeypatch.setattr(moe, "route", routed_as(chosen))
+        monkeypatch.setitem(globals(), "route", routed_as(chosen))  # by_hand routes the same way
+        counters = assert_same_as_the_loop(model, variables, x, some=live > 0)
+        assert counters["moe/pairs_held"] == live and counters["moe/overflow_layers"] == (live > rows)
+
+    @pytest.mark.parametrize("rows, longest, want", [(5120, 10, (44, 128, 119)), (8192, 4, (66, 128, 125)), (512, 2, (5, 128, 127)),
+                                                      (512, 1, (5, 128, 127)), (4096, 100, (27, 256, 157))])
+    def test_the_token_order_buffer_is_cut_into_chunks_no_run_crosses(self, rows, longest, want):
+        chunks, tile, heads = run_layout(rows, longest)
+        assert (chunks, tile, heads) == want and heads + max(longest, 2) - 1 == tile
+        assert (chunks - 1) * heads + tile - 1 >= rows  # the slot the last place stands for is never a live row's
 
 
 class TestSortedPairs:

@@ -190,24 +190,29 @@ def test_int8_training_dot_compiles_for_v5e(v5e, monkeypatch):
     assert "s8[" in hlo  # the quantized kernel really is int8 in the program
 
 
-def test_a_bounded_expert_layer_writes_no_array_of_all_pairs_rows_on_its_usual_path(v5e):
-    """One expert layer of ``lfm2-train-8k`` (8 of 64 experts held, 8192 tokens, top-4), forward and
-    backward: the grouped products are the TPU's own kernel, each direction is one conditional, and the
-    branch taken when the live rows fit the row bound holds no ``[32768, features]`` array."""
+@pytest.mark.parametrize("n, k, d, f, held, experts, bound, overrides", [
+    (8192, 4, 2048, 1536, 8, 64, 8192, dict(use_expert_bias=True)),
+    (8192, 10, 3072, 1024, 8, 256, 5120, dict(scoring_func="softmax", routed_scaling_factor=2.5)),
+], ids=["lfm2-train-8k", "laguna-train-8k"])
+def test_a_bounded_expert_layer_writes_no_array_of_all_pairs_rows_on_its_usual_path(v5e, n, k, d, f, held, experts, bound, overrides):
+    """One expert layer of ``lfm2-train-8k`` (8 of 64 experts held, 8192 tokens, top-4 by sigmoid) and of
+    ``laguna-train-8k`` (8 of 256, top-10 by softmax), forward and backward: the grouped products are the
+    TPU's own kernel, each direction is one conditional, and the branch taken when the live rows fit the row
+    bound holds no ``[N * k, features]`` array and sorts nothing; outside the conditionals no sort is as long
+    as the ``N * k`` pairs either (the router's top-k sorts rows of ``experts``)."""
     import re
 
     from dmlcloud_tpu.models.moe import MoEConfig, MoEMLP, row_bound
     from dmlcloud_tpu.utils.profiling import phase_map
 
-    n, k, d, f, held, experts = 8192, 4, 2048, 1536, 8, 64
-    assert row_bound(n * k, held, experts) == 8192
-    model = MoEMLP(MoEConfig(num_experts=experts, top_k=k, hidden_dim=d, mlp_dim=f, use_expert_bias=True, experts_held=(0, held)))
+    assert row_bound(n * k, held, experts) == bound
+    model = MoEMLP(MoEConfig(num_experts=experts, top_k=k, hidden_dim=d, mlp_dim=f, experts_held=(0, held), **overrides))
     one_chip = SingleDeviceSharding(v5e.devices[0])
     x = jax.ShapeDtypeStruct((1, n, d), jnp.bfloat16, sharding=one_chip)
     variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)
     variables = jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), variables)
     loss = lambda v, x: model.apply(v, x).astype(jnp.float32).sum()
-    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), {"params": variables["params"], "buffers": variables["buffers"]}, x)
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1)), {name: variables[name] for name in variables if name in ("params", "buffers")}, x)
     # XLA's grouped kernel carries the names round it and none of the layer's own: its phase goes by its
     # instruction's name (``_KERNEL_PHASES``), which a scope of the layer's round the conditional would override
     kernels = {name: phase for name, (phase, _) in phase_map(text).items() if name.startswith("ragged-dot")}
@@ -228,10 +233,15 @@ def test_a_bounded_expert_layer_writes_no_array_of_all_pairs_rows_on_its_usual_p
         return seen
 
     wide = re.compile(rf"= \(?\w+\[{n * k},(?:{d}|{f})\]")
+    long_sort = re.compile(rf"= \(?\w+\[{n * k}\][^\n]* sort\(")
+    inside = set()
     for first, second, true, false in conditionals:
         full, usual = (first, second) if first else (false, true)  # branch 0 is the false one: the full path
-        assert not [c for c in reach(usual, set()) if wide.search(computations[c])]
-        assert [c for c in reach(full, set()) if wide.search(computations[c])]  # the pattern does see such arrays
+        assert not [c for c in reach(usual, set()) if wide.search(computations[c]) or " sort(" in computations[c]]
+        assert [c for c in reach(full, set()) if wide.search(computations[c])]  # the patterns do see such arrays
+        inside |= reach(full, set())
+    assert [c for c in inside if long_sort.search(computations[c])]  # the rare path sorts all the pairs for itself
+    assert not [c for c in set(computations) - inside if long_sort.search(computations[c])]
 
 
 @pytest.mark.parametrize("layout", ["one_chip", "vocab_over_model"])
