@@ -50,8 +50,9 @@ def transformer_config_from_hf(hf_config: Any, **overrides) -> TransformerConfig
     ``Lfm2MoeConfig`` (``model_type`` ``lfm2_moe``: per-layer operators from
     ``layer_types``, RMSNorm over the head dimension of q and k, sigmoid-scored
     experts with a selection bias after ``num_dense_layers`` dense layers) or a
-    ``laguna`` config (:func:`_laguna_keys`). ``experts_held`` is no published
-    key: pass it as an override for one chip's share of the experts."""
+    ``laguna`` config (:func:`_laguna_keys`) or a ``granitemoehybrid`` config
+    (:func:`_granite_keys`). ``experts_held`` is no published key: pass it as an
+    override for one chip's share of the experts."""
     get = lambda key, default=None: getattr(hf_config, key, default)
     rope = get("rope_parameters") or {}
     scaling = get("rope_scaling") or (rope if rope.get("rope_type", "default") != "default" else None)
@@ -90,6 +91,8 @@ def transformer_config_from_hf(hf_config: Any, **overrides) -> TransformerConfig
         )
     if get("model_type") == "laguna":
         base.update(_laguna_keys(hf_config))
+    if get("model_type") == "granitemoehybrid":
+        base.update(_granite_keys(hf_config))
     base.update(overrides)
     return TransformerConfig(**base)
 
@@ -135,6 +138,111 @@ def _laguna_keys(hf_config: Any) -> dict:
         norm_topk_prob=bool(get("norm_topk_prob", True)),
         routed_scaling_factor=float(get("moe_routed_scaling_factor", 1.0)),
     )
+
+
+def _granite_keys(hf_config: Any) -> dict:
+    """The ``granitemoehybrid`` family (IBM Granite 4.0-H) without experts:
+    ``mamba`` layers (Mamba-2 mixers, the ``mamba_*`` keys) beside ``attention``
+    layers (the program's ``full_attention``) that may carry no position signal
+    (``position_embedding_type`` ``nope``) and scale their scores by
+    ``attention_multiplier``; one SwiGLU of ``shared_intermediate_size`` in every
+    block; ``embedding_multiplier``, ``residual_multiplier``, ``logits_scaling``.
+    What this model cannot honour is refused: experts (``num_local_experts`` >
+    0, a sparse layer beside the shared MLP), biases on the projections, a conv
+    without its bias, another norm or activation. ``mamba_expand`` is not read:
+    the mixer's inner width is ``mamba_n_heads * mamba_d_head``, which is the
+    published ``mamba_expand * hidden_size`` for the whole model and less for a
+    share of its heads."""
+    get = lambda key, default=None: getattr(hf_config, key, default)
+    if int(get("num_local_experts") or 0) > 0:
+        raise ValueError(f"num_local_experts = {get('num_local_experts')}: this model has no expert layer beside the shared MLP "
+                         "(granitemoehybrid's sparse block is not supported)")
+    if get("attention_bias", False) or get("mamba_proj_bias", False) or not get("mamba_conv_bias", True):
+        raise ValueError("attention_bias, mamba_proj_bias and a conv without bias (mamba_conv_bias false) are not supported")
+    if get("normalization_function", "rmsnorm") != "rmsnorm" or get("hidden_act", "silu") != "silu":
+        raise ValueError(f"only rmsnorm and silu are supported, got {get('normalization_function')!r} / {get('hidden_act')!r}")
+    position = get("position_embedding_type", "rope")
+    if position not in ("rope", "nope"):
+        raise ValueError(f"position_embedding_type {position!r} is not supported (rope, nope)")
+    kinds = {"attention": "full_attention", "mamba": "mamba"}
+    unknown = sorted(set(hf_config.layer_types) - set(kinds))
+    if unknown:
+        raise ValueError(f"layer_types holds {unknown}; a granitemoehybrid config names {sorted(kinds)}")
+    heads, d_head = int(hf_config.mamba_n_heads), int(hf_config.mamba_d_head)
+    return dict(
+        layer_types=tuple(kinds[k] for k in hf_config.layer_types),
+        mlp_dim=int(get("shared_intermediate_size") or hf_config.intermediate_size),
+        tie_embeddings=bool(get("tie_word_embeddings", True)),
+        position_embedding=position,
+        attention_multiplier=None if get("attention_multiplier") is None else float(hf_config.attention_multiplier),
+        embedding_multiplier=float(get("embedding_multiplier") or 1.0),
+        residual_multiplier=float(get("residual_multiplier") or 1.0),
+        logits_scaling=float(get("logits_scaling") or 1.0),
+        mamba_n_heads=heads, mamba_d_head=d_head, mamba_d_state=int(hf_config.mamba_d_state),
+        mamba_n_groups=int(get("mamba_n_groups") or 1), mamba_d_conv=int(get("mamba_d_conv") or 4),
+        mamba_chunk_size=int(get("mamba_chunk_size") or 256),
+    )
+
+
+def granite_params_from_hf(state_dict: Mapping[str, Any], cfg: TransformerConfig, dtype=jnp.float32):
+    """A ``GraniteMoeHybridForCausalLM`` state dict (no experts) as this model's
+    params. Beyond the transposes: ``shared_mlp.input_linear`` is the gate's and
+    the up projection's rows one after the other and is split into ``gate_proj``
+    and ``up_proj``; ``mamba.conv1d.weight [C, 1, K]`` becomes ``[K, C]``; q and
+    k keep their columns (no rotary pairs to interleave where nothing rotates;
+    with ``position_embedding`` ``rope`` they are permuted as a Llama's are)."""
+    sd = dict(state_dict)
+    hd, hid = cfg.head_dim, cfg.hidden_dim
+
+    def take(key: str) -> np.ndarray:
+        if key not in sd:
+            raise KeyError(f"HF state dict is missing {key!r}")
+        return _np(sd.pop(key))
+
+    def heads_kernel(key: str, heads: int, rope: bool) -> np.ndarray:
+        w = take(key).reshape(heads, hd, hid)
+        if rope and cfg.position_embedding == "rope":
+            w = _interleave_rope_rows(w.transpose(0, 2, 1)).transpose(0, 2, 1)
+        return np.ascontiguousarray(w.transpose(2, 0, 1))
+
+    params: dict[str, Any] = {"embed": {"embedding": take("model.embed_tokens.weight")},
+                              "final_norm": {"scale": take("model.norm.weight")}}
+    lm_head = sd.pop("lm_head.weight", None)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {"kernel": _np(params["embed"]["embedding"] if lm_head is None else lm_head).T}
+    for i in range(cfg.num_layers):
+        p = f"model.layers.{i}."
+        gate_up = take(p + "shared_mlp.input_linear.weight")  # [2 f, hid]: silu(first half) * second half
+        layer = {
+            "mlp_norm": {"scale": take(p + "post_attention_layernorm.weight")},
+            "mlp": {"gate_proj": {"kernel": gate_up[: cfg.mlp_dim].T}, "up_proj": {"kernel": gate_up[cfg.mlp_dim:].T},
+                    "down_proj": {"kernel": take(p + "shared_mlp.output_linear.weight").T}},
+        }
+        if cfg.layer_kind(i) == "mamba":
+            layer["mamba_norm"] = {"scale": take(p + "input_layernorm.weight")}
+            layer["mamba"] = {
+                "in_proj": {"kernel": take(p + "mamba.in_proj.weight").T},
+                "conv_weight": take(p + "mamba.conv1d.weight")[:, 0, :].T, "conv_bias": take(p + "mamba.conv1d.bias"),
+                "A_log": take(p + "mamba.A_log"), "dt_bias": take(p + "mamba.dt_bias"), "D": take(p + "mamba.D"),
+                "norm_scale": take(p + "mamba.norm.weight"), "out_proj": {"kernel": take(p + "mamba.out_proj.weight").T},
+            }
+        else:
+            heads = cfg.attention_layer(i).num_heads
+            layer["attn_norm"] = {"scale": take(p + "input_layernorm.weight")}
+            layer["attn"] = {
+                "q_proj": {"kernel": heads_kernel(p + "self_attn.q_proj.weight", heads, rope=True)},
+                "k_proj": {"kernel": heads_kernel(p + "self_attn.k_proj.weight", cfg.kv_heads, rope=True)},
+                "v_proj": {"kernel": heads_kernel(p + "self_attn.v_proj.weight", cfg.kv_heads, rope=False)},
+                "o_proj": {"kernel": take(p + "self_attn.o_proj.weight").T},
+            }
+        params[f"layer_{i}"] = layer
+    leftovers = [k for k in sd if "rotary_emb" not in k]
+    if leftovers:
+        raise ValueError(f"unconverted HF weights: {leftovers[:8]}{'...' if len(leftovers) > 8 else ''}")
+
+    import jax
+
+    return jax.tree_util.tree_map(lambda x: jnp.asarray(x, dtype), params)
 
 
 def _rope_scaling_from_hf(rs: Any) -> tuple | None:

@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 
 #: the operators a block can have, by the names published configs give them
-LAYER_KINDS = ("full_attention", "sliding_attention", "conv")
+LAYER_KINDS = ("full_attention", "sliding_attention", "conv", "mamba")
 
 
 class LayerAttention(NamedTuple):
@@ -85,9 +85,37 @@ class TransformerConfig:
     # The operator of each block, by the published names: "full_attention" and
     # "sliding_attention" (Attention, without and with the window) or "conv"
     # (ShortConv, the gated short convolution of the LFM2 family, kernel length
-    # ``conv_L_cache``). None = attention everywhere.
+    # ``conv_L_cache``) or "mamba" (Mamba2Mixer, the state-space mixer of the
+    # granitemoehybrid family). None = attention everywhere.
     layer_types: tuple[str, ...] | None = None
     conv_L_cache: int = 3
+    # The "mamba" layers, under their published names: heads of ``mamba_d_head``
+    # channels (``d_inner`` is their product: a share of the heads needs no other
+    # code), each with a state ``mamba_d_head x mamba_d_state``; ``mamba_n_groups``
+    # groups of heads share B and C; a causal depthwise conv of ``mamba_d_conv``
+    # taps; ``mamba_chunk_size`` tokens to a chunk of the scan (ops/ssd.py).
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_d_state: int = 128
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    # "rope": q and k rotate by position. "nope": no position signal at all in
+    # attention (published as position_embedding_type; the mamba layers order the tokens).
+    position_embedding: str = "rope"
+    # attention's scores times this in place of 1/sqrt(head_dim); None = 1/sqrt(head_dim)
+    attention_multiplier: float | None = None
+    # h = embed(ids) * embedding_multiplier; every residual branch times
+    # residual_multiplier before it is added; logits / logits_scaling. 1 = not there.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # Mesh axis over which each layer's heads are shared when the step runs under
+    # shard_map (or vmap) with explicit collectives: the operator's partial result
+    # (o_proj's, out_proj's) is summed over it, and so are the sum of squares and the
+    # channel count of the mamba layer's gated norm. None on one chip, and under plain
+    # jit, where XLA places the sums the partition rules imply.
+    heads_axis: str | None = None
     # MoE (models/moe.py): with ``num_experts`` > 0 every block after the first
     # ``num_dense_layers`` has the dropless expert layer in the dense MLP's
     # place. ``num_experts`` is the router's width; ``experts_held = (a, b)``
@@ -146,6 +174,11 @@ class TransformerConfig:
                 raise ValueError(f"num_heads_per_layer {self.num_heads_per_layer} are not all multiples of {self.kv_heads} KV heads")
         if self.gating not in (None, "per-head"):
             raise ValueError(f"gating must be None or 'per-head', got {self.gating!r}")
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(f"position_embedding must be 'rope' or 'nope', got {self.position_embedding!r}")
+        if "mamba" in (self.layer_types or ()) and (self.mamba_n_heads < 1 or self.mamba_n_heads % self.mamba_n_groups):
+            raise ValueError(f"layer_types names mamba layers and mamba_n_heads is {self.mamba_n_heads} "
+                             f"({self.mamba_n_groups} groups)")
 
     @property
     def kv_heads(self) -> int:
@@ -170,7 +203,7 @@ class TransformerConfig:
     def require_attention_only(self, what: str) -> None:
         """Decoding keeps keys and values per sequence and nothing else, in one
         page shape and under one window for the whole model: a layer kind with
-        state of another sort (ROADMAP M5), or with a window of its own beside
+        state of another sort (``conv``, ``mamba``: ROADMAP M5), or with a window of its own beside
         layers without (ROADMAP M2), cannot be served yet."""
         other = sorted({k for k in self.layer_types or () if k != "full_attention"})
         if other:
@@ -200,6 +233,8 @@ def llama_partition_rules() -> list[tuple[str, P]]:
         ("lm_head/kernel", P("fsdp", "model")),
         ("conv/in_proj/kernel", P("fsdp", "model")),
         ("conv/out_proj/kernel", P("model", "fsdp")),
+        ("mamba/in_proj/kernel", P("fsdp", "model")),
+        ("mamba/out_proj/kernel", P("model", "fsdp")),
         ("norm", P()),
         (".*", P()),
     ]
@@ -303,17 +338,18 @@ def _window_keep(q_pos, k_pos, window: int) -> jnp.ndarray:
 
 
 @jax.named_scope("attention")
-def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None):
+def _dot_attention(q, k, v, causal: bool = True, mask: jnp.ndarray | None = None, sm_scale: float | None = None):
     """Reference attention: fp32 softmax, bf16 matmuls. q:[B,T,H,D] k/v:[B,S,K,D].
     ``mask`` ([T, S] or [B, T, S] bool, True = attend) REPLACES the causal
     triangle entirely — callers must bake causality into it (the decode path
     does for unwritten KV-cache slots, packed training for segment
-    isolation)."""
+    isolation). ``sm_scale`` multiplies the scores in place of ``1/sqrt(D)``."""
     b, t, h, d = q.shape
     s, kh = k.shape[1], k.shape[2]
     group = h // kh
     q = q.reshape(b, t, kh, group, d)
-    scores = jnp.einsum("btkgd,bskd->bkgts", q, k).astype(jnp.float32) / jnp.sqrt(d)
+    scores = jnp.einsum("btkgd,bskd->bkgts", q, k).astype(jnp.float32)
+    scores = scores / jnp.sqrt(d) if sm_scale is None else scores * sm_scale
     if mask is None and causal:
         mask = jnp.tril(jnp.ones((t, s), dtype=bool), k=s - t)
     if mask is not None:
@@ -331,7 +367,7 @@ def _flash_attention(cfg: TransformerConfig, layer: LayerAttention, q, k, v, seg
     ``sliding_attention`` layer run under a phase of their own."""
     from ..ops.flash_attention import flash_attention, flash_attention_sharded
 
-    kwargs = dict(causal=True, window=layer.window, segment_ids=segment_ids)
+    kwargs = dict(causal=True, window=layer.window, segment_ids=segment_ids, sm_scale=cfg.attention_multiplier)
     with jax.named_scope("attn_window_kernel" if layer.kind == "sliding_attention" else "attn_kernel"):
         if cfg.mesh is not None and cfg.mesh.size > 1:
             return flash_attention_sharded(q, k, v, cfg.mesh, **kwargs)
@@ -387,21 +423,24 @@ class Attention(nn.Module):
             q = RMSNorm(eps=cfg.norm_eps, name="q_norm")(q)
             k = RMSNorm(eps=cfg.norm_eps, name="k_norm")(k)
 
+        # "nope": q and k go as they are, whatever the branch
+        rotate = (lambda a, **at: apply_rope(a, cos, sin, **at)) if cfg.position_embedding == "rope" else (lambda a, **at: a)
+        scale = cfg.attention_multiplier
         if seg_info is None and decode_pad is None and paged is None:
-            q = apply_rope(q, cos, sin, offset=offset)
-            k = apply_rope(k, cos, sin, offset=offset)
+            q = rotate(q, offset=offset)
+            k = rotate(k, offset=offset)
         elif paged is not None:
             # paged decode: every row sits at its own absolute position
             # (fill + step offset) — precomputed once in DecoderLM
             _, _, positions = paged
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
+            q = rotate(q, positions=positions)
+            k = rotate(k, positions=positions)
         elif decode_pad is not None:
             # left-padded ragged prompts: per-row positions (real tokens
             # count from 0 at each row's first real slot)
             _, positions = decode_pad
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
+            q = rotate(q, positions=positions)
+            k = rotate(k, positions=positions)
 
         new_cache = None
         if seg_info is not None:
@@ -410,12 +449,12 @@ class Attention(nn.Module):
             # is causal AND same-segment (the flash kernel takes the raw ids,
             # the dot path the precomputed mask).
             positions, mask, seg_ids = seg_info
-            q = apply_rope(q, cos, sin, positions=positions)
-            k = apply_rope(k, cos, sin, positions=positions)
+            q = rotate(q, positions=positions)
+            k = rotate(k, positions=positions)
             if cfg.attn_impl == "flash":
                 out = _flash_attention(cfg, layer, q, k, v, segment_ids=seg_ids)
             else:
-                out = _dot_attention(q, k, v, mask=mask)
+                out = _dot_attention(q, k, v, mask=mask, sm_scale=scale)
         elif paged is not None:
             # Paged decode (the serving engine's path): the cache leaves
             # are the POOL pages [num_blocks, block_size, KH, D]. Write the
@@ -438,7 +477,7 @@ class Attention(nn.Module):
             mask = kv_pos <= q_pos  # causal AND only this row's filled slots
             if window is not None:
                 mask = mask & _window_keep(q_pos, kv_pos, window)
-            out = _dot_attention(q, gk, gv, mask=mask)
+            out = _dot_attention(q, gk, gv, mask=mask, sm_scale=scale)
         elif cache is not None:
             # Autoregressive decode: write this call's K/V into the static-
             # shape cache at ``offset`` and attend over the FILLED prefix
@@ -464,7 +503,7 @@ class Attention(nn.Module):
                 # left-pad slots hold garbage K/V — mask them per row
                 pad_len, _ = decode_pad
                 mask = mask[None] & (kv_pos[None] >= pad_len[:, None, None])
-            out = _dot_attention(q, k, v, mask=mask)
+            out = _dot_attention(q, k, v, mask=mask, sm_scale=scale)
         elif cfg.attn_impl == "flash":
             out = _flash_attention(cfg, layer, q, k, v)
         elif cfg.attn_impl == "ring":
@@ -473,22 +512,22 @@ class Attention(nn.Module):
                     from ..ops.ring_attention import ring_attention_sharded
 
                     out = ring_attention_sharded(
-                        q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=window
+                        q, k, v, cfg.mesh, axis_name=cfg.seq_axis, causal=True, window=window, sm_scale=scale
                     )
                 else:
                     from ..ops.ring_attention import ring_attention
 
                     out = ring_attention(
-                        q, k, v, axis_name=cfg.seq_axis, causal=True, window=window
+                        q, k, v, axis_name=cfg.seq_axis, causal=True, window=window, sm_scale=scale
                     )
         elif window is not None:
             pos = jnp.arange(t)
             q_pos, k_pos = pos[:, None], pos[None, :]
             out = _dot_attention(
-                q, k, v, mask=(q_pos >= k_pos) & _window_keep(q_pos, k_pos, window)
+                q, k, v, mask=(q_pos >= k_pos) & _window_keep(q_pos, k_pos, window), sm_scale=scale
             )
         else:
-            out = _dot_attention(q, k, v, causal=True)
+            out = _dot_attention(q, k, v, causal=True, sm_scale=scale)
 
         if cfg.gating == "per-head":
             with jax.named_scope("attn_gate"):
@@ -548,6 +587,91 @@ class ShortConv(nn.Module):
             return dense(d, "out_proj")(c_gate * z)
 
 
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 mixer as the ``granitemoehybrid`` family runs it, on ``H =
+    mamba_n_heads`` heads of ``P = mamba_d_head`` channels (``d_inner = H P``),
+    ``G`` groups, a state of ``N = mamba_d_state``:
+
+    ``[z | x | B | C | dt] = u W_in`` (widths ``d_inner, d_inner, G N, G N, H``);
+    ``[x | B | C] = silu(conv1d([x | B | C]) + b)``, depthwise, causal, zeros
+    before the sequence; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``;
+    ``y`` by the state-space scan (``ops/ssd.py``: ``S_t = exp(dt_t A_h) S_{t-1}
+    + dt_t x_t B_t^T``, ``y_t = S_t C_t + D_h x_t``); the gated norm ``g = y *
+    silu(z)``, ``g * rsqrt(mean(g^2) + eps) * w`` over all of ``d_inner`` at
+    once; ``out = g W_out``. No bias but the conv's.
+
+    With ``cfg.heads_axis`` the heads held here are a share of the layer's: the
+    norm's mean is then over every holder's channels (one ``psum`` of the sum of
+    squares and of the count), and the caller sums ``out`` over the holders.
+    Four phases of the profile: ``ssm_proj``, ``ssm_conv``, ``ssm_scan``,
+    ``ssm_gate_norm``. Sows ``ssm_stats/state_absmax``, the largest magnitude
+    of a state carried from chunk to chunk (:func:`ssm_counters`)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from ..ops.ssd import ssd_chunked
+        from .quant import QuantDense
+
+        cfg = self.cfg
+        h, p, n, g, taps = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state, cfg.mamba_n_groups, cfg.mamba_d_conv
+        d_inner, f32 = h * p, jnp.float32
+        conv_dim = d_inner + 2 * g * n
+        b, t, _ = u.shape
+        dense = lambda feats, name: QuantDense(feats, use_bias=False, dtype=cfg.dtype, param_dtype=f32, name=name)
+        with jax.named_scope("ssm_proj"):
+            z, xbc, dt = jnp.split(dense(d_inner + conv_dim + h, "in_proj")(u), [d_inner, d_inner + conv_dim], axis=-1)
+        with jax.named_scope("ssm_conv"):
+            w = self.param("conv_weight", nn.initializers.normal(taps**-0.5), (taps, conv_dim), f32).astype(cfg.dtype)
+            bias = self.param("conv_bias", nn.initializers.zeros_init(), (conv_dim,), f32).astype(cfg.dtype)
+            padded = jnp.pad(xbc, ((0, 0), (taps - 1, 0), (0, 0)))
+            xbc = nn.silu(sum(w[j] * jax.lax.slice_in_dim(padded, j, j + t, axis=1) for j in range(taps)) + bias)
+            x, b_in, c_in = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+        with jax.named_scope("ssm_scan"):
+            # the published initialiser's ranges: decays neither 0 nor 1, steps of 1e-3 to 1e-1
+            a_log = self.param("A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0)), (h,))
+            dt_bias = self.param("dt_bias", _inverse_softplus_log_uniform(1e-3, 1e-1), (h,))
+            skip = self.param("D", nn.initializers.ones_init(), (h,), f32)
+            # a row shorter than a chunk (``init``'s example input) is one chunk of its own length
+            y, carried = ssd_chunked(
+                x.reshape(b, t, h, p), jax.nn.softplus(dt.astype(f32) + dt_bias), -jnp.exp(a_log),
+                b_in.reshape(b, t, g, n), c_in.reshape(b, t, g, n), skip, min(cfg.mamba_chunk_size, t), return_carry=True)
+            self.sow("ssm_stats", "state_absmax", jnp.max(jnp.abs(jax.lax.stop_gradient(carried))),
+                     init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.maximum)
+        with jax.named_scope("ssm_gate_norm"):
+            scale = self.param("norm_scale", nn.initializers.ones_init(), (d_inner,), f32)
+            gated = y.reshape(b, t, d_inner).astype(f32) * nn.silu(z.astype(f32))
+            squares, channels = jnp.sum(gated * gated, axis=-1, keepdims=True), d_inner
+            if cfg.heads_axis is not None:
+                squares, channels = jax.lax.psum(squares, cfg.heads_axis), jax.lax.psum(channels, cfg.heads_axis)
+            gated = (gated * jax.lax.rsqrt(squares / channels + cfg.norm_eps) * scale).astype(cfg.dtype)
+        with jax.named_scope("ssm_proj"):
+            return dense(cfg.hidden_dim, "out_proj")(gated)
+
+
+def _inverse_softplus_log_uniform(low: float, high: float):
+    """An initialiser: ``x`` with ``softplus(x)`` log-uniform in ``[low, high]``."""
+
+    def init(key, shape):
+        step = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(low), math.log(high)))
+        return step + jnp.log(-jnp.expm1(-step))
+
+    return init
+
+
+def ssm_counters(variables: Any) -> dict:
+    """``{"ssm/state_absmax"}`` of one forward, from the variables a
+    ``mutable=["ssm_stats"]`` apply returned: the largest magnitude of a state
+    the scan carried from one chunk to the next, over the model's mamba layers
+    (what a carry in fewer bits, or a kernel, has to hold). A device scalar: a
+    train step returns it beside its loss and the tracker fetches it with it.
+    Empty for a model without such layers."""
+    stats = variables.get("ssm_stats", {}) if isinstance(variables, dict) else {}
+    peaks = jax.tree_util.tree_leaves(stats)
+    return {"ssm/state_absmax": jnp.max(jnp.stack(peaks))} if peaks else {}
+
+
 class DecoderBlock(nn.Module):
     """``h = x + Op(norm(x))``, ``y = h + FFN(norm(h))``. ``kind`` names the
     operator (``LAYER_KINDS``), which owns its projections and its call;
@@ -574,20 +698,25 @@ class DecoderBlock(nn.Module):
             mlp_ad = ((sub or {}).get("mlp"), ids)
         norm = lambda name: RMSNorm(eps=cfg.norm_eps, name=name)
         new_cache = None
-        if self.kind == "conv":
+        if self.kind in ("conv", "mamba"):
             if cache is not None or seg_info is not None or paged is not None or attn_ad is not None:
-                raise NotImplementedError("a 'conv' layer takes no cache, packed rows, pages or attention adapters")
-            x = x + ShortConv(cfg, name="conv")(norm("conv_norm")(x))
+                raise NotImplementedError(f"a {self.kind!r} layer takes no cache, packed rows, pages or attention adapters")
+            if self.kind == "conv":
+                out = ShortConv(cfg, name="conv")(norm("conv_norm")(x))
+            else:
+                out = Mamba2Mixer(cfg, name="mamba")(norm("mamba_norm")(x))
         elif cache is not None:
-            attn_out, new_cache = Attention(cfg, self.attention, name="attn")(
+            out, new_cache = Attention(cfg, self.attention, name="attn")(
                 norm("attn_norm")(x), cos, sin, cache=cache, offset=offset,
                 decode_pad=decode_pad, attend_len=attend_len, paged=paged, adapters=attn_ad,
             )
-            x = x + attn_out
         else:
-            x = x + Attention(cfg, self.attention, name="attn")(
+            out = Attention(cfg, self.attention, name="attn")(
                 norm("attn_norm")(x), cos, sin, seg_info=seg_info, adapters=attn_ad
             )
+        if cfg.heads_axis is not None:  # the heads held here gave a partial result
+            out = jax.lax.psum(out, cfg.heads_axis)
+        x = x + _branch(cfg, out)
         if self.use_moe:
             from .moe import MoEConfig, MoEMLP
 
@@ -606,10 +735,20 @@ class DecoderBlock(nn.Module):
             )
             # MoE blocks carry no per-request adapters (expert routing and
             # LoRA-per-tenant compose poorly; dense layers cover serving)
-            x = x + MoEMLP(moe_cfg, name="moe")(norm("mlp_norm")(x))
+            x = x + _branch(cfg, MoEMLP(moe_cfg, name="moe")(norm("mlp_norm")(x)))
         else:
-            x = x + MLP(cfg, name="mlp")(norm("mlp_norm")(x), adapters=mlp_ad)
+            x = x + _branch(cfg, MLP(cfg, name="mlp")(norm("mlp_norm")(x), adapters=mlp_ad))
         return x if new_cache is None else (x, new_cache)
+
+
+def _scaled(x, factor: float):
+    """``x * factor`` with the factor in float32: 0.22 held in bfloat16 is 0.1 % off."""
+    return x if factor == 1.0 else (x.astype(jnp.float32) * factor).astype(x.dtype)
+
+
+def _branch(cfg: TransformerConfig, out):
+    """A residual branch as it is added to the stream (``residual_multiplier``)."""
+    return _scaled(out, cfg.residual_multiplier)
 
 
 class DecoderLM(nn.Module):
@@ -690,9 +829,11 @@ class DecoderLM(nn.Module):
         x = nn.Embed(
             cfg.vocab_size, cfg.hidden_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="embed"
         )(tokens)
+        x = _scaled(x, cfg.embedding_multiplier)
         # one rotary table, unless ``rope_parameters`` gives the layer kinds their own
         layers = [cfg.attention_layer(i) for i in range(cfg.num_layers)]
-        tables = {rope: rope_frequencies(cfg.head_dim, cfg.max_seq_len, *rope) for rope in dict.fromkeys(a.rope for a in layers)}
+        rotary = lambda rope: rope_frequencies(cfg.head_dim, cfg.max_seq_len, *rope) if cfg.position_embedding == "rope" else (None, None)
+        tables = {rope: rotary(rope) for rope in dict.fromkeys(a.rope for a in layers)}
 
         def constrain(x):
             if cfg.act_sharding is None:
@@ -728,7 +869,9 @@ class DecoderLM(nn.Module):
                     )
                 )
 
-        x = RMSNorm(eps=cfg.norm_eps, name="final_norm")(x)
+        # published as logits / logits_scaling: dividing the head's input is the same product,
+        # and leaves lm_loss's hand-written backward as it is
+        x = _scaled(RMSNorm(eps=cfg.norm_eps, name="final_norm")(x), 1.0 / cfg.logits_scaling)
         if return_hidden and new_cache is None:
             # the chunked-vocab loss path (chunked_lm_loss) consumes the
             # final hidden states directly and never materializes logits

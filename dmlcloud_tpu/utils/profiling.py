@@ -208,9 +208,13 @@ def chip_peak_flops(device=None) -> float:
 #: the grouped products; ``moe_shared``: the shared expert; ``conv_op``: the whole
 #: operator). ``attn_window_kernel`` is ``attn_kernel`` for the kernels of a
 #: ``sliding_attention`` layer, ``attn_gate`` the per-head gate on attention's output.
+#: The Mamba-2 mixer opens four: ``ssm_proj`` (its two projections), ``ssm_conv``
+#: (depthwise conv, bias, silu, the split), ``ssm_scan`` (softplus, the running sums
+#: and exponentials, the chunk products, the carry, ``D x``), ``ssm_gate_norm``.
 PHASES = (
     "embed", "norm", "attn_proj", "attn_kernel", "attn_window_kernel", "attn_gate", "kv_write", "kv_gather", "attention",
-    "mlp", "conv_op", "moe_route", "moe_experts", "moe_shared", "loss_head", "head", "sampling", "grad_clip", "optimizer",
+    "mlp", "conv_op", "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm", "moe_route", "moe_experts", "moe_shared",
+    "loss_head", "head", "sampling", "grad_clip", "optimizer",
 )
 #: Flax module names that are not themselves phase names.
 _PHASE_ALIASES = {"attn": "attn_proj"}

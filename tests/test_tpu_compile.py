@@ -281,3 +281,61 @@ def test_the_loss_reads_the_logits_where_they_lie(v5e, layout):
         assert f"bf16[{B},{T},{vocab}]" in text
     else:  # the target's logit is a partial sum a shard and an all-reduce of [B, T], no gather across shards
         assert " all-gather(" not in text and " all-to-all(" not in text
+
+
+def test_the_granite_cells_step_fits_one_chip_at_the_two_chip_head_share_with_remat(v5e, monkeypatch):
+    """``granite-train-8k``'s step at its published widths (10 layers, 32 of 64 Mamba-2 heads, 16 / 4 attention
+    heads of 64, MLP 8192, 1 x 8192 tokens, fp32 weights + AdamW, ``remat``), as the stage builds it: loss and
+    gradient, the clip, the update, state donated. Its ``memory_analysis()`` is the number the configuration's
+    decision rule reads: arguments + temporaries + the registry's float32 copy of the parameters under the chip's
+    15.75 GiB, else the cell takes the four-chip share. The flash kernels are in it, and the block's recomputed
+    forward lands in the mixer's own phases."""
+    import json
+    import os
+
+    import optax
+
+    from benchmark import counts_granite, reference_granite
+    from benchmark.drivers import train_granite
+    from dmlcloud_tpu.models.transformer import DecoderLM, lm_loss, ssm_counters
+    from dmlcloud_tpu.utils.profiling import phase_map
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash path asks it which kernels to lower
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic", "train-granite-8k.json")) as f:
+        job = json.load(f)
+    cfg = train_granite.model_config(config, job)
+    assert cfg.remat and cfg.attn_impl == "flash" and (cfg.mamba_n_heads, cfg.num_heads, cfg.kv_heads) == (32, 16, 4)
+    model = DecoderLM(cfg)
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    held = sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(params))
+    assert held == counts_granite.param_count(dict(reference_granite.spec(config))) == 652_970_080
+    o = job["optimizer"]
+    tx = optax.adamw(optax.warmup_cosine_decay_schedule(o["init_lr"], o["peak_lr"], o["warmup_steps"], o["decay_steps"]),
+                     b1=o["b1"], b2=o["b2"], eps=o["eps"], weight_decay=o["weight_decay"])
+    tokens = jax.ShapeDtypeStruct((job["batch"], job["seq_len"]), jnp.int32, sharding=one_chip)
+
+    def step(params, opt_state, tokens):
+        def loss_fn(p):
+            logits, stats = model.apply({"params": p}, tokens, mutable=["ssm_stats"])
+            return lm_loss(logits, tokens), ssm_counters(stats)
+
+        (loss, counters), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        norm = jnp.sqrt(sum(jnp.sum(g**2) for g in jax.tree_util.tree_leaves(grads)))
+        grads = jax.tree_util.tree_map(lambda g: g * jnp.minimum(1.0, job["gradient_clip"] / jnp.maximum(norm, 1e-6)), grads)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss, counters
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(params, on_chip(jax.eval_shape(tx.init, params)), tokens).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(12 * held, rel=0.001)  # weights and two moments
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + 4 * held < 15.75 * 2**30
+    assert memory.temp_size_in_bytes < 3.5e9  # 2.53 GB when this was written; without remat the step's activations alone pass that
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    found = set(phase_map(text).values())
+    assert {(p, "recompute") for p in ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm")} <= found
