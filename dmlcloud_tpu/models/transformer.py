@@ -133,11 +133,12 @@ class TransformerConfig:
     shared_expert_intermediate_size: int = 0  # > 0: one SwiGLU of this width on every token, added to the routed experts'
     experts_held: tuple[int, int] | None = None
     seq_axis: str = "seq"  # mesh axis used when attn_impl == 'ring'
-    # Mesh for attn_impl='ring' and 'flash' under plain jit: both wrap
-    # themselves in shard_map over it (ring to split the sequence; flash
-    # because XLA cannot partition the Pallas kernel, so on a multi-device
-    # TPU mesh it does not compile without). Leave None on one device, or
-    # when the step is already shard_mapped.
+    # Mesh for attn_impl='ring' and 'flash' and for the "mamba" layers' scan
+    # under plain jit: each wraps itself in shard_map over it (ring to split
+    # the sequence; flash and the scan because XLA cannot partition a Pallas
+    # kernel: on a multi-device TPU mesh flash does not compile without, and
+    # the scan keeps its plain form wherever the process sees several
+    # devices). Leave None when the step is already shard_mapped.
     mesh: Any = None
     # Residual-stream sharding constraint ([B, T, D] activations), applied
     # after the embedding and every block. Pin this (e.g. a NamedSharding of
@@ -611,7 +612,7 @@ class Mamba2Mixer(nn.Module):
 
     @nn.compact
     def __call__(self, u):
-        from ..ops.ssd import ssd_chunked
+        from ..ops import ssd
         from .quant import QuantDense
 
         cfg = self.cfg
@@ -633,11 +634,16 @@ class Mamba2Mixer(nn.Module):
             a_log = self.param("A_log", lambda key, shape: jnp.log(jax.random.uniform(key, shape, f32, 1.0, 16.0)), (h,))
             dt_bias = self.param("dt_bias", _inverse_softplus_log_uniform(1e-3, 1e-1), (h,))
             skip = self.param("D", nn.initializers.ones_init(), (h,), f32)
-            # a row shorter than a chunk (``init``'s example input) is one chunk of its own length
-            y, carried = ssd_chunked(
+            # a row shorter than a chunk (``init``'s example input) is one chunk of its own length; with a mesh named
+            # the scan shard_maps itself over it, as the flash path does (XLA cannot partition its kernels; over a mesh
+            # of one device the compiled step is the same, and the scan knows its trace is one device's)
+            scan = ssd.ssd_chunked
+            if cfg.mesh is not None:
+                scan = lambda *args, **kwargs: ssd.ssd_chunked_sharded(*args, cfg.mesh, **kwargs)
+            y, carried = scan(
                 x.reshape(b, t, h, p), jax.nn.softplus(dt.astype(f32) + dt_bias), -jnp.exp(a_log),
                 b_in.reshape(b, t, g, n), c_in.reshape(b, t, g, n), skip, min(cfg.mamba_chunk_size, t), return_carry=True)
-            self.sow("ssm_stats", "state_absmax", jnp.max(jnp.abs(jax.lax.stop_gradient(carried))),
+            self.sow("ssm_stats", "state_absmax", jnp.max(jnp.abs(carried)),  # a reading: the scan stops its gradient
                      init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.maximum)
         with jax.named_scope("ssm_gate_norm"):
             scale = self.param("norm_scale", nn.initializers.ones_init(), (d_inner,), f32)

@@ -34,7 +34,7 @@ MODULES = [
     ("dmlcloud_tpu.ops.flash_attention", "Fused Pallas flash-attention kernels (fwd + bwd)."),
     ("dmlcloud_tpu.ops.ring_attention", "Ring attention: sequence parallelism over the mesh."),
     ("dmlcloud_tpu.ops.grouped_matmul", "Grouped products over ragged groups, and the moves of rows to experts and back."),
-    ("dmlcloud_tpu.ops.ssd", "The chunked state-space scan (Mamba-2's SSD form) in plain jax.numpy."),
+    ("dmlcloud_tpu.ops.ssd", "The chunked state-space scan (Mamba-2's SSD form): two Pallas kernels with their own backward, and the plain form."),
     ("dmlcloud_tpu.models.transformer", "Llama-style decoder LM building blocks."),
     ("dmlcloud_tpu.models.generate", "Autoregressive generation: sampling + beam search."),
     ("dmlcloud_tpu.models.moe", "Dropless mixture-of-experts layer: a chip's share of the experts, expert parallelism."),

@@ -9,6 +9,7 @@ run. Skipped where the installation cannot describe the topology.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the TPU compiler logs under /tmp
 
@@ -283,6 +284,101 @@ def test_the_loss_reads_the_logits_where_they_lie(v5e, layout):
         assert " all-gather(" not in text and " all-to-all(" not in text
 
 
+def _as_on_a_tpu(monkeypatch, devices: int = 1):
+    """What a process on a TPU machine observes: the flash path and the scan ask the backend which kernels to lower,
+    and the scan asks how many devices its trace is for (the test session's CPU shows eight)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+
+
+def _granite():
+    """``granite-train-8k``'s configuration file, its job and the program's configuration of the model."""
+    import json
+
+    from benchmark.drivers import train_granite
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmark", "traffic", "train-granite-8k.json")) as f:
+        job = json.load(f)
+    return config, job, train_granite.model_config(config, job)
+
+
+def _scan_args(b, t, h, g, sharding):
+    """``ssd_chunked``'s six inputs as the mixer hands them over: heads of 64, a state of 128, bf16 beside float32."""
+    sds = lambda shape, dtype, *spec: jax.ShapeDtypeStruct(shape, dtype, sharding=sharding(*spec))
+    groups = "model" if g > 1 else None
+    return (sds((b, t, h, 64), jnp.bfloat16, "fsdp", None, "model", None), sds((b, t, h), jnp.float32, "fsdp", None, "model"),
+            sds((h,), jnp.float32, "model"), sds((b, t, g, 128), jnp.bfloat16, "fsdp", None, groups, None),
+            sds((b, t, g, 128), jnp.bfloat16, "fsdp", None, groups, None), sds((h,), jnp.float32, "model"))
+
+
+@pytest.mark.parametrize("b, t, h, g, chunk, heads, on", [
+    (1, 8192, 32, 1, 256, 8, "one"),  # granite-train-8k: the largest a grid step holds in VMEM
+    (2, 1024, 16, 2, 128, 8, "one"), (1, 1024, 4, 2, 256, 2, "one"), (1, 1024, 12, 1, 128, 6, "one"),
+    (2, 8192, 32, 1, 256, 8, "mesh"), (2, 1024, 16, 4, 128, 4, "mesh"),
+], ids=lambda v: str(v))
+def test_the_scans_kernels_compile_at_every_class_of_shapes_the_dispatch_lets_through(v5e, monkeypatch, b, t, h, g, chunk, heads, on):
+    """``ssd_fwd`` and ``ssd_bwd`` through Mosaic for a described v5e, which sees what the interpreter does not (a
+    layout it cannot broadcast from, the scoped VMEM limit): both chunks ``_heads_per_step`` admits, one group
+    and two, two to eight heads a grid step; on one chip, and on the 2 x 2 mesh through ``ssd_chunked_sharded``
+    (heads over ``model``, rows over ``fsdp``; what shards share has its gradient summed over them, nothing is gathered)."""
+    from dmlcloud_tpu.ops import ssd
+
+    shards = 2 if on == "mesh" else 1
+    assert ssd._heads_per_step(h // shards, max(g // shards, 1), 64, 128, chunk) == heads
+    if on == "one":
+        _as_on_a_tpu(monkeypatch)
+        args = _scan_args(b, t, h, g, lambda *spec: SingleDeviceSharding(v5e.devices[0]))
+        scan = lambda *a: ssd.ssd_chunked(*a, chunk)
+    else:
+        _as_on_a_tpu(monkeypatch, devices=4)
+        mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("fsdp", "model"))
+        args = _scan_args(b, t, h, g, lambda *spec: NamedSharding(mesh, P(*spec)))
+        scan = lambda *a: ssd.ssd_chunked_sharded(*a, chunk, mesh)
+    text = _compile(jax.grad(lambda *a: jnp.sum(scan(*a).astype(jnp.float32) ** 2), argnums=tuple(range(6))), *args)
+    assert "ssd_fwd" in text and "ssd_bwd" in text
+    assert " all-gather(" not in text and " all-to-all(" not in text
+    assert (" all-reduce(" in text) == (on == "mesh")  # what the shards share: ``A`` and ``D`` over the rows' holders, one group's ``B`` / ``C``
+
+
+@pytest.mark.parametrize("mesh_named", [True, False], ids=["cfg.mesh", "no-mesh-named"])
+def test_a_mamba_layer_compiles_on_a_four_chip_mesh_under_plain_jit(v5e, monkeypatch, mesh_named):
+    """A ``mamba`` block of ``granite-train-8k``'s widths, loss and gradient, on the described fsdp x model mesh with
+    the repo's partition rules and plain jit. With ``TransformerConfig.mesh`` named the scan shard_maps itself and
+    the step holds the kernels; with none named it holds the plain form, which XLA partitions as it did before
+    there were kernels (and says so in the log); the kernels as they are it would refuse, as it refuses flash."""
+    import dataclasses
+
+    from dmlcloud_tpu.models.transformer import DecoderBlock, llama_partition_rules
+    from dmlcloud_tpu.ops import ssd
+    from dmlcloud_tpu.parallel.mesh import sharding_for
+
+    _as_on_a_tpu(monkeypatch, devices=4)
+    _, job, cfg = _granite()
+    mesh = Mesh(np.array(v5e.devices).reshape(2, 2), ("fsdp", "model"))
+    block = DecoderBlock(dataclasses.replace(cfg, remat=False, mesh=mesh if mesh_named else None), kind="mamba")
+    example = jnp.zeros((1, 8, cfg.hidden_dim), jnp.bfloat16)
+    shapes = jax.eval_shape(block.init, jax.random.PRNGKey(0), example, None, None)["params"]
+    params = jax.tree_util.tree_map(lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+                                    shapes, sharding_for(shapes, mesh, llama_partition_rules()))
+    assert params["mamba"]["in_proj"]["kernel"].sharding.spec == P("fsdp", "model")
+    u = jax.ShapeDtypeStruct((2, job["seq_len"], cfg.hidden_dim), jnp.bfloat16, sharding=NamedSharding(mesh, P("fsdp")))
+
+    def loss(p, u):
+        out, _ = block.apply({"params": p}, u, None, None, mutable=["ssm_stats"])
+        return jnp.sum(out[0].astype(jnp.float32) ** 2)
+
+    step = jax.grad(loss, argnums=(0, 1))
+    text = _compile(step, params, u)
+    assert ("ssd_fwd" in text, "ssd_bwd" in text) == (mesh_named, mesh_named)
+    if not mesh_named:
+        monkeypatch.setattr(ssd, "_on_one_device", lambda: True)  # what the dispatch would do if it did not look
+        with pytest.raises(NotImplementedError, match="Mosaic kernels cannot be automatically partitioned"):
+            _compile(lambda p, u: step(p, u), params, u)  # a function jit has not traced yet
+
+
 def test_the_granite_cells_step_fits_one_chip_at_the_two_chip_head_share_with_remat(v5e, monkeypatch):
     """``granite-train-8k``'s step at its published widths (10 layers, 32 of 64 Mamba-2 heads, 16 / 4 attention
     heads of 64, MLP 8192, 1 x 8192 tokens, fp32 weights + AdamW, ``remat``), as the stage builds it: loss and
@@ -290,23 +386,17 @@ def test_the_granite_cells_step_fits_one_chip_at_the_two_chip_head_share_with_re
     decision rule reads: arguments + temporaries + the registry's float32 copy of the parameters under the chip's
     15.75 GiB, else the cell takes the four-chip share. The flash kernels are in it, and the block's recomputed
     forward lands in the mixer's own phases."""
-    import json
-    import os
+    import dataclasses
 
     import optax
 
     from benchmark import counts_granite, reference_granite
-    from benchmark.drivers import train_granite
     from dmlcloud_tpu.models.transformer import DecoderLM, lm_loss, ssm_counters
     from dmlcloud_tpu.utils.profiling import phase_map
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the flash path asks it which kernels to lower
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "benchmark", "configs", "granite-4.0-h-micro.json")) as f:
-        config = json.load(f)
-    with open(os.path.join(root, "benchmark", "traffic", "train-granite-8k.json")) as f:
-        job = json.load(f)
-    cfg = train_granite.model_config(config, job)
+    _as_on_a_tpu(monkeypatch, devices=4)  # a host of four chips, of which the cell takes one and says so: ``cfg.mesh``, as its driver
+    config, job, cfg = _granite()
+    cfg = dataclasses.replace(cfg, mesh=Mesh(np.array(v5e.devices[:1]), ("data",)))
     assert cfg.remat and cfg.attn_impl == "flash" and (cfg.mamba_n_heads, cfg.num_heads, cfg.kv_heads) == (32, 16, 4)
     model = DecoderLM(cfg)
     one_chip = SingleDeviceSharding(v5e.devices[0])
@@ -337,5 +427,16 @@ def test_the_granite_cells_step_fits_one_chip_at_the_two_chip_head_share_with_re
     assert memory.temp_size_in_bytes < 3.5e9  # 2.53 GB when this was written; without remat the step's activations alone pass that
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3
-    found = set(phase_map(text).values())
+    phases = phase_map(text)
+    found = set(phases.values())
     assert {(p, "recompute") for p in ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate_norm")} <= found
+    # the scan is ops/ssd.py's two kernels, in the phase ``ssm_scan`` in all three directions (so that
+    # ``train_ssm_scan_share`` and ``ssm_scan_roofline.granite`` go on reading the whole scan), and no
+    # [.., chunk, chunk] decay matrix of the plain form is left among the step's arrays in HBM
+    kernels = {name: where for name, where in phases.items() if name.startswith(("ssd_fwd", "ssd_bwd"))}
+    assert {where for name, where in kernels.items() if name.startswith("ssd_fwd")} == {("ssm_scan", "fwd"), ("ssm_scan", "recompute")}
+    assert {where for name, where in kernels.items() if name.startswith("ssd_bwd")} == {("ssm_scan", "bwd")}
+    calls = re.findall(r"%(ssd_(?:fwd|bwd))[.\d]* = [^\n]* custom-call\(", text)  # a kernel XLA fused a cut of its input into is listed twice
+    assert (calls.count("ssd_fwd"), calls.count("ssd_bwd")) == (2 * cfg.layer_types.count("mamba"), cfg.layer_types.count("mamba"))
+    chunk = cfg.mamba_chunk_size
+    assert not re.findall(rf"(?:f32|bf16)\[[\d,]*{chunk},{chunk}\]", text)
