@@ -23,6 +23,8 @@
 - ``trace``: dump ONE request's causal trace from a serve run's journals —
   every span carrying its trace id in ts order, plus the TTFT breakdown
   (queue wait vs prefill vs first decode) and terminal status.
+  ``--setup`` instead tables a training run's set-up: each ``setup`` and
+  ``compile`` span, nested, with its duration and self time.
 - ``top``: live terminal view of a serving metrics surface — polls either
   a ``/metrics`` HTTP endpoint (``--url``) or a registry snapshot JSON
   (``MetricsRegistry(save_path=...)``) and renders the headline serving
@@ -35,6 +37,7 @@
     python -m dmlcloud_tpu verify [paths...] [--json] [--hbm-budget 16G]
     python -m dmlcloud_tpu timeline RUN_DIR [-o trace.json] [--by-request]
     python -m dmlcloud_tpu trace RUN_DIR --rid 17   # or --trace tr-17
+    python -m dmlcloud_tpu trace RUN_DIR --setup
     python -m dmlcloud_tpu top --url http://127.0.0.1:9100/metrics --once
 
 The bare invocation (no subcommand) stays diag for backward compatibility
@@ -109,11 +112,13 @@ def _trace_main(argv) -> int:
                         help="request id (trace id tr-RID)")
     parser.add_argument("--trace", default=None, metavar="TID",
                         help="explicit trace id (overrides --rid)")
+    parser.add_argument("--setup", action="store_true",
+                        help="table the run's set-up spans instead of a request")
     parser.add_argument("--json", action="store_true",
                         help="machine-readable dump instead of the table")
     args = parser.parse_args(argv)
-    if args.trace is None and args.rid is None:
-        parser.error("one of --rid / --trace is required")
+    if args.trace is None and args.rid is None and not args.setup:
+        parser.error("one of --rid / --trace / --setup is required")
     tid = args.trace if args.trace is not None else f"tr-{args.rid}"
 
     from .telemetry.journal import linked_trace_report, load_journals
@@ -123,6 +128,19 @@ def _trace_main(argv) -> int:
     except FileNotFoundError as e:
         print(f"trace: {e}", file=sys.stderr)
         return 1
+    if args.setup:
+        rows = setup_summary(records)
+        if not rows:
+            print(f"trace: no setup spans under {args.run_dir}", file=sys.stderr)
+            return 1
+        if args.json:
+            print(json.dumps({"setup": rows}))
+            return 0
+        print(f"  {'offset_s':>9} {'dur_s':>8} {'self_s':>8}  part")
+        for r in rows:
+            print(f"  {r['offset_s']:>9.3f} {r['dur_s']:>8.3f} {r['self_s']:>8.3f}  "
+                  f"{'  ' * r['depth']}{r['part']} {r['detail']}".rstrip())
+        return 0
     report = linked_trace_report(records)
     spans = report["traces"].get(tid)
     if not spans:
@@ -150,6 +168,35 @@ def _trace_main(argv) -> int:
             f"{s['where']:<8} {s['detail']}"
         )
     return 0
+
+
+def setup_summary(records: list) -> list:
+    """The ``setup`` and ``compile`` spans of each rank in time order, as
+    ``trace --setup`` tables them: a part's self time is its duration less
+    that of the parts directly inside it (``pre_stage``, ``state``,
+    ``compile`` and ``phase_map`` inside ``stage``)."""
+    parts = sorted((r for r in records if r.get("kind") in ("setup", "compile")),
+                   key=lambda r: (r.get("rank", 0), r["ts"], -r["dur"]))
+    rows, stack = [], []  # stack: (rank, end, row) of the parts open at this one's start
+    t0 = min((r["ts"] for r in parts), default=0.0)
+    for r in parts:
+        end = r["ts"] + r["dur"]
+        while stack and (stack[-1][0] != r.get("rank", 0) or stack[-1][1] < end - 1e-6):
+            stack.pop()
+        if r["kind"] == "compile":
+            part = f"compile {r.get('label')}"
+            detail = f"lower_s={r['lower_s']:.3f} cache_hit={r['cache_hit']}" if "lower_s" in r else ""
+        else:
+            part, detail = r.get("label"), ""
+        row = {"offset_s": round(r["ts"] - t0, 6), "dur_s": round(r["dur"], 6), "self_s": r["dur"],
+               "part": part, "depth": len(stack), "rank": r.get("rank", 0), "detail": detail}
+        if stack:
+            stack[-1][2]["self_s"] -= r["dur"]
+        rows.append(row)
+        stack.append((r.get("rank", 0), end, row))
+    for row in rows:
+        row["self_s"] = round(max(row["self_s"], 0.0), 6)
+    return rows
 
 
 def request_trace_summary(spans: list, status=None) -> dict:

@@ -137,13 +137,15 @@ class PrecompiledStep:
         self._compiled: dict[tuple, Any] = {}
         self._seen: set[tuple] = set()
         self._recompiles = 0
-        self.compile_ms = 0.0
 
     def precompile(self, *abstract_args: Any) -> float:
         """Lower + compile one signature ahead of the data loop; returns the
         wall-clock ms this compilation took (0.0 if already registered).
         Accounts a persistent-cache hit when the compile added no new cache
-        entry (the executable was deserialized, not built)."""
+        entry (the executable was deserialized, not built). With a journal
+        armed, the ``compile`` span carries ``lower_s`` (the ``.lower()``
+        part) and ``cache_hit``, and the step's phase map is written after
+        it, timed as the ``phase_map`` set-up span."""
         sig = signature_of(abstract_args)
         if sig in self._compiled:
             return 0.0
@@ -151,9 +153,18 @@ class PrecompiledStep:
 
         entries_before = cache_lib.entry_count()
         t0 = time.perf_counter()
-        compiled = self._fn.lower(*abstract_args).compile()
+        lowered = self._fn.lower(*abstract_args)
+        t_lowered = time.perf_counter()
+        compiled = lowered.compile()
         t1 = time.perf_counter()
         elapsed_ms = (t1 - t0) * 1e3
+        entries_after = cache_lib.entry_count()
+        hit = (
+            entries_before is not None
+            and entries_after is not None
+            and entries_after == entries_before
+        )
+        cache_lib.record_compile(hit=hit, elapsed_ms=elapsed_ms)
         j = _journal.active_journal()
         if j is not None:
             # with a journal armed, and only then, the step's phase map goes beside
@@ -163,18 +174,13 @@ class PrecompiledStep:
             n = len(self._compiled) + 1
             safe = "".join(c if c.isalnum() or c in "._-" else "_" for c in self.name)
             path = os.path.join(j.directory, f"phases-{safe}-{n}.json")
-            j.emit("compile", t0, t1, label=self.name, signature=n,
-                   phases=write_phase_map(compiled, path, self.name))
-        entries_after = cache_lib.entry_count()
-        hit = (
-            entries_before is not None
-            and entries_after is not None
-            and entries_after == entries_before
-        )
-        cache_lib.record_compile(hit=hit, elapsed_ms=elapsed_ms)
+            t_map = time.perf_counter()
+            phases = write_phase_map(compiled, path, self.name)
+            _journal.setup("phase_map", t_map)
+            j.emit("compile", t0, t1, label=self.name, signature=n, phases=phases,
+                   lower_s=round(t_lowered - t0, 9), cache_hit=hit)
         self._compiled[sig] = compiled
         self._seen.add(sig)
-        self.compile_ms += elapsed_ms
         return elapsed_ms
 
     def __call__(self, *args: Any):

@@ -34,6 +34,7 @@ from .metrics import MetricTracker, Reduction
 from .parallel import mesh as mesh_lib
 from .parallel import runtime
 from .stage import Stage
+from .telemetry import journal as journal_mod
 from .utils.config import Config, as_config
 from .utils.logging import IORedirector, add_log_handlers, experiment_header, general_diagnostics
 from .utils.wandb import wandb, wandb_is_initialized, wandb_set_startup_timeout
@@ -645,6 +646,7 @@ class TrainingPipeline:
         self.logger.warning("TPU-hazard linter findings in registered stages:\n%s", report)
 
     def _pre_run(self):
+        t0 = journal_mod.now()
         if len(self.stages) == 0:
             raise ValueError("No stages defined. Use append_stage() to add stages to the pipeline.")
         self._verdict_written = False
@@ -708,6 +710,7 @@ class TrainingPipeline:
             self.logger.info("persistent compilation cache: %s", self._compile_cache_dir)
 
         self.pre_run()
+        journal_mod.setup("pipeline", t0)
 
     def _arm_telemetry(self):
         """Start the flight recorder: journal + goodput + hang watchdog
@@ -719,7 +722,6 @@ class TrainingPipeline:
         import os
 
         from .checkpoint import is_remote_path
-        from .telemetry import journal as journal_mod
         from .telemetry.watchdog import HangWatchdog
 
         opts = dict(cfg) if isinstance(cfg, dict) else {}
@@ -776,7 +778,6 @@ class TrainingPipeline:
     def _telemetry_ledger(self):
         """Root-only end-of-run goodput ledger: log the table and persist
         ``goodput.json`` next to the journals."""
-        from .telemetry import journal as journal_mod
         from .telemetry.goodput import ledger_from_tracker
 
         if self._run_span_t0 is not None:
@@ -803,7 +804,6 @@ class TrainingPipeline:
         """Teardown half of ``_arm_telemetry`` — always runs (run guard).
         An uncaught exception triggers a forensics dump first: the flight
         recorder's whole point is that the crash leaves evidence behind."""
-        from .telemetry import journal as journal_mod
 
         if self._watchdog is not None:
             if exc is not None and not isinstance(exc, KeyboardInterrupt):

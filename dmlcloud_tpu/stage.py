@@ -174,6 +174,7 @@ class Stage:
         ``_stop_requested`` (stage already stopped before the interruption)
         skips the loop entirely."""
         self._pre_stage()
+        _journal.setup("stage", self._stage_span_t0)
         while not self._stop_requested and (self.max_epochs is None or self.current_epoch <= self.max_epochs):
             self._pre_epoch()
             # the runtime sanitizer's guard window is exactly one epoch:
@@ -224,7 +225,9 @@ class Stage:
         self._setup_table()
         if len(self.pipeline.stages) > 1:
             self.logger.info(f"\n========== STAGE: {self.name} ==========")
+        t0 = _journal.now()
         self.pre_stage()
+        _journal.setup("pre_stage", t0)
         flush_log_handlers(self.logger)
         self.pipeline.barrier(self.barrier_timeout)
 
@@ -869,13 +872,17 @@ class TrainValStage(Stage):
         if self.state is None:
             entry = self.pipeline._model_entry(self.model_name())
             self._policy = entry.policy
+            t0 = _journal.now()
             self.state = self.make_state()
+            _journal.setup("state", t0)
         self._configure_state_manager()
         if self.pipeline.resumed and (
             int(self.checkpoint_every()) > 0 or int(self.checkpoint_every_steps()) > 0
         ):
             # manual mode (checkpoint_every()==0) owns its restore layout too
+            t0 = _journal.now()
             self._restore_state()
+            _journal.setup("restore", t0)
         self._train_step_fn = self._build_train_step()
         self._val_step_fn = self._build_val_step()
         self._setup_compiled_steps()
@@ -950,7 +957,6 @@ class TrainValStage(Stage):
         from .compile import cache as compile_cache
         from .compile.buckets import bucket_spec
 
-        t0 = time.perf_counter()
         stats0 = compile_cache.cache_stats()
         state_spec = aot.abstract_spec(self.state)
 
@@ -972,9 +978,10 @@ class TrainValStage(Stage):
             return out
 
         n_train = 0
+        elapsed_ms = 0.0  # the compile spans' own readings (PrecompiledStep.precompile)
         verify_args: list[tuple] = []
         for gs in global_specs(self._host_batch_spec(self.train_dataset)):
-            self._train_compiled.precompile(state_spec, gs)
+            elapsed_ms += self._train_compiled.precompile(state_spec, gs)
             verify_args.append(("train_step", self._train_compiled, (state_spec, gs), (0,)))
             n_train += 1
         # val is best-effort: a stage may have no val dataset, or one whose
@@ -982,13 +989,12 @@ class TrainValStage(Stage):
         n_val = 0
         try:
             for gs in global_specs(self._host_batch_spec(self.val_dataset)):
-                self._val_compiled.precompile(state_spec, gs)
+                elapsed_ms += self._val_compiled.precompile(state_spec, gs)
                 verify_args.append(("val_step", self._val_compiled, (state_spec, gs), ()))
                 n_val += 1
         except ValueError as e:
             self.logger.warning(f"val-step precompile skipped: {e}")
 
-        elapsed_ms = (time.perf_counter() - t0) * 1e3
         if not ("misc/compile_ms" in self.tracker and self.tracker.has_value("misc/compile_ms")):
             self.track("misc/compile_ms", round(elapsed_ms, 3), prefixed=False)
         stats1 = compile_cache.cache_stats()

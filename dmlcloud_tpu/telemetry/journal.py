@@ -40,6 +40,7 @@ from typing import Any, Iterable
 __all__ = [
     "SCHEMA_VERSION",
     "SPAN_KINDS",
+    "SETUP_LABELS",
     "REQUEST_SPAN_KINDS",
     "BATCH_SPAN_KINDS",
     "SpanJournal",
@@ -48,6 +49,7 @@ __all__ = [
     "active_journal",
     "span",
     "emit",
+    "setup",
     "now",
     "load_journals",
     "to_chrome_trace",
@@ -97,6 +99,7 @@ SPAN_KINDS = frozenset(
         "call_launch",  # serving: the jitted call returning, the pool swapped
         "call_fetch",  # serving: the call's tokens back on the host (the one sync)
         "profile",  # a jax.profiler trace the program took (utils.profiling.trace)
+        "setup",  # one part of the time from process start to the first step (label: SETUP_LABELS)
     }
 )
 
@@ -114,6 +117,19 @@ REQUEST_SPAN_KINDS = frozenset(
 #: Serve span kinds that advance a whole decode BATCH: they carry a
 #: ``traces`` list attr linking every request that rode the batch.
 BATCH_SPAN_KINDS = frozenset({"decode_batch", "draft", "verify", "medusa"})
+
+#: The ``setup`` span labels and the label of the set-up span each one nests
+#: in (its ``parent`` attr; None for a top-level part). A part's self time is
+#: its duration less its children's.
+SETUP_LABELS = {
+    "import": None,  # `import dmlcloud_tpu`: __init__.py's first to last line
+    "pipeline": None,  # TrainingPipeline._pre_run through the user's pre_run()
+    "stage": None,  # one stage's _pre_stage, whole (TrainValStage's included)
+    "pre_stage": "stage",  # the user's pre_stage() hook
+    "state": "stage",  # make_state(): the TrainState on the device, the optimizer's init
+    "restore": "stage",  # _restore_state(), when resuming
+    "phase_map": "stage",  # write_phase_map after a compile (armed runs only)
+}
 
 _JOURNAL_GLOB_PREFIX = "journal-rank"
 
@@ -267,11 +283,22 @@ class SpanJournal:
 # single attribute read + None check — the default path stays free.
 
 _active: SpanJournal | None = None
+#: ``perf_counter`` at the top and bottom of the package's ``__init__``, until
+#: the first journal armed in the process records them as the ``import`` span
+_import_readings: tuple[float, float] | None = None
+
+
+def record_import(start: float, end: float) -> None:
+    global _import_readings
+    _import_readings = (start, end)
 
 
 def activate(journal: SpanJournal) -> SpanJournal:
-    global _active
+    global _active, _import_readings
     _active = journal
+    if _import_readings is not None:
+        readings, _import_readings = _import_readings, None
+        setup("import", *readings)
     return journal
 
 
@@ -299,6 +326,15 @@ def emit(kind: str, start: float, end: float | None = None, label: str | None = 
     if j is None:
         return None
     return j.emit(kind, start, end, label=label, **attrs)
+
+
+def setup(label: str, start: float, end: float | None = None):
+    """Record one part of set-up (``SETUP_LABELS``) on the active journal,
+    with its ``parent``; no-op when not armed."""
+    j = _active
+    if j is None:
+        return None
+    return j.emit("setup", start, end, label=label, parent=SETUP_LABELS[label])
 
 
 def now() -> float:

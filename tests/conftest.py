@@ -40,6 +40,13 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 jax.config.update("jax_enable_compilation_cache", False)
 
 from dmlcloud_tpu.parallel import runtime  # noqa: E402
+from dmlcloud_tpu.telemetry import journal as _journal  # noqa: E402
+
+# The package's import is journalled by the first journal armed in the process
+# (telemetry/journal.py), and which test arms one first depends on a worker's
+# order of files: the session starts with it taken, so a test sees the spans
+# it made (tests/test_setup_spans.py sets its own readings).
+_journal._import_readings = None
 
 
 @pytest.fixture

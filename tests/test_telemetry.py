@@ -65,6 +65,9 @@ V1_KINDS = {
     # call, the call's uploads, launch and fetch; a profile the program took
     "engine_step", "call_build", "call_upload", "call_launch", "call_fetch",
     "profile",
+    # set-up (PR 39): one part of the time from process start to the first
+    # step, by label (journal.SETUP_LABELS), with its parent's label
+    "setup",
 }
 
 #: Core fields every v1 record carries, with their types.
