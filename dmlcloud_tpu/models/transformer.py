@@ -677,48 +677,48 @@ def sinkhorn_knopp(m: jnp.ndarray, iters: int, eps: float) -> jnp.ndarray:
 
 class HyperConnection(nn.Module):
     """One sublayer's manifold-constrained hyper-connection (mHC,
-    arXiv:2512.24880) over the stream ``X [B, T, n, C]`` (``hc_mult`` = n): with
+    arXiv:2512.24880) over the stream ``X [B, T, n C]`` (``hc_mult`` = n, held
+    lane-flat: stream ``j`` is lanes ``[j C, (j + 1) C)``): with
     ``u = vec(X) / rms(vec(X))`` (over all ``n C`` values, no learned scale),
     ``H_pre = sigmoid(a_pre u phi_pre + b_pre)`` (n), ``H_post = 2 sigmoid(a_post u
     phi_post + b_post)`` (n), ``H_res = SinkhornKnopp(exp(clamp(a_res mat(u phi_res)
-    + b_res)))`` (n x n), all float32. Returns the sublayer's input ``H_pre X``
-    ``[B, T, C]`` and the two maps :func:`hc_merge` writes the stream back with:
-    ``X <- H_res X + H_post^T F``. Sows ``mhc_stats/row_sum_gap``, the largest
-    ``|sum_j H_res[i, j] - 1|`` after the last round (:func:`mhc_counters`). The
-    profile's phase ``mhc``."""
+    + b_res)))`` (n x n), all float32, by ``ops/hyper_connection.mhc_pre``: four
+    Pallas kernels with their own backward on one TPU device, the plain form with
+    :func:`sinkhorn_knopp`'s rounds everywhere else. Returns the sublayer's input
+    ``H_pre X`` ``[B, T, C]`` and what :func:`hc_merge` writes the stream back
+    with, ``X <- H_res X + H_post^T F``: the two maps and the model's mesh, which
+    chooses the path. Sows ``mhc_stats/row_sum_gap``, the largest ``|sum_j H_res[i,
+    j] - 1|`` after the last round (:func:`mhc_counters`). The profile's phase
+    ``mhc``, the kernels' time included (``mhc_pre`` and ``mhc_merge`` forward and
+    recomputed, ``mhc_pre_bwd`` and ``mhc_merge_bwd`` backward)."""
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, stream):
+        from ..ops.hyper_connection import mhc_pre
+
         cfg, f32 = self.cfg, jnp.float32
-        b, t, n, c = stream.shape
-        phi = self.param("phi", nn.initializers.normal((n * c) ** -0.5), (n * c, 2 * n + n * n), f32)
+        n, k = cfg.hc_mult, stream.shape[-1]
+        phi = self.param("phi", nn.initializers.normal(k**-0.5), (k, 2 * n + n * n), f32)
         bias = self.param("bias", nn.initializers.zeros_init(), (2 * n + n * n,), f32)
         alpha = self.param("alpha", nn.initializers.constant(0.01), (3,), f32)
         with jax.named_scope("mhc"):
-            flat = stream.reshape(b, t, n * c)
-            # the norm has no scale of its own: it is one number a token, applied after the product
-            inv_rms = jax.lax.rsqrt(jnp.mean(jnp.square(flat.astype(f32)), axis=-1, keepdims=True) + cfg.norm_eps)
-            mix = jnp.einsum("btk,kf->btf", flat, phi.astype(stream.dtype), preferred_element_type=f32) * inv_rms
-            pre = jax.nn.sigmoid(alpha[0] * mix[..., :n] + bias[:n])
-            post = 2.0 * jax.nn.sigmoid(alpha[1] * mix[..., n : 2 * n] + bias[n : 2 * n])
-            logits = (alpha[2] * mix[..., 2 * n :] + bias[2 * n :]).reshape(b, t, n, n)
-            res = sinkhorn_knopp(jnp.exp(jnp.clip(logits, cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max)),
-                                 cfg.hc_sinkhorn_iters, cfg.hc_eps)
-            self.sow("mhc_stats", "row_sum_gap", jax.lax.stop_gradient(jnp.max(jnp.abs(jnp.sum(res, axis=-1) - 1.0))),
-                     init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.maximum)
-            # n multiply-adds a channel, written out so that XLA fuses them into one pass over the stream
-            x_in = sum(pre[..., j, None] * stream[:, :, j].astype(f32) for j in range(n))
-        return x_in.astype(stream.dtype), post, res
+            x_in, post, res, gap = mhc_pre(
+                stream, phi, bias, alpha, n, iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, norm_eps=cfg.norm_eps,
+                clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max), mesh=cfg.mesh, sinkhorn=sinkhorn_knopp)
+            self.sow("mhc_stats", "row_sum_gap", gap, init_fn=lambda: jnp.zeros(()), reduce_fn=jnp.maximum)
+        return x_in, post, res, cfg.mesh
 
 
-def hc_merge(stream, out, post, res):
-    """``X <- H_res X + H_post^T out`` a token: ``stream [B, T, n, C]``, ``out [B, T, C]``."""
+def hc_merge(stream, out, post, res, mesh=None):
+    """``X <- H_res X + H_post^T out`` a token: ``stream [B, T, n C]``, ``out [B, T, C]``
+    (``ops/hyper_connection.mhc_merge``: its kernels where ``mhc_pre``'s ran, on
+    the same ``mesh``; the plain form elsewhere)."""
+    from ..ops.hyper_connection import mhc_merge
+
     with jax.named_scope("mhc"):
-        n, f32 = stream.shape[2], jnp.float32
-        mixed = sum(res[..., :, j, None] * stream[:, :, None, j].astype(f32) for j in range(n))
-        return (mixed + post[..., None] * out[:, :, None, :].astype(f32)).astype(stream.dtype)
+        return mhc_merge(stream, out, post, res, mesh=mesh)
 
 
 def mhc_counters(variables: Any) -> dict:
@@ -872,7 +872,7 @@ class DecoderBlock(nn.Module):
     operator (``LAYER_KINDS``), which owns its projections and its call;
     ``use_moe`` puts the expert layer in the dense MLP's place; ``attention``
     is what an attention operator is told of itself (None: the model-wide values).
-    With ``hc_mult`` n > 1, ``x`` is the stream ``[B, T, n, C]`` and each of the
+    With ``hc_mult`` n > 1, ``x`` is the stream ``[B, T, n C]`` and each of the
     two sublayers reads and writes it through its own ``HyperConnection``
     (``attn_hc``, ``mlp_hc``): ``X <- H_res X + H_post^T F(norm(H_pre X))``."""
 
@@ -1201,11 +1201,11 @@ class DecoderLM(nn.Module):
 
 
 def _streams(cfg: TransformerConfig, x):
-    """The embedding as the residual stream: itself, or ``hc_mult`` copies of it ``[B, T, n, C]``."""
+    """The embedding as the residual stream: itself, or ``hc_mult`` copies of it side by side ``[B, T, n C]``."""
     if cfg.hc_mult == 1:
         return x
     with jax.named_scope("mhc"):
-        return jnp.broadcast_to(x[:, :, None], (*x.shape[:2], cfg.hc_mult, x.shape[-1]))
+        return jnp.concatenate([x] * cfg.hc_mult, axis=-1)
 
 
 def _stream_sum(cfg: TransformerConfig, x):
@@ -1213,7 +1213,8 @@ def _stream_sum(cfg: TransformerConfig, x):
     if cfg.hc_mult == 1:
         return x
     with jax.named_scope("mhc"):
-        return jnp.sum(x.astype(jnp.float32), axis=2).astype(x.dtype)
+        c = x.shape[-1] // cfg.hc_mult
+        return sum(x[..., j * c : (j + 1) * c].astype(jnp.float32) for j in range(cfg.hc_mult)).astype(x.dtype)
 
 
 class NextTokenPrediction(nn.Module):

@@ -969,6 +969,7 @@ class TrainValStage(Stage):
         from .compile import aot
         from .compile import cache as compile_cache
         from .compile.buckets import bucket_spec
+        from .ops import hyper_connection
 
         stats0 = compile_cache.cache_stats()
         state_spec = aot.abstract_spec(self.state)
@@ -993,10 +994,11 @@ class TrainValStage(Stage):
         n_train = 0
         elapsed_ms = 0.0  # the compile spans' own readings (PrecompiledStep.precompile)
         verify_args: list[tuple] = []
-        for gs in global_specs(self._host_batch_spec(self.train_dataset)):
-            elapsed_ms += self._train_compiled.precompile(state_spec, gs)
-            verify_args.append(("train_step", self._train_compiled, (state_spec, gs), (0,)))
-            n_train += 1
+        with hyper_connection.recording() as mhc_seen:  # the path each hyper-connection's maps took, for the log line
+            for gs in global_specs(self._host_batch_spec(self.train_dataset)):
+                elapsed_ms += self._train_compiled.precompile(state_spec, gs)
+                verify_args.append(("train_step", self._train_compiled, (state_spec, gs), (0,)))
+                n_train += 1
         # val is best-effort: a stage may have no val dataset, or one whose
         # first-batch peek is impossible — the val step then compiles lazily
         n_val = 0
@@ -1017,6 +1019,8 @@ class TrainValStage(Stage):
                 f"; remat: {plan['kept_blocks']} of {plan['blocks']} blocks keep their MLP's products, "
                 f"{plan['kept_bytes']} bytes (free {plan['free_bytes']}, reserve {plan['reserve_bytes']})"
             )
+            if mhc_seen:
+                kept += f"; mhc: {hyper_connection.summary(mhc_seen)}"
             self.logger.info(
                 f"precompile: {n_train} train + {n_val} val signature(s) in {elapsed_ms:.0f} ms "
                 f"(compile cache: {stats1['aot_hits'] - stats0['aot_hits']} hit(s), "

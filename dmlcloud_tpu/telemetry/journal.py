@@ -101,6 +101,7 @@ SPAN_KINDS = frozenset(
         "profile",  # a jax.profiler trace the program took (utils.profiling.trace)
         "setup",  # one part of the time from process start to the first step (label: SETUP_LABELS)
         "remat",  # a train step traced with remat: the blocks that keep their MLP's products, the room (no duration)
+        "mhc",  # a hyper-connection traced: the path its maps take (kernels or plain), the stream's shape, why (no duration)
     }
 )
 

@@ -362,12 +362,13 @@ class _TinyLMStage(dml.TrainValStage):
     of a real train step, at toy widths."""
 
     remat = False
+    hc_mult = 1
 
     def pre_stage(self):
         from dmlcloud_tpu.models.transformer import DecoderLM, TransformerConfig
 
         cfg = TransformerConfig(vocab_size=64, hidden_dim=32, num_layers=2, num_heads=4, num_kv_heads=2, mlp_dim=64,
-                                max_seq_len=32, attn_impl="flash", dtype=jnp.float32, remat=self.remat)
+                                max_seq_len=32, attn_impl="flash", dtype=jnp.float32, remat=self.remat, hc_mult=self.hc_mult)
         tokens = np.random.RandomState(0).randint(0, 64, size=(2, 32)).astype(np.int32)
         self.pipeline.register_model("lm", DecoderLM(cfg), init_args=(jnp.asarray(tokens),), verbose=False)
         self.pipeline.register_optimizer("adamw", optax.adamw(1e-3))
@@ -453,4 +454,24 @@ def test_a_remat_step_journals_what_its_blocks_keep_once_a_trace(single_runtime,
     assert plan == dict(kept_blocks=0, blocks=2, kept_bytes=0, free_bytes=free, reserve_bytes=reserve)
     assert records[0]["label"] == stage.name and records[0]["dur"] == 0.0
     assert f"remat: 0 of 2 blocks keep their MLP's products, 0 bytes (free {free}, reserve {reserve})" in caplog.text
+    j.close()
+
+
+def test_a_hyper_connected_step_journals_the_path_of_its_maps(single_runtime, tmp_path, caplog):
+    """Each hyper-connection traced journals an ``mhc`` record (the path its maps take, the stream's shape, why
+    the plain form), and the ``precompile:`` line says what the train step's four took: on the CPU, the plain form."""
+    from dmlcloud_tpu.telemetry import journal as journal_mod
+
+    j = journal_mod.activate(journal_mod.SpanJournal(tmp_path))
+    try:
+        stage = _TinyLMStage()
+        stage.hc_mult = 2
+        with caplog.at_level(logging.INFO, logger="dmlcloud_tpu"):
+            _run_pipeline(stage, epochs=1, precompile=True)
+    finally:
+        journal_mod.deactivate()
+    records = [r for r in j.tail(1024) if r["kind"] == "mhc"]
+    assert len(records) >= 4 and all(r["dur"] == 0.0 for r in records)
+    assert {(r["path"], tuple(r["shape"]), r["why"]) for r in records} == {("plain", (2, 32, 64), "backend cpu")}
+    assert "mhc: plain [2, 32, 64] (backend cpu) x 4" in caplog.text
     j.close()

@@ -71,6 +71,9 @@ V1_KINDS = {
     # remat (PR 40): one record each time a train step is traced with remat:
     # the blocks that keep their MLP's products and the device's room
     "remat",
+    # mhc: one record each time a hyper-connection is traced: the path its
+    # maps take (kernels or plain), the stream's shape, why the plain form
+    "mhc",
 }
 
 #: Core fields every v1 record carries, with their types.

@@ -55,7 +55,9 @@ def test_the_xing_cells_step_fits_one_chip_with_remat(v5e, monkeypatch):
     ``remat``), as the stage builds it: both losses and the gradient, the clip, the update, state donated. Its
     ``memory_analysis()`` is the number the configuration's head and width share rests on: arguments + temporaries
     + the registry's float32 copy of the parameters under the chip's 15.75 GiB. The flash kernels run at qk 192 /
-    v 128, and the recomputed forward lands in the new mechanisms' own phases."""
+    v 128, and the recomputed forward lands in the new mechanisms' own phases. The twelve sublayers' hyper-connection
+    maps are ``ops/hyper_connection.py``'s four kernels, each in the phase ``mhc`` in every direction it runs in, and
+    the step's temporaries are no more than those of the maps in plain ``jax.numpy``."""
     import dataclasses
 
     import optax
@@ -98,10 +100,21 @@ def test_the_xing_cells_step_fits_one_chip_with_remat(v5e, monkeypatch):
     print(f"xing-train-8k step: arguments {memory.argument_size_in_bytes}, temporaries {memory.temp_size_in_bytes}")
     assert memory.argument_size_in_bytes == pytest.approx(12 * held, rel=0.001)  # weights and two moments
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + 4 * held < 15.75 * 2**30
+    assert memory.temp_size_in_bytes <= 5_922_267_648  # the maps in plain jax.numpy, as the chip read the step before the kernels
     text = compiled.as_text()
     calls = re.findall(r"%(flash_(?:fwd|bwd_dq|bwd_dkv))[.\d]* = ([^\n]*?) custom-call\(", text)
     assert {name for name, _ in calls} == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
     assert any("[4,8192,128]" in shapes for name, shapes in calls if name == "flash_fwd")  # the output is v's 128 wide
-    found = set(phase_map(text).values())
+    phases = phase_map(text)
+    found = set(phases.values())
     assert {("mhc", "recompute"), ("mla_latent", "recompute"), ("mhc", "bwd"), ("mla_latent", "bwd"), ("mtp", "fwd"), ("mtp", "bwd")} <= found
+    # five blocks and the MTP block, two sublayers each: every map is a kernel, forward, recomputed and backward
+    kernels = {name: where for name, where in phases.items() if re.match(r"mhc_(pre|merge)(_bwd)?\b", name)}
+    for kernel, directions in (("mhc_pre", {"fwd", "recompute"}), ("mhc_merge", {"fwd", "recompute"}),
+                               ("mhc_pre_bwd", {"bwd"}), ("mhc_merge_bwd", {"bwd"})):
+        assert {where for name, where in kernels.items() if re.match(rf"{kernel}(\.\d+)?$", name)} == {("mhc", d) for d in directions}
+    calls = set(re.findall(r"%(mhc_(?:pre|merge)(?:_bwd)?(?:\.\d+)?) = [^\n]* custom-call\(", text))
+    count = lambda kernel: sum(re.fullmatch(rf"{kernel}(\.\d+)?", name) is not None for name in calls)
+    # the recomputed forward of a block needs no merge of its last sublayer: the block's output is the next one's saved input
+    assert (count("mhc_pre"), count("mhc_merge"), count("mhc_pre_bwd"), count("mhc_merge_bwd")) == (24, 18, 12, 12)
 

@@ -340,7 +340,11 @@ def test_the_partition_rules_shard_the_latent_projections_by_head():
 # ------------------------------------------------------------ phases
 
 
-def test_the_new_phases_forward_backward_and_recomputed():
+def test_the_new_phases_forward_backward_and_recomputed(monkeypatch):
+    """The new mechanisms' phases in a ``remat`` step's forward, recomputed forward and backward; with the
+    hyper-connections on ``ops/hyper_connection.py``'s kernels (interpreted here), each of the four kernels by its
+    name in ``mhc``: ``mhc_pre`` and ``mhc_merge`` forward and recomputed, their ``_bwd`` backward."""
+    from dmlcloud_tpu.ops import hyper_connection
     from dmlcloud_tpu.utils.profiling import PHASES, phase_map, phase_of
 
     assert {"mla_latent", "mhc", "mtp"} <= set(PHASES)
@@ -354,6 +358,12 @@ def test_the_new_phases_forward_backward_and_recomputed():
     for phase in ("mla_latent", "mhc", "mtp"):
         assert {(phase, "fwd"), (phase, "bwd")} <= found or {(phase, "recompute"), (phase, "bwd")} <= found, (phase, found)
     assert {("mhc", "recompute"), ("mla_latent", "recompute")} <= found  # a block's recomputed forward keeps its phases
+    monkeypatch.setattr(hyper_connection, "_plan", lambda shape, dtype, n, mesh: (hyper_connection._Tiles(32, 32, True), None))
+    step = jax.jit(jax.grad(lambda p: mtp_lm_loss(*model.apply({"params": p, "buffers": buffers}, tokens), tokens, 0.3)[0]))
+    names = re.findall(r'op_name="([^"]*)"', step.lower(tree).compile().as_text())
+    for kernel, directions in (("mhc_pre", {"fwd", "recompute"}), ("mhc_merge", {"fwd", "recompute"}),
+                               ("mhc_pre_bwd", {"bwd"}), ("mhc_merge_bwd", {"bwd"})):
+        assert {phase_of(name) for name in names if f"/{kernel}/" in name} == {("mhc", d) for d in directions}, kernel
 
 
 @pytest.mark.parametrize("which", ["lfm2", "laguna", "granite"])
